@@ -7,9 +7,9 @@ location in its findings:
 
   compat-boundary   no ``jax.experimental.*`` import/use and no version-gated
                     JAX symbol outside ``compat/`` and ``kernels/``. The
-                    compat layer is the single home of feature probes
-                    (ROADMAP: call-time detection, 0.4.x-0.7.x); a gated
-                    symbol elsewhere breaks some supported JAX version.
+                    compat layer is the single import point of JAX API that
+                    moves between releases, so an upgrade that moves one
+                    is a one-file change.
   env-at-import     no ``os.environ`` *reads* at module top level. Every
                     env-driven choice in this repo (SCALECOM_LAYOUT /
                     SCALECOM_BACKEND / SCALECOM_BUCKET_MB / autotune cache)
@@ -62,8 +62,8 @@ from repro.analysis.scalecheck.findings import Finding
 # compat-boundary
 # ---------------------------------------------------------------------------
 
-# Version-gated jax symbols: moved/renamed/added across the 0.4.x-0.7.x span
-# the compat layer spans (see compat/jax_compat.py's module docstring).
+# Version-gated jax symbols: API that has moved or been renamed between JAX
+# releases (see compat/jax_compat.py's module docstring).
 _GATED_ATTRS = {
     "jax.sharding.AxisType",
     "jax.set_mesh",

@@ -149,17 +149,10 @@ def _dependency_closure(jaxpr, seed_vars) -> Set[int]:
 
 def check_schedule(layout: str, *, overlap: bool = True) -> List[Finding]:
     """Verify the three schedule properties on one layout's bucketed trace."""
-    from repro.compat import jax_compat
-
     path = f"<jaxpr:{layout}>"
 
     def finding(msg: str) -> Finding:
         return Finding(rule="collective-schedule", path=path, line=0, message=msg)
-
-    if not jax_compat.has_optimization_barrier():
-        # Identity fallback on this jax: there is no schedule contract to
-        # verify (and none is promised — core.overlap degrades to sync).
-        return []
 
     closed, schedule, n_leaves = trace_schedule(layout, overlap=overlap)
     jaxpr = closed.jaxpr

@@ -42,9 +42,10 @@ __all__ = [
     "clear_cache",
 ]
 
-# Sublane counts to sweep: all multiples of the fp32 (8,128) VREG tile. The
-# kernel default (chunk_topk.BLOCK_CHUNKS) is included by construction.
-CANDIDATE_BLOCKS: Tuple[int, ...] = (64, 128, 256, 512, 1024)
+# Tile heights to sweep: multiples of the 1024-element tile XLA gives the
+# kernels' 1-D per-row outputs on TPU (Mosaic refuses any other 1-D block;
+# see chunk_topk.BLOCK_CHUNKS, which is included by construction).
+CANDIDATE_BLOCKS: Tuple[int, ...] = (1024, 2048, 4096)
 
 _OPS = ("select", "ef_update", "fused_reduce")
 

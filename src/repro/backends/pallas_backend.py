@@ -18,10 +18,11 @@ kernels.rowwise (kernels.chunk_topk row launchers underneath), so a flat
 the identical code path — the backend pads the trailing axis to a chunk
 multiple here and slices dense outputs back.
 
-Execution mode is a call-time probe (compat-layer style): native lowering
-when jax.default_backend() == "tpu", interpret mode elsewhere (bit-identical
-math, Python-speed — the correctness/CI path, exercised by the
-SCALECOM_BACKEND=pallas CI leg). Tile geometry per (op, chunk, dtype, size)
+Execution mode is a call-time probe: native Mosaic lowering when
+jax.default_backend() == "tpu", interpret mode elsewhere (the same math at
+host speed — the CPU correctness path, exercised by the
+SCALECOM_BACKEND=pallas CI leg). ``chip_smoke.py`` asserts the native mode
+on the chip. Tile geometry per (op, chunk, dtype, size)
 comes from the repro.backends.autotune on-disk cache, falling back to the
 kernel default when untuned.
 

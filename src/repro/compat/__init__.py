@@ -1,32 +1,27 @@
 """Version-portability shims. ``jax_compat`` is the only place in the repo
-allowed to reference version-gated JAX symbols (see tests/test_compat.py)."""
+allowed to reference version-gated JAX symbols (scalecheck's compat-boundary
+rule)."""
 
 from repro.compat import jax_compat
 from repro.compat.jax_compat import (
-    JAX_VERSION,
     axis_size,
     Mesh,
     NamedSharding,
     P,
     PartitionSpec,
     make_mesh,
-    psum_scatter,
     set_mesh,
     shard_map,
-    tree_map_with_path,
 )
 
 __all__ = [
     "jax_compat",
-    "JAX_VERSION",
     "axis_size",
     "Mesh",
     "NamedSharding",
     "P",
     "PartitionSpec",
     "make_mesh",
-    "psum_scatter",
     "set_mesh",
     "shard_map",
-    "tree_map_with_path",
 ]

@@ -1,35 +1,26 @@
-"""JAX version-portability layer for the distributed path.
+"""The single import point for JAX's mesh, collective and float8 API.
 
-Every version-gated JAX symbol the repo relies on is probed and wrapped HERE,
-and nowhere else (enforced by tests/test_compat.py): the same reduce path has
-to run unmodified on whatever JAX the host ships, 0.4.x through 0.7.x, on
-CPU/GPU/TPU. The moving targets:
+The repo targets the one JAX it is installed with (0.9). Every other module
+reaches these symbols through this module and nowhere else (scalecheck's
+``compat-boundary`` rule enforces it), so a JAX upgrade that moves one of them
+is a one-file change:
 
-  * ``jax.make_mesh(axis_types=...)`` / ``jax.sharding.AxisType`` — AxisType
-    only exists on 0.6+; ``jax.make_mesh`` itself only on 0.4.34+. Older still
-    falls back to ``Mesh(mesh_utils.create_device_mesh(...))``.
-  * ``jax.set_mesh`` (0.6+) vs ``jax.sharding.use_mesh`` (0.5.x) vs the legacy
-    ``with mesh:`` context (0.4.x).
-  * ``jax.shard_map`` (top-level on 0.6+) vs
-    ``jax.experimental.shard_map.shard_map``.
-  * ``jax.tree_util.tree_map_with_path`` / ``jax.lax.psum_scatter`` — present
-    on every version we target, but probed with a manual fallback so a future
-    relocation doesn't break the reduce path.
-  * ``jnp.float8_e4m3fn`` — availability probe plus an emulated e4m3 rounding
-    for builds without ml_dtypes float8 (storage degrades to bfloat16 there;
-    codec byte accounting follows the real itemsize).
-
-All probes run at CALL time, not import time, so tests can monkeypatch either
-branch and deployments that hot-swap jax (notebook upgrades) stay correct.
+  * ``make_mesh`` — ``jax.make_mesh`` with every axis ``AxisType.Auto`` (the
+    reduce path relies on GSPMD-inferred shardings, never on Explicit-mode
+    sharding-in-types);
+  * ``set_mesh`` / ``shard_map`` / ``axis_size`` — ``jax.set_mesh``,
+    ``jax.shard_map``, ``jax.lax.axis_size``;
+  * ``optimization_barrier`` — the scheduling fence of core.overlap;
+  * ``float8_e4m3_dtype`` / ``cast_to_e4m3`` — the e4m3 residue storage of
+    the fp8 codecs (core.state).
 
 Stable sharding symbols (Mesh / NamedSharding / PartitionSpec) are re-exported
-so the rest of the repo has a single canonical import point for sharding API.
+so the rest of the repo has one canonical import point for sharding API.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -38,43 +29,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 P = PartitionSpec
 
 __all__ = [
-    "JAX_VERSION",
     "Mesh",
     "NamedSharding",
     "PartitionSpec",
     "P",
-    "has_axis_type",
     "make_mesh",
     "set_mesh",
     "shard_map",
-    "tree_map_with_path",
     "axis_size",
-    "psum_scatter",
     "pallas_available",
-    "has_optimization_barrier",
     "optimization_barrier",
-    "has_float8",
     "float8_e4m3_dtype",
-    "float8_itemsize",
     "cast_to_e4m3",
     "describe",
 ]
-
-JAX_VERSION: Tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
-
-_E4M3_MAX = 448.0  # e4m3fn finite max (no inf encoding; overflow -> nan)
-
-
-# ---------------------------------------------------------------------------
-# mesh construction / activation
-# ---------------------------------------------------------------------------
-
-
-def has_axis_type() -> bool:
-    """True when this jax has ``jax.sharding.AxisType`` (0.6+ explicit-mesh API)."""
-    return hasattr(jax.sharding, "AxisType")
 
 
 def make_mesh(
@@ -83,117 +51,33 @@ def make_mesh(
     *,
     devices: Optional[Sequence[Any]] = None,
 ) -> Mesh:
-    """Version-portable ``jax.make_mesh``.
-
-    Newest first: make_mesh with explicit Auto axis_types (0.6+), make_mesh
-    without (0.4.34–0.5.x), and Mesh over mesh_utils.create_device_mesh for
-    anything older. All branches produce a fully Auto (GSPMD-inferred) mesh —
-    the repo's reduce path never relies on Explicit-mode sharding-in-types.
-    """
-    shape = tuple(shape)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-inferred)."""
     axes = tuple(axes)
-    if hasattr(jax, "make_mesh"):
-        if has_axis_type():
-            try:
-                return jax.make_mesh(
-                    shape,
-                    axes,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-                    devices=devices,
-                )
-            except TypeError:
-                pass  # make_mesh present but predates the axis_types kwarg
-        try:
-            return jax.make_mesh(shape, axes, devices=devices)
-        except TypeError:
-            return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return Mesh(devs, axes)
+    return jax.make_mesh(
+        tuple(shape),
+        axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def set_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for jit/sharding resolution.
-
-    jax.set_mesh (0.6+) > jax.sharding.use_mesh (0.5.x) > the legacy
-    ``with mesh:`` context (0.4.x). All uses in this repo pass NamedSharding
-    (which carries its own mesh), so the activation is belt-and-braces on old
-    versions rather than load-bearing.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return _legacy_mesh_context(mesh)
-
-
-@contextlib.contextmanager
-def _legacy_mesh_context(mesh: Mesh):
-    with mesh:
-        yield mesh
-
-
-# ---------------------------------------------------------------------------
-# collectives / tree utils
-# ---------------------------------------------------------------------------
+    """Context manager activating ``mesh`` for jit/sharding resolution."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` (0.6+) or ``jax.experimental.shard_map.shard_map``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def tree_map_with_path(f, tree, *rest, is_leaf=None):
-    """``jax.tree_util.tree_map_with_path`` with a flatten-based fallback."""
-    tu = jax.tree_util
-    if hasattr(tu, "tree_map_with_path"):
-        return tu.tree_map_with_path(f, tree, *rest, is_leaf=is_leaf)
-    flat, treedef = tu.tree_flatten_with_path(tree, is_leaf=is_leaf)
-    rests = [treedef.flatten_up_to(r) for r in rest]
-    out = [
-        f(path, leaf, *(r[i] for r in rests)) for i, (path, leaf) in enumerate(flat)
-    ]
-    return treedef.unflatten(out)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` (0.6+); statically-folded psum(1) fallback on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def psum_scatter(x, axis_name: str, *, scatter_dimension: int = 0, tiled: bool = False):
-    """``jax.lax.psum_scatter`` with a psum+slice fallback (inside shard_map)."""
-    if hasattr(jax.lax, "psum_scatter"):
-        return jax.lax.psum_scatter(
-            x, axis_name, scatter_dimension=scatter_dimension, tiled=tiled
-        )
-    full = jax.lax.psum(x, axis_name)
-    n = axis_size(axis_name)
-    idx = jax.lax.axis_index(axis_name)
-    shard = x.shape[scatter_dimension] // n
-    out = jax.lax.dynamic_slice_in_dim(full, idx * shard, shard, scatter_dimension)
-    if not tiled and shard == 1:
-        out = jnp.squeeze(out, axis=scatter_dimension)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# scheduling barriers
-# ---------------------------------------------------------------------------
+    return jax.lax.axis_size(axis_name)
 
 
 def pallas_available() -> bool:
     """Call-time probe: does this jax ship the pallas package?
 
-    ``jax.experimental.pallas`` moved/changed across the supported span, so
-    the import probe lives here behind the compat boundary; the kernel
+    The import probe lives here behind the compat boundary; the kernel
     registry (repro.backends.base) consumes the verdict, never the import.
     """
     try:
@@ -203,74 +87,26 @@ def pallas_available() -> bool:
     return True
 
 
-def has_optimization_barrier() -> bool:
-    """True when this jax ships ``jax.lax.optimization_barrier``.
-
-    The overlap-aware bucketed reduce (core.overlap) uses the barrier to pin
-    the launch order of per-bucket collectives; when the primitive is absent
-    the scheduler degrades to the synchronous (unordered) trace, which is
-    bitwise identical — only the scheduling hint is lost.
-    """
-    return hasattr(jax.lax, "optimization_barrier")
-
-
 def optimization_barrier(tree):
-    """``jax.lax.optimization_barrier`` with an identity fallback.
-
-    The barrier is a value-level identity either way: it never changes
-    numerics, only forbids XLA from reordering/DCE-ing computation across it.
-    """
-    if has_optimization_barrier():
-        return jax.lax.optimization_barrier(tree)
-    return tree
-
-
-# ---------------------------------------------------------------------------
-# float8 guards
-# ---------------------------------------------------------------------------
-
-
-def has_float8() -> bool:
-    """True when this jax ships ``jnp.float8_e4m3fn`` (ml_dtypes float8)."""
-    return hasattr(jnp, "float8_e4m3fn")
+    """``jax.lax.optimization_barrier``: a value-level identity that forbids
+    XLA from reordering or eliminating computation across it."""
+    return jax.lax.optimization_barrier(tree)
 
 
 def float8_e4m3_dtype():
-    """The e4m3 storage dtype: ``jnp.float8_e4m3fn``, or ``jnp.bfloat16`` when
-    float8 is unavailable (values are still rounded onto the e4m3 grid by
-    ``cast_to_e4m3``, so codec numerics match; only the storage width grows)."""
-    return jnp.float8_e4m3fn if has_float8() else jnp.bfloat16
-
-
-def float8_itemsize() -> int:
-    """Bytes per element of the active e4m3 storage (1, or 2 when emulated)."""
-    return 1 if has_float8() else 2
+    """The e4m3 residue storage dtype."""
+    return jnp.float8_e4m3fn
 
 
 def cast_to_e4m3(x):
-    """Round ``x`` onto the e4m3 grid, in whatever storage dtype is active.
-
-    Native path is a plain astype. The emulated path keeps 4 significand bits
-    of fp32 (1 implicit + 3 explicit, e4m3's precision) via round-to-nearest-
-    even bit masking (ties-to-even matches ml_dtypes) and clamps to ±448;
-    e4m3 subnormals are approximated by the same masking (cold path — only
-    builds without ml_dtypes float8 hit it).
-    """
-    if has_float8():
-        return x.astype(jnp.float8_e4m3fn)
-    f = jnp.clip(x.astype(jnp.float32), -_E4M3_MAX, _E4M3_MAX)
-    bits = jax.lax.bitcast_convert_type(f, jnp.uint32)
-    lsb = (bits >> 20) & jnp.uint32(1)
-    rounded = (bits + jnp.uint32((1 << 19) - 1) + lsb) & jnp.uint32(0xFFF00000)
-    out = jax.lax.bitcast_convert_type(rounded, jnp.float32)
-    out = jnp.where(jnp.abs(out) < 2.0**-9, 0.0, out)  # below e4m3 min subnormal
-    return out.astype(jnp.bfloat16)
+    """Round ``x`` onto the e4m3 grid (nearest, ties to even)."""
+    return x.astype(jnp.float8_e4m3fn)
 
 
 def describe() -> str:
-    """One-line runtime feature summary for launcher logs."""
+    """One-line runtime summary for launcher logs."""
+    devs = jax.devices()
     return (
-        f"jax {jax.__version__} | AxisType={has_axis_type()} "
-        f"set_mesh={hasattr(jax, 'set_mesh')} shard_map={hasattr(jax, 'shard_map')} "
-        f"float8={has_float8()} opt_barrier={has_optimization_barrier()}"
+        f"jax {jax.__version__} | {devs[0].platform} {devs[0].device_kind} "
+        f"x{len(devs)}"
     )

@@ -28,9 +28,9 @@ backprop. This module is the *launch* stage that fixes it:
 Both staging points are ``jax.lax.optimization_barrier`` — a value-level
 identity — so the bucketed reduce is BITWISE identical to the unbucketed
 path: same per-tensor plans, same EF residues, only launch granularity
-changes (asserted over 20-step trajectories by tests/test_overlap.py). When
-the compat probe says the primitive is unavailable the scheduler degrades to
-the synchronous fallback: the same per-bucket trace with no ordering hints.
+changes (asserted over 20-step trajectories by tests/test_overlap.py). With
+``overlap=False`` the scheduler emits the same per-bucket trace with no
+ordering hints.
 
 Resolution mirrors layout/backend: ``resolve_bucket_bytes`` probes the
 ``SCALECOM_BUCKET_MB`` env var at call time (the CI leg that runs tier-1
@@ -146,8 +146,7 @@ def stage_bucket(
     The barrier ties the staged leaves to ``token`` (= the previous bucket's
     fence), so this bucket's compress + all-reduce cannot be hoisted ahead of
     the previous bucket's collective. Identity on values. With
-    ``overlap=False`` (or no optimization_barrier on this jax) the leaves
-    pass through untouched — the synchronous fallback.
+    ``overlap=False`` the leaves pass through untouched.
 
     ``bucket`` is the schedule index for the telemetry tap (a static count of
     staged leaves per bucket, repro.obs.taps — a trace-time no-op unless a
@@ -160,7 +159,7 @@ def stage_bucket(
             bucket=bucket,
             overlap=overlap,
         )
-    if not overlap or not jax_compat.has_optimization_barrier():
+    if not overlap:
         return list(leaves), token
     staged, token = jax_compat.optimization_barrier((tuple(leaves), token))
     return list(staged), token
@@ -176,7 +175,7 @@ def fence_bucket(
     caller UN-barriered — the optimizer never serializes behind the token
     chain, only the next bucket's launch does.
     """
-    if not overlap or not jax_compat.has_optimization_barrier():
+    if not overlap:
         return token
     _, token = jax_compat.optimization_barrier((tuple(outputs), token))
     return token

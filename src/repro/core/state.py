@@ -243,7 +243,7 @@ class _Fp8Codec(ResidueCodec):
 
     def nbytes(self, n, shape):
         size = int(np.prod(shape))
-        q_item = jax_compat.float8_itemsize()
+        q_item = jnp.dtype(jax_compat.float8_e4m3_dtype()).itemsize
         if len(shape) == 1:
             p = self._padded(size)
             return n * (q_item * p + 4 * p // _FP8_CHUNK)

@@ -3,8 +3,8 @@ extends to ring all-reduce settings") as a manual-collective backend.
 
 The primary runtime (repro.training.train_step) expresses ScaleCom in pure
 GSPMD; this module is the dual formulation with hand-written collectives
-inside ``shard_map`` (via the compat layer, so it runs on 0.4.x and 0.7.x
-alike): each device holds ITS worker's error-feedback state
+inside ``shard_map`` (through the compat layer): each device holds ITS
+worker's error-feedback state
 and gradient shard, and the only collectives are
 
     psum(masked index row)   — the leader's O(k) index broadcast

@@ -11,9 +11,11 @@ tile*. The flat gradient is viewed as (n_chunks, chunk); the kernel streams
 (block_chunks, chunk) tiles HBM->VMEM and emits per-chunk (argmax, value) pairs.
 All reductions are along the minor (lane) axis, the natural VPU reduction
 direction: no data-dependent control flow, no cross-lane shuffles, MXU not
-needed. chunk and block_chunks are picked so tiles are (8,128)-aligned;
-``block_chunks`` is a static tuning knob swept by ``repro.backends.autotune``
-(see benchmarks/bench_kernels.py for the measured sweep).
+needed. Reads at a data-dependent lane offset are a one-hot compare-and-select
+followed by a lane sum (``lane_pick``): Mosaic lowers no in-kernel gather.
+``block_chunks`` is a static tuning knob swept by ``repro.backends.autotune``;
+it must be a multiple of 1024 (see ``BLOCK_CHUNKS``). With the default
+chunk of 64 each tile row fills half of the 128 lanes.
 
 Four kernel bodies share the tile geometry:
 
@@ -30,7 +32,8 @@ repro.kernels.rowwise. These flat wrappers are the 1-D public API
 oracles in repro.core.chunked).
 
 Validated against repro.kernels.ref in interpret mode (CPU) over a shape/dtype
-sweep — see tests/test_kernels.py and tests/test_backends.py.
+sweep — see tests/test_kernels.py and tests/test_backends.py — and compiled
+for a described TPU v5e at full width by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -50,11 +53,13 @@ __all__ = [
 ]
 
 # Default tile geometry: (BLOCK_CHUNKS, chunk) tiles; BLOCK_CHUNKS rows of the
-# chunk view are processed per grid step. 8 sublanes x 128 lanes is the fp32
-# VREG tile; chunk sizes of 128+ keep lanes full, BLOCK_CHUNKS=256 gives
-# 128KiB fp32 tiles — comfortably inside the ~16 MiB VMEM budget with double
-# buffering. Autotuned per device kind by repro.backends.autotune.
-BLOCK_CHUNKS = 256
+# chunk view are processed per grid step. The per-row (index, value) arrays
+# are 1-D, and XLA lays a long 1-D TPU array out in 1024-element tiles; Mosaic
+# refuses a 1-D block that is not a multiple of that tile, so every block
+# height is a multiple of 1024 (autotune.CANDIDATE_BLOCKS). A 1024 x 64 fp32
+# tile is 512 KiB in VMEM (64-lane rows pad to 128 lanes), well inside the
+# 16 MiB default scoped limit with double buffering.
+BLOCK_CHUNKS = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -62,13 +67,38 @@ BLOCK_CHUNKS = 256
 # ---------------------------------------------------------------------------
 
 
+def lane_pick(x, idx):
+    """Values of ``x`` (..., C) at lane offsets ``idx`` (...,): a one-hot
+    compare-and-select followed by a lane reduction.
+
+    Mosaic has no in-kernel gather, so every per-chunk read at a data-
+    dependent offset takes this form. Exactly one lane survives the select,
+    so the sum is exact in any float dtype.
+    """
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    zero = jnp.zeros((), x.dtype)
+    return jnp.sum(jnp.where(cols == idx[..., None], x, zero), axis=-1)
+
+
+def lane_argmax(mag):
+    """Per-row arg-max of ``mag`` (..., C) over lanes, as a max and a min.
+
+    Returns what ``jnp.argmax`` returns — the lowest lane among equal
+    maxima, or the first NaN — so indices match the jnp oracles bitwise.
+    Mosaic's own argmax lowering breaks exact ties differently on the chip.
+    """
+    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, mag.ndim - 1)
+    top = jnp.max(mag, axis=-1, keepdims=True)
+    hit = (mag == top) | (mag != mag)  # mag != mag: NaN ranks first
+    return jnp.min(jnp.where(hit, cols, mag.shape[-1]), axis=-1)
+
+
 def _argmax_kernel(x_ref, idx_ref, val_ref):
     """x: (B, C) tile -> idx/val: (B,) per-chunk magnitude arg-max."""
     x = x_ref[...]
-    mag = jnp.abs(x)
-    idx = jnp.argmax(mag, axis=-1).astype(jnp.int32)
+    idx = lane_argmax(jnp.abs(x))
     idx_ref[...] = idx
-    val_ref[...] = jnp.take_along_axis(x, idx[:, None], axis=-1)[:, 0]
+    val_ref[...] = lane_pick(x, idx)
 
 
 def _topm_kernel(x_ref, idx_ref, val_ref, *, m: int):
@@ -82,9 +112,9 @@ def _topm_kernel(x_ref, idx_ref, val_ref, *, m: int):
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     neg = jnp.full((), -1.0, mag.dtype)
     for j in range(m):
-        ij = jnp.argmax(mag, axis=-1).astype(jnp.int32)
+        ij = lane_argmax(mag)
         idx_ref[:, j] = ij
-        val_ref[:, j] = jnp.take_along_axis(x, ij[:, None], axis=-1)[:, 0]
+        val_ref[:, j] = lane_pick(x, ij)
         mag = jnp.where(cols == ij[:, None], neg, mag)
 
 
@@ -93,9 +123,10 @@ def _gather_kernel(x_ref, idx_ref, val_ref):
     x = x_ref[...]
     idx = idx_ref[...]
     if idx.ndim == 1:
-        val_ref[...] = jnp.take_along_axis(x, idx[:, None], axis=-1)[:, 0]
+        val_ref[...] = lane_pick(x, idx)
     else:
-        val_ref[...] = jnp.take_along_axis(x, idx, axis=-1)
+        for j in range(idx.shape[1]):  # top-m: m is small and static
+            val_ref[:, j] = lane_pick(x, idx[:, j])
 
 
 def _scatter_kernel(vals_ref, idx_ref, out_ref):
@@ -238,8 +269,8 @@ def chunk_argmax_pallas(
     block_chunks: int = BLOCK_CHUNKS,
 ):
     """Per-chunk (indices, values) of a flat array. Returns ((n_chunks,) i32,
-    (n_chunks,) x.dtype). interpret=True executes on CPU (the container has no
-    TPU); on TPU pass interpret=False.
+    (n_chunks,) x.dtype). interpret=True evaluates the kernel body with XLA
+    on any device; interpret=False compiles it with Mosaic for a TPU.
     """
     xp, n_chunks = _flat_view(x, chunk)
     idx, val = row_select(xp, topm=1, interpret=interpret, block_chunks=block_chunks)

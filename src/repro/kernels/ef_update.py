@@ -49,16 +49,16 @@ def _ef_update_kernel(m_ref, g_ref, idx_ref, m_out_ref, val_ref, *, beta: float)
     cols = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
     zero = jnp.zeros((), ef.dtype)
     if idx.ndim == 1:
-        vals = jnp.take_along_axis(ef, idx[:, None], axis=-1)[:, 0]
         own = jnp.where(cols == idx[:, None], ef, zero)
+        val_ref[...] = jnp.sum(own, axis=-1)
     else:
-        vals = jnp.take_along_axis(ef, idx, axis=-1)
         own = jnp.zeros(m.shape, ef.dtype)
         for j in range(idx.shape[1]):  # top-m: selected offsets are distinct
-            own = own + jnp.where(cols == idx[:, j : j + 1], ef, zero)
+            own_j = jnp.where(cols == idx[:, j : j + 1], ef, zero)
+            val_ref[:, j] = jnp.sum(own_j, axis=-1)
+            own = own + own_j
     # ghat_own = vals scattered at idx; m' = m + beta*(g - ghat_own)
     m_out_ref[...] = m + beta * (g - own)
-    val_ref[...] = vals
 
 
 def row_ef_update(m2d, g2d, idx, beta, *, interpret, block_chunks):

@@ -8,9 +8,10 @@ see ``analysis.perfmodel.reduce_hbm_passes``). This kernel runs all three
 phases over ONE VMEM-resident tile per grid step:
 
   phase 1  top-m index select over the worker-stacked EF gradients
-           (clt_k: per-worker masked-argmax candidates + the leader's one-hot
-           pick, bitwise-identical to ``compressors.leader_pick`` over the
-           3-launch select; true_topk: argmax over the worker mean)
+           (clt_k: masked-argmax passes over the leader's EF rows, picked by
+           a one-hot worker mask — bitwise-identical to
+           ``compressors.leader_pick`` over the 3-launch select; true_topk:
+           the same passes over the worker mean)
   phase 2  residue (EF) update with codec-aware write-back — the m' tile the
            kernel writes is exactly what ``codec.encode`` consumes (for the
            fp32 codec the encode is a reshape, so this write IS the stored
@@ -34,8 +35,9 @@ stays pure tile math.
 The leader is a *traced* scalar (t mod G changes every step); it enters as a
 (G, chunk) int32 one-hot mask operand — 2-D so it tiles legally on real TPU
 (1-D operands with degenerate BlockSpecs do not; same lesson as ef_update's
-static beta) — and the kernel reduces idx candidates against it as a masked
-int sum, the in-tile form of ``leader_pick``.
+static beta). The kernel sums the worker tile under that mask, which yields
+the leader's EF rows exactly, and selects from them: the in-tile form of
+``leader_pick``, since only the leader's candidates are ever kept.
 
 Validated against the composed 3-op path (bitwise indices, allclose values)
 in tests/test_backends.py; the 1-launch property is asserted by the
@@ -49,8 +51,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.chunk_topk import BLOCK_CHUNKS, _padded_rows
+from repro.kernels.chunk_topk import BLOCK_CHUNKS, _padded_rows, lane_argmax
 
 __all__ = ["FUSABLE_MODES", "fused_reduce_trailing", "row_fused_reduce"]
 
@@ -59,77 +62,65 @@ __all__ = ["FUSABLE_MODES", "fused_reduce_trailing", "row_fused_reduce"]
 # to the 3-launch path — backends.base.fused_reduce documents the contract.
 FUSABLE_MODES = ("clt_k", "true_topk")
 
+# Scoped VMEM the fused kernel may use. The default 16 MiB holds the
+# double-buffered (G, block_chunks, chunk) m/g/m' tiles for only a few
+# workers (64-lane chunk rows pad to 128 lanes: 24 MiB at G=8 with 1024-row
+# blocks); a TPU v5e core has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
 
 def _fused_kernel(
     m_ref, g_ref, wmask_ref, idx_ref, val_ref, m_out_ref, ghat_ref,
     *, beta: float, topm: int, mode: str,
 ):
-    """One (G, B, C) tile through all three phases (see module docstring)."""
+    """One (G, B, C) tile through all three phases (see module docstring).
+
+    Every reduction runs over the lane axis of a (B, C) or (G, B, C) tile
+    or over the untiled worker axis, so Mosaic never has to relayout a
+    per-row result against a worker-broadcast operand.
+    """
     m = m_ref[...]          # (G, B, C)
     g = g_ref[...]
     ef = m + g              # lives only in VMEM — never materialized in HBM
     zero = jnp.zeros((), ef.dtype)
-    cols3 = jax.lax.broadcasted_iota(jnp.int32, ef.shape, 2)
 
     # --- phase 1: shared top-m index select ------------------------------
+    # the (B, C) tile the shared index set is chosen from: the worker mean
+    # (true_topk) or the leader's own EF rows (clt_k)
     if mode == "true_topk":
-        efm = jnp.mean(ef, axis=0)                      # (B, C) worker mean
-        magm = jnp.abs(efm)
-        cols2 = jax.lax.broadcasted_iota(jnp.int32, magm.shape, 1)
-        if topm == 1:
-            idx = jnp.argmax(magm, axis=-1).astype(jnp.int32)       # (B,)
-        else:
-            neg = jnp.full((), -1.0, magm.dtype)
-            picks = []
-            for _ in range(topm):  # masked-argmax passes, ties to lower lane
-                ij = jnp.argmax(magm, axis=-1).astype(jnp.int32)
-                picks.append(ij)
-                magm = jnp.where(cols2 == ij[:, None], neg, magm)
-            idx = jnp.stack(picks, axis=-1)                         # (B, topm)
-    else:  # clt_k: every worker's candidates, the leader's one-hot pick
-        w = wmask_ref[...][:, :1].astype(jnp.int32)                 # (G, 1)
-        mag = jnp.abs(ef)
-        if topm == 1:
-            idx_all = jnp.argmax(mag, axis=-1).astype(jnp.int32)    # (G, B)
-            idx = jnp.sum(idx_all * w, axis=0)                      # (B,)
-        else:
-            neg = jnp.full((), -1.0, mag.dtype)
-            picks = []
-            for _ in range(topm):
-                ij = jnp.argmax(mag, axis=-1).astype(jnp.int32)     # (G, B)
-                picks.append(ij)
-                mag = jnp.where(cols3 == ij[..., None], neg, mag)
-            idx_all = jnp.stack(picks, axis=-1)                     # (G, B, m)
-            idx = jnp.sum(idx_all * w[..., None], axis=0)           # (B, m)
+        sel = jnp.mean(ef, axis=0)
+    else:
+        lead = wmask_ref[...][:, None, :] != 0                      # (G, 1, C)
+        sel = jnp.sum(jnp.where(lead, ef, zero), axis=0)
+    mag = jnp.abs(sel)
+    cols2 = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 1)
+    neg = jnp.full((), -1.0, mag.dtype)
+    picks = []
+    for _ in range(topm):  # masked-argmax passes, ties to the lower lane
+        ij = lane_argmax(mag)                                       # (B,)
+        picks.append(ij)
+        mag = jnp.where(cols2 == ij[:, None], neg, mag)
 
     # --- phase 2: gather + Eq. 5 residue update (codec-aware write-back) --
-    G = ef.shape[0]
-    if topm == 1:
-        idx_b = jnp.broadcast_to(idx[None, :, None], (G,) + idx.shape + (1,))
-        vals = jnp.take_along_axis(ef, idx_b, axis=-1)[..., 0]      # (G, B)
-        own = jnp.where(cols3 == idx[None, :, None], ef, zero)
-    else:
-        idx_b = jnp.broadcast_to(idx[None], (G,) + idx.shape)
-        vals = jnp.take_along_axis(ef, idx_b, axis=-1)              # (G, B, m)
-        own = jnp.zeros(ef.shape, ef.dtype)
-        for j in range(topm):  # top-m: selected offsets are distinct
-            own = own + jnp.where(cols3 == idx[None, :, j : j + 1], ef, zero)
+    # (one-hot select + lane sum: Mosaic has no in-kernel gather)
+    cols3 = jax.lax.broadcasted_iota(jnp.int32, ef.shape, 2)
+    own = None
+    for j, ij in enumerate(picks):  # top-m: selected offsets are distinct
+        own_j = jnp.where(cols3 == ij[None, :, None], ef, zero)
+        vals_j = jnp.sum(own_j, axis=-1)                            # (G, B)
+        if topm == 1:
+            idx_ref[...] = ij
+            val_ref[...] = vals_j
+        else:
+            idx_ref[:, j] = ij
+            val_ref[:, :, j] = vals_j
+        own = own_j if own is None else own + own_j
     m_out_ref[...] = m + beta * (g - own)
-    val_ref[...] = vals
 
     # --- phase 3: ĝ scatter of the k-value worker mean --------------------
-    vmean = jnp.mean(vals, axis=0)                      # (B,) or (B, topm)
-    gcols = jax.lax.broadcasted_iota(jnp.int32, ghat_ref.shape, 1)
-    if topm == 1:
-        ghat = jnp.where(gcols == idx[:, None], vmean[:, None], zero)
-    else:
-        ghat = jnp.zeros(ghat_ref.shape, vmean.dtype)
-        for j in range(topm):
-            ghat = ghat + jnp.where(
-                gcols == idx[:, j : j + 1], vmean[:, j : j + 1], zero
-            )
-    ghat_ref[...] = ghat
-    idx_ref[...] = idx
+    # own is ef at the selected lanes and zero elsewhere, so its worker mean
+    # is the scatter of mean(vals) — without a per-row relayout
+    ghat_ref[...] = jnp.mean(own, axis=0)
 
 
 def _pad_rows3(x3, block_chunks: int):
@@ -187,6 +178,7 @@ def row_fused_reduce(m3, g3, wmask, beta, *, topm, mode, interpret, block_chunks
             jax.ShapeDtypeStruct((G, rows, chunk), m3.dtype),
             jax.ShapeDtypeStruct((rows, chunk), m3.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(mp, gp, wmask)
     return idx[:n_rows], vals[:, :n_rows], m_new[:, :n_rows], ghat[:n_rows]

@@ -1,8 +1,10 @@
-"""jit'd public wrappers for the Pallas kernels with automatic CPU fallback.
+"""1-D convenience wrappers over the flat Pallas kernels.
 
-On TPU (the target) the kernels compile natively; this container is CPU-only so
-``interpret=True`` executes the kernel bodies in Python — bit-identical math,
-validated against repro.kernels.ref in the test suite.
+The execution mode follows the default JAX platform: on a TPU the kernels are
+compiled by Mosaic; on any other platform (the CPU tests) they run in
+interpret mode, where XLA evaluates the kernel bodies — the same math, checked
+against repro.kernels.ref by tests/test_kernels.py. Native compilation is
+checked by tests/test_tpu_compile.py and on the chip by ``chip_smoke.py``.
 
 These are the thin 1-D convenience entry points. Production dispatch —
 jnp-vs-pallas selection, autotuned tile geometry, batched worker axes, and the

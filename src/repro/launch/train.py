@@ -1,19 +1,24 @@
-"""Runnable training driver (CPU-scale): trains an assigned-arch SMOKE variant
-or the paper transformer on synthetic data with ScaleCom, simulating n workers
-on whatever devices exist (the worker axis works unsharded on one CPU device).
+"""Training driver: trains a registered architecture on synthetic data with
+ScaleCom, simulating n workers stacked on one device (the worker axis runs
+unsharded; the dense warm-up and the compressed step are separate programs).
 
     PYTHONPATH=src python -m repro.launch.train --arch starcoder2-3b \
         --workers 8 --steps 200 --compressor clt_k --chunk 64 --beta 0.1
 
-This is the end-to-end example driver (deliverable b): ~100M-param configs are
-reachable with --full-width; default smoke widths keep CI fast.
+Widths default to the architecture's SMOKE variant, which runs anywhere
+(CPU included, with Pallas kernels in interpret mode). ``--full-width`` loads
+the published widths (the paper transformer is 56.8M params) and is sized for
+a TPU, where the kernel backend resolves to native Pallas; ``chip_smoke.py``
+at the repo root drives this path on one TPU v5e.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +34,32 @@ from repro.optim import make_optimizer, schedule
 from repro.training import TrainLoop, init_train_state, run_training
 
 
-def main(argv=None):
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set: JAX reads it itself and no
+    other directory is set here. Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (git-ignored) — fixed because the path is part
+    of the cache key. Call it before the first compile of the process: JAX
+    settles the cache location at that compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-transformer-base")
+    ap.add_argument("--full-width", action="store_true",
+                    help="train the architecture at its published widths "
+                         "(registry.arch) instead of its SMOKE variant")
     ap.add_argument("--workers", type=int, default=8)
     ap.add_argument("--local-batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
@@ -91,38 +119,19 @@ def main(argv=None):
     if args.metrics_every and not args.trace_dir:
         ap.error("--metrics-every requires --trace-dir (the similarity taps "
                  "need the telemetry run to land anywhere)")
+    if args.arch not in registry._MODULES:
+        ap.error(f"unknown arch {args.arch}; choices: {list(registry._MODULES)}")
+    return args
 
-    cfg = registry.smoke(args.arch) if args.arch in registry._MODULES else None
-    if cfg is None:
-        raise SystemExit(f"unknown arch {args.arch}; choices: {list(registry._MODULES)}")
 
-    if args.preflight_scenarios:
-        from repro.harness.scenarios import SCENARIOS, run_scenario
+def build(args: argparse.Namespace, **sc_overrides):
+    """Model, loop, initial state and batch iterator for parsed ``args``.
 
-        names = (
-            list(SCENARIOS)
-            if args.preflight_scenarios == "all"
-            else [s.strip() for s in args.preflight_scenarios.split(",") if s.strip()]
-        )
-        for name in names:
-            res = run_scenario(
-                name, args.workers, compressor=args.compressor,
-                chunk=args.chunk, groups=args.groups,
-                residue_dtype=args.residue_dtype,
-            )
-            print(f"[launch.train] preflight {name}: "
-                  f"dist={res.final_distance:.4f}/{res.tolerance:.4f} "
-                  f"{'ok' if res.passed else 'VIOLATION'}")
-            if not res.passed:
-                for v in res.violations:
-                    print(f"[launch.train]   {v}")
-                raise SystemExit(f"preflight scenario {name!r} failed")
-
-    print(f"[launch.train] {jax_compat.describe()}")
-    if args.residue_dtype.startswith("fp8") and not jax_compat.has_float8():
-        print("[launch.train] float8 unavailable on this jax; "
-              "residues fall back to emulated e4m3 (bf16 storage)")
-
+    ``sc_overrides`` replace ScaleComConfig fields after the CLI has set them
+    (chip_smoke.py pins ``fused`` this way). Returns (cfg, loop, state,
+    batches).
+    """
+    cfg = (registry.arch if args.full_width else registry.smoke)(args.arch)
     model = build_model(cfg, compute_dtype="float32", loss_chunk=64)
     # --bucket-mb: None -> "auto" ($SCALECOM_BUCKET_MB probe), 0 -> force the
     # unbucketed single-shot reduce, > 0 -> bucketed at that size
@@ -148,24 +157,13 @@ def main(argv=None):
         telemetry=args.trace_dir is not None,
         metrics_every=args.metrics_every,
     )
+    sc_cfg = dataclasses.replace(sc_cfg, **sc_overrides)
     opt = make_optimizer(args.optimizer)
     sched = schedule.linear_warmup(schedule.constant(args.lr), args.warmup_steps)
 
     state, _ = init_train_state(
         model, opt, sc_cfg, jax.random.PRNGKey(args.seed), n_workers=args.workers
     )
-    if args.autotune and args.backend != "jnp":
-        from repro.backends import autotune as _at
-
-        wins = _at.autotune_params(
-            state.params, args.chunk, min_size=sc_cfg.min_size
-        )
-        for key, best in wins.items():
-            print(f"[launch.train] autotune {key}: block_chunks={best} "
-                  f"-> {_at.cache_path()}")
-    elif args.autotune:
-        print("[launch.train] --autotune skipped: backend=jnp never consults "
-              "the Pallas tile cache")
     loop = TrainLoop(
         model=model, optimizer=opt, schedule=sched, sc_cfg=sc_cfg,
         n_workers=args.workers, checkpoint_dir=args.checkpoint_dir,
@@ -178,6 +176,50 @@ def main(argv=None):
         d_model=cfg.d_model,
         encoder_seq=cfg.encoder_seq if cfg.is_encdec else 0,
     )
+    return cfg, loop, state, batches
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+
+    if args.preflight_scenarios:
+        from repro.harness.scenarios import SCENARIOS, run_scenario
+
+        names = (
+            list(SCENARIOS)
+            if args.preflight_scenarios == "all"
+            else [s.strip() for s in args.preflight_scenarios.split(",") if s.strip()]
+        )
+        for name in names:
+            res = run_scenario(
+                name, args.workers, compressor=args.compressor,
+                chunk=args.chunk, groups=args.groups,
+                residue_dtype=args.residue_dtype,
+            )
+            print(f"[launch.train] preflight {name}: "
+                  f"dist={res.final_distance:.4f}/{res.tolerance:.4f} "
+                  f"{'ok' if res.passed else 'VIOLATION'}")
+            if not res.passed:
+                for v in res.violations:
+                    print(f"[launch.train]   {v}")
+                raise SystemExit(f"preflight scenario {name!r} failed")
+
+    print(f"[launch.train] {jax_compat.describe()}")
+
+    _, loop, state, batches = build(args)
+    if args.autotune and args.backend != "jnp":
+        from repro.backends import autotune as _at
+
+        wins = _at.autotune_params(
+            state.params, args.chunk, min_size=loop.sc_cfg.min_size
+        )
+        for key, best in wins.items():
+            print(f"[launch.train] autotune {key}: block_chunks={best} "
+                  f"-> {_at.cache_path()}")
+    elif args.autotune:
+        print("[launch.train] --autotune skipped: backend=jnp never consults "
+              "the Pallas tile cache")
     telemetry = None
     if args.trace_dir:
         telemetry = obs.TelemetryRun(

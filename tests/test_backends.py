@@ -623,9 +623,9 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     autotune.clear_cache()
     try:
         best = autotune.autotune(
-            "select", size=1024, chunk=16, candidates=(64, 128), iters=1
+            "select", size=1024, chunk=16, candidates=(1024, 2048), iters=1
         )
-        assert best in (64, 128)
+        assert best in (1024, 2048)
         assert cache.exists()
         # the read path the dispatch layer uses returns the cached winner
         assert autotune.best_block_chunks("select", 64, 16, jnp.float32) == best
@@ -667,14 +667,14 @@ def test_autotune_fused_tile_falls_back_to_ef_update(tmp_path, monkeypatch):
         )
         # an ef_update entry at the same geometry is borrowed
         ef_key = autotune._key("ef_update", 16, jnp.float32, 64)
-        cache.write_text(json.dumps({ef_key: 128}))
+        cache.write_text(json.dumps({ef_key: 2048}))
         autotune.clear_cache()
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 128
+        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 2048
         # ...until the fused op has its own tuned entry
         own_key = autotune._key("fused_reduce", 16, jnp.float32, 64)
-        cache.write_text(json.dumps({ef_key: 128, own_key: 512}))
+        cache.write_text(json.dumps({ef_key: 2048, own_key: 4096}))
         autotune.clear_cache()
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 512
+        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 4096
         # the fallback never launders a stale (non-candidate) geometry
         cache.write_text(json.dumps({ef_key: 7}))
         autotune.clear_cache()
@@ -697,11 +697,11 @@ def test_autotune_sweeps_fused_reduce(tmp_path, monkeypatch):
     autotune.clear_cache()
     try:
         best = autotune.autotune(
-            "fused_reduce", size=256, chunk=16, candidates=(64,), iters=1
+            "fused_reduce", size=256, chunk=16, candidates=(1024,), iters=1
         )
-        assert best == 64
+        assert best == 1024
         # size=256, chunk=16 -> 16 chunk rows x 4 sweep workers = 64 rows
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 64
+        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 1024
         assert any("fused_reduce" in k for k in json.loads(cache.read_text()))
     finally:
         autotune.clear_cache()
@@ -723,9 +723,9 @@ def test_autotune_tolerates_corrupt_cache(tmp_path, monkeypatch, garbage):
         assert autotune.best_block_chunks("select", 64, 16, jnp.float32) == BLOCK_CHUNKS
         # the explicit write path re-sweeps and republishes a valid cache
         best = autotune.autotune(
-            "select", size=256, chunk=16, candidates=(64,), iters=1
+            "select", size=256, chunk=16, candidates=(1024,), iters=1
         )
-        assert best == 64
+        assert best == 1024
         assert isinstance(json.loads(cache.read_text()), dict)
     finally:
         autotune.clear_cache()

@@ -1,5 +1,5 @@
-"""Compat-layer feature detection (both branches, monkeypatched) + residue
-codec round-trip properties.
+"""Compat-layer delegation (monkeypatched) + residue codec round-trip
+properties.
 
 The codec section is the acceptance gate for the stochastic-rounding /
 error-compensation work: the quantized EF trajectory must track the fp32 one
@@ -22,7 +22,7 @@ from repro.core.state import CODECS, codec_key, codec_roundtrip_error, init_stat
 
 
 # ---------------------------------------------------------------------------
-# feature detection — new-API-present branch (faked on 0.4.x)
+# delegation to the installed jax API
 # ---------------------------------------------------------------------------
 
 
@@ -43,22 +43,6 @@ def test_make_mesh_uses_axis_types_when_available(monkeypatch):
     out = jax_compat.make_mesh((2, 2), ("a", "b"))
     assert out == "fake-mesh"
     assert calls["axis_types"] == (_FakeAxisType.Auto, _FakeAxisType.Auto)
-
-
-def test_make_mesh_axis_types_kwarg_absent(monkeypatch):
-    """AxisType exists but make_mesh predates the kwarg -> plain retry."""
-    calls = {"n": 0}
-
-    def fake_make_mesh(shape, axes, *, devices=None, **kw):
-        calls["n"] += 1
-        if "axis_types" in kw:
-            raise TypeError("unexpected keyword argument 'axis_types'")
-        return "plain-mesh"
-
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh, raising=False)
-    monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType, raising=False)
-    assert jax_compat.make_mesh((1,), ("a",)) == "plain-mesh"
-    assert calls["n"] == 2
 
 
 def test_set_mesh_prefers_new_api(monkeypatch):
@@ -85,102 +69,6 @@ def test_shard_map_prefers_top_level(monkeypatch):
     monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
     f = jax_compat.shard_map(lambda x: x, mesh="m", in_specs=(), out_specs=())
     assert f(3) == 3 and seen["mesh"] == "m"
-
-
-# ---------------------------------------------------------------------------
-# feature detection — new-API-absent branch (real on 0.4.x, forced elsewhere)
-# ---------------------------------------------------------------------------
-
-
-def test_make_mesh_mesh_utils_fallback(monkeypatch):
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    n = len(jax.devices())
-    mesh = jax_compat.make_mesh((n,), ("data",))
-    assert isinstance(mesh, jax_compat.Mesh)
-    assert mesh.axis_names == ("data",) and mesh.size == n
-
-
-def test_set_mesh_legacy_context(monkeypatch):
-    monkeypatch.delattr(jax, "set_mesh", raising=False)
-    monkeypatch.delattr(jax.sharding, "use_mesh", raising=False)
-    mesh = jax_compat.make_mesh((len(jax.devices()),), ("data",))
-    with jax_compat.set_mesh(mesh) as m:
-        assert m is mesh
-
-
-def test_shard_map_experimental_fallback(monkeypatch):
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    mesh = jax_compat.make_mesh((len(jax.devices()),), ("data",))
-    P = jax_compat.P
-    n = mesh.size
-    f = jax_compat.shard_map(
-        lambda x: jax.lax.psum(x, "data") * jnp.ones_like(x),
-        mesh=mesh,
-        in_specs=P("data"),
-        out_specs=P("data"),
-    )
-    out = f(jnp.arange(float(n)))
-    np.testing.assert_allclose(np.asarray(out), n * (n - 1) / 2.0)
-
-
-def test_axis_size_fallback_inside_shard_map(monkeypatch):
-    monkeypatch.delattr(jax.lax, "axis_size", raising=False)
-    mesh = jax_compat.make_mesh((len(jax.devices()),), ("data",))
-    f = jax_compat.shard_map(
-        lambda x: x * jax_compat.axis_size("data"),
-        mesh=mesh,
-        in_specs=jax_compat.P("data"),
-        out_specs=jax_compat.P("data"),
-    )
-    np.testing.assert_allclose(
-        np.asarray(f(jnp.ones(mesh.size))), float(mesh.size)
-    )
-
-
-def test_tree_map_with_path_fallback(monkeypatch):
-    tree = {"a": 1, "b": {"c": 2}}
-    expect = jax.tree_util.tree_map_with_path(lambda p, x: x * 10, tree)
-    monkeypatch.delattr(jax.tree_util, "tree_map_with_path", raising=False)
-    got = jax_compat.tree_map_with_path(lambda p, x: x * 10, tree)
-    assert got == expect
-
-
-def test_psum_scatter_fallback_matches_native():
-    mesh = jax_compat.make_mesh((len(jax.devices()),), ("data",))
-    n = mesh.size
-    x = jnp.arange(float(n * n)).reshape(n, n)
-
-    def run(fn):
-        g = jax_compat.shard_map(
-            fn, mesh=mesh, in_specs=jax_compat.P("data", None),
-            out_specs=jax_compat.P("data"),
-        )
-        return np.asarray(g(x))
-
-    native = run(lambda rows: jax.lax.psum_scatter(rows[0], "data", tiled=True))
-
-    def fallback(rows):
-        full = jax.lax.psum(rows[0], "data")
-        idx = jax.lax.axis_index("data")
-        shard = rows.shape[-1] // jax_compat.axis_size("data")
-        return jax.lax.dynamic_slice_in_dim(full, idx * shard, shard, 0)
-
-    np.testing.assert_allclose(run(fallback), native)
-
-
-def test_float8_probe_and_emulated_grid(monkeypatch):
-    # this image ships real float8 — the emulation must land on the same grid
-    assert jax_compat.has_float8()
-    real_dtype = jnp.float8_e4m3fn
-    x = jnp.asarray([0.1337, -3.75, 447.9, 1e-4, 0.0], jnp.float32)
-    native = x.astype(real_dtype).astype(jnp.float32)
-    monkeypatch.delattr(jnp, "float8_e4m3fn", raising=False)
-    assert not jax_compat.has_float8()
-    assert jax_compat.float8_e4m3_dtype() == jnp.bfloat16
-    assert jax_compat.float8_itemsize() == 2
-    emulated = jax_compat.cast_to_e4m3(x).astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(emulated), np.asarray(native), rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -70,6 +70,20 @@ def test_kernel_property_sweep(size, chunk, seed):
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-6)
 
 
+@pytest.mark.parametrize("topm", [1, 2])
+def test_select_breaks_ties_to_the_lowest_lane(topm):
+    """Exact magnitude ties pick the lowest lane, as jnp.argmax and
+    lax.top_k do (full-width random gradients hit a few per step). Small
+    integers make ties in nearly every chunk, signs included."""
+    from repro.backends import resolve_backend
+
+    x = jax.random.randint(jax.random.PRNGKey(3), (4, 4096), -3, 4).astype(jnp.float32)
+    i1, v1 = resolve_backend("pallas").select(x, 64, topm)
+    i2, v2 = resolve_backend("jnp").select(x, 64, topm)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+
+
 def test_kernel_grid_covers_multiple_blocks():
     """Sizes spanning several BLOCK_CHUNKS grid steps (the tiling path)."""
     from repro.kernels.chunk_topk import BLOCK_CHUNKS

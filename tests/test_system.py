@@ -74,9 +74,11 @@ def test_hamming_distance_in_paper_range():
     assert d < 0.9
 
 
-def test_cli_train_driver(tmp_path):
+def test_cli_train_driver(tmp_path, monkeypatch):
     from repro.launch.train import main
 
+    # main() turns on the persistent compile cache; keep it out of the checkout
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
     hist = main([
         "--arch", "paper-transformer-base", "--workers", "2", "--steps", "6",
         "--local-batch", "2", "--seq", "32", "--warmup-steps", "2",
@@ -84,6 +86,54 @@ def test_cli_train_driver(tmp_path):
     ])
     assert np.isfinite(hist[-1]["loss"])
     assert (tmp_path / "h.json").exists()
+
+
+def test_cli_full_width_loads_published_widths(monkeypatch):
+    """--full-width builds registry.arch; the default builds registry.smoke.
+    (The published widths are stood in for by the smoke config so the CPU
+    never builds the full-size model.)"""
+    from repro.launch import train
+
+    asked = []
+
+    def fake_arch(name):
+        asked.append(name)
+        return registry.smoke(name)
+
+    monkeypatch.setattr(registry, "arch", fake_arch)
+    argv = ["--arch", "paper-transformer-base", "--workers", "2",
+            "--local-batch", "1", "--seq", "8"]
+    cfg, _, _, _ = train.build(train.parse_args(argv))
+    assert asked == [] and cfg == registry.smoke("paper-transformer-base")
+    train.build(train.parse_args(argv + ["--full-width"]))
+    assert asked == ["paper-transformer-base"]
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_dir(tmp_path, monkeypatch, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing else is set; unset, the
+    cache goes to the one fixed, git-ignored directory in the checkout."""
+    import pathlib
+
+    from repro.launch.train import enable_compile_cache
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_cli_serve_driver():
