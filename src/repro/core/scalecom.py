@@ -406,44 +406,67 @@ def _execute(
     """
     comp = plan.comp
     G = gw.shape[0]
-    work = gw.reshape((G,) + plan.work)
-    m = codec.decode(enc, plan.storage)
-    if plan.work != plan.storage:
-        m = m.reshape((G,) + plan.work)  # exact path over a rowwise residue
+    # Stage scopes (fold, decode, ef_sum, select, ef_update, worker_mean,
+    # scatter, fused, encode) name each op's part of Algorithm 1 in the
+    # compiled module's op_name metadata; trace-time only.
+    with jax.named_scope("fold"):
+        work = gw.reshape((G,) + plan.work)
+    with jax.named_scope("decode"):
+        m = codec.decode(enc, plan.storage)
+        if plan.work != plan.storage:
+            m = m.reshape((G,) + plan.work)  # exact path over a rowwise residue
     C = work.shape[-1]
     use_fused = fused and not comp.exact and comp.name in FUSABLE_MODES
-    ef = None if use_fused else m + work
+    ef = None
+    if not use_fused:
+        with jax.named_scope("ef_sum"):
+            ef = m + work
 
     if comp.exact:
-        ghat, own, vals, idx = _execute_exact(ef, t, comp, backend)
-        new_m = lowpass_update(m, work, own, beta)
+        with jax.named_scope("select"):
+            ghat, own, vals, idx = _execute_exact(ef, t, comp, backend)
+            ghat = ghat.reshape(plan.shape)
+        with jax.named_scope("ef_update"):
+            new_m = lowpass_update(m, work, own, beta)
     elif use_fused:
         # Single fused op: select over worker-stacked EF, Eq. 5 residue
         # update, ĝ scatter — one kernel launch on the pallas backend, the
         # identical 3-op composition on jnp (backends.base.fused_reduce).
-        leader = (
-            jnp.mod(t, G).astype(jnp.int32) if comp.name == "clt_k" else None
-        )
-        idx, vals, new_m, ghat = backend.fused_reduce(
-            m, work, beta, comp.chunk, comp.topm, comp.name, leader
-        )
+        with jax.named_scope("fused"):
+            leader = (
+                jnp.mod(t, G).astype(jnp.int32) if comp.name == "clt_k" else None
+            )
+            idx, vals, new_m, ghat = backend.fused_reduce(
+                m, work, beta, comp.chunk, comp.topm, comp.name, leader
+            )
+            ghat = ghat.reshape(plan.shape)
     else:
-        idx = select_indices(ef, t, comp, backend)  # shared, or per-worker
+        with jax.named_scope("select"):
+            idx = select_indices(ef, t, comp, backend)  # shared, or per-worker
         # Fused Eq. 5: one pass emits both the residue update and the values
         # each worker contributes to the k-value all-reduce.
-        new_m, vals = backend.ef_update(m, work, idx, beta, comp.chunk, comp.topm)
+        with jax.named_scope("ef_update"):
+            new_m, vals = backend.ef_update(
+                m, work, idx, beta, comp.chunk, comp.topm
+            )
         if comp.name == "local_topk":
             # union-average (gradient build-up): every worker scatters its own
-            ghat = jnp.mean(
-                backend.scatter(vals, idx, comp.chunk, C, comp.topm), axis=0
-            )
+            with jax.named_scope("scatter"):
+                ghat = jnp.mean(
+                    backend.scatter(vals, idx, comp.chunk, C, comp.topm), axis=0
+                ).reshape(plan.shape)
         else:
-            vmean = jnp.mean(vals, axis=0)  # all-reduce of k values
-            ghat = backend.scatter(vmean, idx, comp.chunk, C, comp.topm)
+            with jax.named_scope("worker_mean"):
+                vmean = jnp.mean(vals, axis=0)  # all-reduce of k values
+            with jax.named_scope("scatter"):
+                ghat = backend.scatter(
+                    vmean, idx, comp.chunk, C, comp.topm
+                ).reshape(plan.shape)
 
-    new_enc = codec.encode(
-        new_m.reshape((G,) + plan.storage), plan.storage, key=enc_key
-    )
+    with jax.named_scope("encode"):
+        new_enc = codec.encode(
+            new_m.reshape((G,) + plan.storage), plan.storage, key=enc_key
+        )
     if taps.active():
         if ef is None:
             ef = m + work  # telemetry-only; the fused hot path skips it
@@ -467,12 +490,13 @@ def _execute(
         _tap_execute(
             plan, codec, ef, vals, idx, ghat, new_m, new_enc, t, metrics_every
         )
-    if compute_stats and ef is None:
-        ef = m + work
-    ef_mean = (
-        jnp.mean(ef, axis=0).reshape(plan.shape) if compute_stats else None
-    )
-    return ghat.reshape(plan.shape), new_enc, ef_mean
+    ef_mean = None
+    if compute_stats:
+        with jax.named_scope("stats"):
+            if ef is None:
+                ef = m + work
+            ef_mean = jnp.mean(ef, axis=0).reshape(plan.shape)
+    return ghat, new_enc, ef_mean
 
 
 # ---------------------------------------------------------------------------
@@ -553,10 +577,12 @@ def _reduce(
         computed on the fp32 ghat before the output cast.
         """
         plan = plans[i]
-        gw = _group_fold(g.astype(jnp.float32), plan.groups)
+        with jax.named_scope("fold"):
+            gw = _group_fold(g.astype(jnp.float32), plan.groups)
         if plan.dense:
-            ghat = jnp.mean(gw, axis=0).reshape(plan.shape)
-            return ghat.astype(g.dtype), None, None
+            with jax.named_scope("dense"):
+                ghat = jnp.mean(gw, axis=0).reshape(plan.shape)
+                return ghat.astype(g.dtype), None, None
         # the telemetry taps also want the ef-mean pass (per-tensor gamma);
         # with both off it is never staged
         want_ef = compute_stats or taps.active()
@@ -567,7 +593,8 @@ def _reduce(
         )
         sums = None
         if want_ef:
-            sq = (jnp.sum((ef_mean - ghat) ** 2), jnp.sum(ef_mean**2))
+            with jax.named_scope("stats"):
+                sq = (jnp.sum((ef_mean - ghat) ** 2), jnp.sum(ef_mean**2))
             taps.tap(
                 "contraction_gamma",
                 sq[0] / jnp.maximum(sq[1], 1e-30),
@@ -610,30 +637,31 @@ def _reduce(
                 [out[0] for out in outs], token, overlap=cfg.overlap
             )
 
-    # Accumulation runs in LEAF order regardless of launch schedule, so the
-    # bucketed and unbucketed paths build identical output graphs.
-    new_residues = dict(state.residues)
-    ghat_leaves = []
-    bytes_sent = 0.0  # per-worker payload under the plan's one byte rule
-    bytes_dense = 0.0
-    sq_err = 0.0
-    sq_all = 0.0
-    for plan, (ghat, new_enc, sums) in zip(plans, results):
-        bytes_dense += plan.bytes_dense
-        bytes_sent += plan.bytes_payload
-        ghat_leaves.append(ghat)
-        if new_enc is not None:
-            new_residues[plan.path] = new_enc
-        if sums is not None:
-            sq_err = sq_err + sums[0]
-            sq_all = sq_all + sums[1]
+    with jax.named_scope("stats"):
+        # Accumulation runs in LEAF order regardless of launch schedule, so the
+        # bucketed and unbucketed paths build identical output graphs.
+        new_residues = dict(state.residues)
+        ghat_leaves = []
+        bytes_sent = 0.0  # per-worker payload under the plan's one byte rule
+        bytes_dense = 0.0
+        sq_err = 0.0
+        sq_all = 0.0
+        for plan, (ghat, new_enc, sums) in zip(plans, results):
+            bytes_dense += plan.bytes_dense
+            bytes_sent += plan.bytes_payload
+            ghat_leaves.append(ghat)
+            if new_enc is not None:
+                new_residues[plan.path] = new_enc
+            if sums is not None:
+                sq_err = sq_err + sums[0]
+                sq_all = sq_all + sums[1]
 
-    ghat_tree = jax.tree_util.tree_unflatten(treedef, ghat_leaves)
-    new_state = ScaleComState(residues=new_residues, t=t + 1)
-    stats: Dict[str, Array] = {
-        "comm_bytes_per_worker": jnp.asarray(bytes_sent, jnp.float32),
-        "comm_bytes_dense": jnp.asarray(bytes_dense, jnp.float32),
-    }
-    if compute_stats:
-        stats["contraction_gamma"] = sq_err / jnp.maximum(sq_all, 1e-30)
-    return ghat_tree, new_state, stats
+        ghat_tree = jax.tree_util.tree_unflatten(treedef, ghat_leaves)
+        new_state = ScaleComState(residues=new_residues, t=t + 1)
+        stats: Dict[str, Array] = {
+            "comm_bytes_per_worker": jnp.asarray(bytes_sent, jnp.float32),
+            "comm_bytes_dense": jnp.asarray(bytes_dense, jnp.float32),
+        }
+        if compute_stats:
+            stats["contraction_gamma"] = sq_err / jnp.maximum(sq_all, 1e-30)
+        return ghat_tree, new_state, stats

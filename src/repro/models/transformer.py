@@ -65,10 +65,11 @@ def _init_block(cfg: ArchConfig, store: common.ParamStore, kind: str, stacked: i
 
 
 def _apply_mlp(cfg, p, x, dtype):
-    xn = common.apply_norm(cfg, x, p, "ln_mlp")
-    if "mlp_gate" in p:
-        return x + common.swiglu(p, xn, dtype)
-    return x + common.gelu_mlp(p, xn, dtype)
+    with jax.named_scope("mlp"):
+        xn = common.apply_norm(cfg, x, p, "ln_mlp")
+        if "mlp_gate" in p:
+            return x + common.swiglu(p, xn, dtype)
+        return x + common.gelu_mlp(p, xn, dtype)
 
 
 def _block_train(
@@ -87,11 +88,12 @@ def _block_train(
         x, _ = rglru.rglru_block(cfg, p, x, state, dtype=dtype)
         return _apply_mlp(cfg, p, x, dtype), aux
     causal = kind != "enc"
-    xn = common.apply_norm(cfg, x, p, "ln_attn")
-    x = x + attn.attention_train(
-        cfg, p, xn, positions, dtype=dtype, causal=causal, window=window,
-        rope=kind not in ("enc", "encdec_dec"),  # enc-dec uses sinusoidal
-    )
+    with jax.named_scope("attn"):
+        xn = common.apply_norm(cfg, x, p, "ln_attn")
+        x = x + attn.attention_train(
+            cfg, p, xn, positions, dtype=dtype, causal=causal, window=window,
+            rope=kind not in ("enc", "encdec_dec"),  # enc-dec uses sinusoidal
+        )
     if kind == "encdec_dec":
         xn = common.apply_norm(cfg, x, p, "ln_cross")
         x = x + attn.attention_train(
@@ -163,15 +165,18 @@ class Model:
         cfg = self.cfg
         dt = self.compute_dtype
         tokens = batch["tokens"]
-        x = common.embed_tokens(params, tokens, dt)
-        labels, mask = batch["labels"], batch["mask"].astype(jnp.float32)
-        if cfg.arch_type == "vlm":
-            vis = batch["vision"].astype(dt)  # (B, Tv, D) stub patch embeddings
-            x = jnp.concatenate([vis, x], axis=1)
-            zeros = jnp.zeros(vis.shape[:2], labels.dtype)
-            labels = jnp.concatenate([zeros, labels], axis=1)
-            mask = jnp.concatenate([jnp.zeros(vis.shape[:2], jnp.float32), mask], axis=1)
-        positions = jnp.arange(x.shape[1], dtype=jnp.int32)
+        with jax.named_scope("embed"):
+            x = common.embed_tokens(params, tokens, dt)
+            labels, mask = batch["labels"], batch["mask"].astype(jnp.float32)
+            if cfg.arch_type == "vlm":
+                vis = batch["vision"].astype(dt)  # (B, Tv, D) stub patch embeddings
+                x = jnp.concatenate([vis, x], axis=1)
+                zeros = jnp.zeros(vis.shape[:2], labels.dtype)
+                labels = jnp.concatenate([zeros, labels], axis=1)
+                mask = jnp.concatenate(
+                    [jnp.zeros(vis.shape[:2], jnp.float32), mask], axis=1
+                )
+            positions = jnp.arange(x.shape[1], dtype=jnp.int32)
         return x, positions, labels, mask
 
     def _encode(self, params, frames: Array) -> Tuple[Array, Array]:
@@ -246,8 +251,9 @@ class Model:
                 )
                 aux_total = {k: jnp.mean(v) for k, v in aux_stack.items()}
 
-        x = common.apply_norm(cfg, x, params, "ln_final")
-        nll = common.chunked_xent(params, x, labels, mask, self.loss_chunk, dt)
+        with jax.named_scope("loss"):
+            x = common.apply_norm(cfg, x, params, "ln_final")
+            nll = common.chunked_xent(params, x, labels, mask, self.loss_chunk, dt)
         total = nll
         if "moe_lb_loss" in aux_total:
             total = total + MOE_LB_COEF * aux_total["moe_lb_loss"]

@@ -6,9 +6,9 @@ Three pieces behind one zero-overhead-when-disabled API:
               pytree leaves of the reduce's stats dict (never host
               callbacks); no-ops entirely when telemetry is off
               (``ScaleComConfig.telemetry``).
-  tracing     wall-clock spans around host-side phases (plan, per-bucket
-              reduce, train step), exported as Chrome-trace-event JSON +
-              JSONL events.
+  tracing     host spans around host-side phases (train step, checkpoint),
+              on the profiler's clock while a capture is open, and exported
+              as Chrome-trace-event JSON + JSONL events.
   registry /  host-side metric aggregation, the JSONL event log, shared
   events /    provenance stamps for every BENCH_*.json, and the
   report      ``python -m repro.obs.report`` summarizer.
@@ -38,7 +38,7 @@ from repro.obs.provenance import device_tags, git_sha
 from repro.obs.provenance import provenance as provenance_stamp
 from repro.obs.registry import MetricRegistry
 from repro.obs.run import TelemetryRun
-from repro.obs.tracing import Tracer, measured_bucket_timeline
+from repro.obs.tracing import Tracer
 
 __all__ = [
     "EventLog",
@@ -50,7 +50,6 @@ __all__ = [
     "events",
     "get_logger",
     "git_sha",
-    "measured_bucket_timeline",
     "provenance",
     "provenance_stamp",
     "read_events",

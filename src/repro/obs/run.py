@@ -2,7 +2,8 @@
 
 One object wiring the three telemetry pieces together for one run directory:
 
-  tracer      wall-clock spans (obs.tracing) -> ``trace.json`` (Chrome trace)
+  tracer      host spans (obs.tracing) -> ``trace.json`` (Chrome trace), and
+              the profiler's host plane while a capture is open
   events      JSONL event log (obs.events)   -> ``events.jsonl``
   registry    host-side metrics (obs.registry), summarized into the log
 
@@ -50,7 +51,8 @@ class TelemetryRun:
 
     def step_span(self, step: int, **args: Any):
         """Span covering one train-loop step (host-side, includes dispatch +
-        the device sync the metrics conversion forces)."""
+        the device sync the metrics conversion forces); ``step`` on the
+        profiler's host plane too, around the loop's ``train_step`` marker."""
         return self.tracer.span("step", step=step, **args)
 
     def record_step(self, step: int, metrics: Mapping[str, Any]) -> None:

@@ -1,28 +1,25 @@
-"""Wall-clock span tracing with Chrome-trace-event export.
+"""Host spans, on the profiler's clock and as a Chrome-trace-event export.
 
-Spans time the HOST-side phases of a run — plan, per-bucket reduce, train
-step, checkpoint — from OUTSIDE any jitted function. A timer inside the
-traced reduce would either be a trace-time constant (useless) or a host
-callback (the overhead the whole telemetry design exists to avoid); the
-scalecheck rule ``obs-hot-path`` rejects both, so the probes here measure
-jitted computations the only honest way: call, ``block_until_ready``, stamp
-the clock around it.
+Spans mark the HOST-side phases of a run — train step, checkpoint, harness
+violations — from OUTSIDE any jitted function. A timer inside the traced
+reduce would either be a trace-time constant (useless) or a host callback
+(the overhead the whole telemetry design exists to avoid); the scalecheck
+rule ``obs-hot-path`` rejects both. Device time is read from a
+``jax.profiler`` trace instead, split by the step's named scopes.
+
+Each span and instant also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so that while a profiler capture is open it lands on the
+profiler's host plane, on the device trace's clock: an idle gap on the
+device can be put down to the host phase that overlaps it. With no capture
+open an annotation costs one inactive TraceMe.
 
 Export formats:
 
   * ``chrome_trace()``   the Trace Event Format dict (``traceEvents`` of
-    complete ``"ph": "X"`` events, microsecond timestamps) that
-    chrome://tracing and Perfetto load directly;
+    complete ``"ph": "X"`` events, microsecond timestamps on the host clock)
+    that chrome://tracing and Perfetto load directly;
   * ``to_events()``      plain dicts for the JSON-lines event log
     (repro.obs.events), one ``{"type": "span", ...}`` record per span.
-
-``measured_bucket_timeline`` is the standing probe the ISSUE asks for: the
-first *measured* per-bucket timeline of the bucketed reduce
-(core.plan.plan_buckets + core.overlap) to set against the modeled one from
-``analysis.perfmodel.overlap_timeline``. On a single-device container the
-buckets cannot actually overlap anything, so the measured spans quantify
-per-bucket compress+reduce cost and launch overhead, not hidden fractions —
-the trace stamps ``device_kind`` so TPU runs are distinguishable.
 """
 
 from __future__ import annotations
@@ -34,7 +31,7 @@ import os
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
-__all__ = ["Span", "Tracer", "measured_bucket_timeline"]
+__all__ = ["Span", "Tracer"]
 
 
 @dataclasses.dataclass
@@ -46,6 +43,13 @@ class Span:
     dur_us: float
     tid: int = 0
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+def _annotation(name: str):
+    # imported at call time: repro.obs keeps no jax at module scope
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class Tracer:
@@ -75,13 +79,16 @@ class Tracer:
         """
         s = Span(name=name, ts_us=self.now_us(), dur_us=0.0, tid=tid, args=args)
         try:
-            yield s
+            with _annotation(name):
+                yield s
         finally:
             s.dur_us = self.now_us() - s.ts_us
             self.spans.append(s)
 
     def instant(self, name: str, tid: int = 0, **args: Any) -> None:
         """A zero-duration marker (violations, re-plans, phase switches)."""
+        with _annotation(name):
+            pass
         self.spans.append(
             Span(name=name, ts_us=self.now_us(), dur_us=0.0, tid=tid, args=args)
         )
@@ -129,112 +136,3 @@ class Tracer:
             }
             for s in self.spans
         ]
-
-
-def measured_bucket_timeline(
-    grads_pw: Any,
-    cfg: Any,
-    *,
-    buckets: Any = True,
-    tracer: Optional[Tracer] = None,
-) -> Dict[str, Any]:
-    """Measure the bucketed reduce per bucket and return spans + model.
-
-    grads_pw: worker-stacked gradient pytree ((n, *shape) leaves); cfg: a
-    ScaleComConfig. Resolves the same bucket schedule the real launch uses,
-    then times (a) the plan stage, (b) each bucket's compress+reduce as an
-    isolated jitted reduce over just that bucket's tensors, and (c) the full
-    bucketed reduce — each with ``block_until_ready`` so the spans cover
-    device completion, not dispatch. Spans land on the given/new Tracer
-    (bucket i on tid i+1) and the modeled timeline from
-    ``analysis.perfmodel.overlap_timeline`` rides along in the return value
-    for side-by-side reporting.
-
-    Imports are call-time on purpose: core.overlap imports repro.obs.taps, so
-    a module-level import of repro.core here would be a cycle.
-    """
-    import jax
-
-    from repro.analysis import perfmodel
-    from repro.core import overlap
-    from repro.core.plan import plan_tensors
-    from repro.core.scalecom import scalecom_reduce
-    from repro.core.state import init_state, residue_signature
-
-    tracer = tracer or Tracer()
-    leaves, _ = jax.tree_util.tree_flatten(grads_pw)
-    n = leaves[0].shape[0]
-    params_like = jax.tree.map(lambda g: g[0], grads_pw)
-    state = init_state(
-        params_like,
-        cfg.n_workers(n),
-        cfg.residue_dtype,
-        cfg.min_size,
-        cfg.layout,
-    )
-
-    with tracer.span("plan", n_tensors=len(leaves)):
-        flat = jax.tree_util.tree_flatten_with_path(grads_pw)[0]
-        plans = plan_tensors(
-            tuple(
-                (jax.tree_util.keystr(p), tuple(g.shape[1:]), g.shape[0])
-                for p, g in flat
-            ),
-            cfg,
-            residue_signature(state.residues),
-        )
-    schedule = overlap.resolve_buckets(buckets, cfg, plans) or ()
-
-    def _timed_reduce(tree, st, spec):
-        fn = jax.jit(lambda g, s: scalecom_reduce(g, s, cfg, buckets=spec))
-        jax.block_until_ready(fn(tree, st))  # compile outside the span
-        t0 = tracer.now_us()
-        jax.block_until_ready(fn(tree, st))
-        return tracer.now_us() - t0
-
-    bucket_rows = []
-    for b in schedule:
-        sub = {f"leaf{i}": flat[i][1] for i in b.leaf_ids}
-        sub_state = init_state(
-            {k: v[0] for k, v in sub.items()},
-            cfg.n_workers(n),
-            cfg.residue_dtype,
-            cfg.min_size,
-            cfg.layout,
-        )
-        with tracer.span(
-            f"bucket[{b.index}]",
-            tid=b.index + 1,
-            bytes_dense=b.bytes_dense,
-            bytes_payload=b.bytes_payload,
-            n_leaves=len(b.leaf_ids),
-        ) as s:
-            s.args["reduce_us"] = _timed_reduce(sub, sub_state, False)
-        bucket_rows.append(
-            {
-                "bucket": b.index,
-                "bytes_dense": b.bytes_dense,
-                "bytes_payload": b.bytes_payload,
-                "measured_us": s.args["reduce_us"],
-            }
-        )
-
-    with tracer.span("reduce/full", bucketed=bool(schedule)) as s:
-        s.args["reduce_us"] = _timed_reduce(grads_pw, state, buckets)
-
-    bucket_bytes = overlap.resolve_bucket_bytes(buckets, cfg.bucket_bytes)
-    scheme = "local_topk" if cfg.compressor.name == "local_topk" else "scalecom"
-    modeled = (
-        perfmodel.overlap_report(
-            perfmodel.reference_transformer_perf(), scheme, bucket_bytes
-        )
-        if bucket_bytes
-        else None
-    )
-    return {
-        "tracer": tracer,
-        "buckets": bucket_rows,
-        "full_us": s.args["reduce_us"],
-        "modeled": modeled,
-        "device_kind": jax.devices()[0].device_kind,
-    }

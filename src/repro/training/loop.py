@@ -16,6 +16,7 @@ reduce emits under ``ScaleComConfig.telemetry``) without console noise.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
@@ -70,13 +71,25 @@ class TrainLoop:
             donate_argnums=(0,),
         )
 
-    def step(self, state: TrainState, batch, step_idx: int):
+    def _program(self, step_idx: int):
         compressed = (
             self.sc_cfg.compressor.name != "none"
             and step_idx >= self.sc_cfg.warmup_steps
         )
-        fn = self._compressed if compressed else self._dense
-        return fn(state, batch)
+        return self._compressed if compressed else self._dense
+
+    def step(self, state: TrainState, batch, step_idx: int):
+        # a step marker on the profiler's host plane, on the device trace's
+        # clock; one inactive TraceMe when no profiler is open
+        with jax.profiler.StepTraceAnnotation("train_step", step_num=step_idx):
+            return self._program(step_idx)(state, batch)
+
+    def compiled(self, state: TrainState, batch, step_idx: int) -> jax.stages.Compiled:
+        """The compiled program ``step`` runs for these arguments (JAX's
+        in-memory cache once ``step`` has run them). Its ``as_text()`` is the
+        optimized module whose op_name metadata carries the step's phase
+        scopes."""
+        return self._program(step_idx).lower(state, batch).compile()
 
 
 def run_training(
@@ -95,10 +108,11 @@ def run_training(
                unless a handler is attached (obs.enable_console_logging), so
                library consumers are quiet by default. ``None`` silences
                entirely; ``print`` restores the historical console output.
-    telemetry: an ``obs.TelemetryRun``: every step gets a wall-clock span and
-               a ``step`` event carrying the full metrics dict (converting
-               the metrics is a per-step device sync — the honest cost of
-               per-step observability). The caller closes the run.
+    telemetry: an ``obs.TelemetryRun``: every step gets a span and a
+               ``step`` event carrying the full metrics dict (converting the
+               metrics is a per-step device sync — the honest cost of
+               per-step observability), and every checkpoint save a
+               ``checkpoint`` span. The caller closes the run.
     """
     if log is _LOGGER:
         log = obs.get_logger("training").info
@@ -131,5 +145,10 @@ def run_training(
         ):
             from repro import checkpoint
 
-            checkpoint.save(loop.checkpoint_dir, i, state)
+            with (
+                telemetry.tracer.span("checkpoint")
+                if telemetry is not None
+                else contextlib.nullcontext()
+            ):
+                checkpoint.save(loop.checkpoint_dir, i, state)
     return state, history

@@ -179,15 +179,23 @@ def build_train_step(
         return loss, auxs, grads
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Array]]:
+        # The phase scopes name every op of the step in the compiled module's
+        # op_name metadata (fwd_bwd / reduce / optimizer); a device trace is
+        # split by phase through them. Trace-time only: no runtime cost.
         if mode == "scalecom":
-            loss, auxs, gpw = per_worker_grads(state.params, batch)
-            ghat, sc_state, stats = scalecom_reduce(
-                gpw, state.sc_state, sc_cfg, compute_stats=compute_stats,
-                buckets=buckets,
-            )
-            ghat = _pin_reduced(ghat)
+            with jax.named_scope("fwd_bwd"):
+                loss, auxs, gpw = per_worker_grads(state.params, batch)
+                auxs = {k: jnp.mean(v) for k, v in auxs.items()}
+            with jax.named_scope("reduce"):
+                ghat, sc_state, stats = scalecom_reduce(
+                    gpw, state.sc_state, sc_cfg, compute_stats=compute_stats,
+                    buckets=buckets,
+                )
+                ghat = _pin_reduced(ghat)
         elif mode == "dense":
-            loss, auxs, grads = dense_grads(state.params, batch)
+            with jax.named_scope("fwd_bwd"):
+                loss, auxs, grads = dense_grads(state.params, batch)
+                auxs = {k: jnp.mean(v) for k, v in auxs.items()}
             ghat = grads
             sc_state = ScaleComState(
                 residues=state.sc_state.residues, t=state.sc_state.t + 1
@@ -196,21 +204,24 @@ def build_train_step(
         else:
             raise ValueError(mode)
 
-        gnorm = _global_norm(ghat)
-        if grad_clip is not None:
-            scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
-            ghat = jax.tree.map(lambda g: g * scale, ghat)
-
-        lr = schedule(state.step)
-        params, opt_state = optimizer.update(ghat, state.opt_state, state.params, lr)
+        with jax.named_scope("optimizer"):
+            gnorm = _global_norm(ghat)
+            if grad_clip is not None:
+                scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12))
+                ghat = jax.tree.map(lambda g: g * scale, ghat)
+            lr = schedule(state.step)
+            params, opt_state = optimizer.update(
+                ghat, state.opt_state, state.params, lr
+            )
+            step = state.step + 1
         metrics = {
             "loss": loss,
             "grad_norm": gnorm,
             "lr": lr,
-            **{k: jnp.mean(v) for k, v in auxs.items()},
+            **auxs,
             **stats,
         }
-        new_state = TrainState(params, opt_state, sc_state, state.step + 1)
+        new_state = TrainState(params, opt_state, sc_state, step)
         return new_state, metrics
 
     return train_step

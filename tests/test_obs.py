@@ -33,7 +33,7 @@ from repro.core.state import init_state
 from repro.obs import report, taps
 from repro.obs.events import EventLog, read_events
 from repro.obs.registry import MetricRegistry
-from repro.obs.tracing import Tracer, measured_bucket_timeline
+from repro.obs.tracing import Tracer
 
 CHUNK = 8
 _TREE_SIZES = {"a": (96,), "b": (24, 16), "c": (520,), "tiny": (16,)}
@@ -297,21 +297,31 @@ def test_span_recorded_even_if_body_raises():
     assert [s.name for s in tr.spans] == ["doomed"]
 
 
-def test_measured_bucket_timeline_smoke():
-    cfg = _cfg(min_size=1)
-    n = 4
-    g = {
-        k: jax.random.normal(jax.random.PRNGKey(i), (n,) + s)
-        for i, (k, s) in enumerate(_TREE_SIZES.items())
-    }
-    out = measured_bucket_timeline(g, cfg, buckets=1024)
-    assert len(out["buckets"]) >= 2
-    assert all(r["measured_us"] > 0 for r in out["buckets"])
-    assert out["full_us"] > 0
-    assert out["modeled"] is not None and "hidden_fraction" in out["modeled"]
-    names = [s.name for s in out["tracer"].spans]
-    assert names[0] == "plan" and names[-1] == "reduce/full"
-    assert any(nm.startswith("bucket[") for nm in names)
+def test_spans_and_instants_open_profiler_annotations(monkeypatch):
+    # each span and instant opens a jax.profiler.TraceAnnotation of its name,
+    # so that it lands on the profiler's host plane while a capture is open
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    tr = Tracer()
+    with tr.span("step"):
+        opened.append(("body", "step"))
+    tr.instant("violation", message="boom")
+    assert opened == [
+        ("enter", "step"), ("body", "step"), ("exit", "step"),
+        ("enter", "violation"), ("exit", "violation"),
+    ]
+    assert [s.name for s in tr.spans] == ["step", "violation"]
 
 
 # ---------------------------------------------------------------------------
@@ -441,16 +451,16 @@ def test_report_module_invocation(traced_run):
 # ---------------------------------------------------------------------------
 
 
-def test_run_training_quiet_by_default_and_telemetry(tmp_path, capsys):
+def _tiny_loop(sc_cfg, **loop_kw):
+    """(loop, state, batches) of the smoke paper transformer on 2 workers."""
     from repro.configs import registry as cfg_registry
     from repro.data import make_batches
     from repro.models import build_model
     from repro.optim import make_optimizer, schedule
-    from repro.training import TrainLoop, init_train_state, run_training
+    from repro.training import TrainLoop, init_train_state
 
     arch = cfg_registry.smoke("paper-transformer-base")
     model = build_model(arch, compute_dtype="float32", loss_chunk=16)
-    sc_cfg = _cfg(telemetry=True, warmup_steps=1)
     opt = make_optimizer("sgdm")
     sched = schedule.constant(0.05)
     state, _ = init_train_state(
@@ -458,9 +468,17 @@ def test_run_training_quiet_by_default_and_telemetry(tmp_path, capsys):
     )
     loop = TrainLoop(
         model=model, optimizer=opt, schedule=sched, sc_cfg=sc_cfg,
-        n_workers=2, log_every=1,
+        n_workers=2, **loop_kw,
     )
-    batches = make_batches(arch.vocab, 2, 2, 16, seed=0)
+    return loop, state, make_batches(arch.vocab, 2, 2, 16, seed=0)
+
+
+def test_run_training_quiet_by_default_and_telemetry(tmp_path, capsys):
+    from repro.training import run_training
+
+    loop, state, batches = _tiny_loop(
+        _cfg(telemetry=True, warmup_steps=1), log_every=1
+    )
     with obs.TelemetryRun(str(tmp_path)) as run:
         _, history = run_training(loop, state, batches, 3, telemetry=run)
         paths = run.close()
@@ -471,3 +489,33 @@ def test_run_training_quiet_by_default_and_telemetry(tmp_path, capsys):
     assert len(steps) == 3
     # the obs/ tap leaves ride through the train step's metrics dict
     assert any(k.startswith("obs/") for k in steps[-1]["metrics"])
+
+
+def test_run_training_spans_each_checkpoint_save(tmp_path):
+    from repro.training import run_training
+
+    loop, state, batches = _tiny_loop(
+        _cfg(), checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=2
+    )
+    with obs.TelemetryRun(str(tmp_path / "trace")) as run:
+        run_training(loop, state, batches, 5, log=None, telemetry=run)
+        names = [s.name for s in run.tracer.spans]
+    # saves after steps 2 and 4, each inside the run's span list
+    assert names.count("checkpoint") == 2
+    assert names.count("step") == 5
+    assert sorted(os.listdir(tmp_path / "ckpt"))
+
+
+def test_train_loop_compiled_is_the_program_step_runs():
+    loop, state, batches = _tiny_loop(_cfg(backend="jnp"))
+    batch = next(batches)
+    compiled = loop.compiled(state, batch, 0)
+    assert isinstance(compiled, jax.stages.Compiled)
+    text = compiled.as_text()
+    for phase in ("fwd_bwd", "reduce", "optimizer"):
+        assert f'op_name="jit(train_step)/{phase}/' in text
+    # the same program: the compiled step reads the loss the loop's step does
+    # (the compiled program donates its state, so it gets a copy)
+    _, via_compiled = compiled(jax.tree.map(jnp.copy, state), batch)
+    _, via_step = loop.step(state, batch, 0)
+    assert float(via_compiled["loss"]) == float(via_step["loss"])
