@@ -1,12 +1,14 @@
 """Tile-geometry autotuner for the Pallas kernel backend.
 
-The chunk kernels stream (block_chunks, chunk) tiles; the right
-``block_chunks`` depends on chunk size, dtype itemwidth (bf16 tiles are
-(16,128) vs fp32 (8,128)), problem size, and the device generation's VMEM
-budget. This module sweeps the candidate geometries on the live device and
-caches the winner on disk keyed by device kind, so the sweep runs once per
-(device, op, chunk, dtype, size-bucket) and every later process start is a
-dict lookup.
+``block_chunks`` is the number of chunks one grid step of a chunk kernel
+covers, in either tile geometry (kernels.chunk_topk): a (block_chunks,
+chunk) tile of the rows geometry, a (block_chunks * chunk / 128, 128)
+lane-dense tile. The right value depends on chunk size, dtype itemwidth
+(bf16 tiles are (16,128) vs fp32 (8,128)), problem size, and the device
+generation's VMEM budget. This module sweeps the candidate values on the
+live device and caches the winner on disk keyed by device kind, so the
+sweep runs once per (device, op, chunk, dtype, size-bucket) and every later
+process start is a dict lookup.
 
 Cache file: ``$SCALECOM_AUTOTUNE_CACHE`` if set, else
 ``~/.cache/scalecom/autotune.json``. Entries are plain JSON so they can be
@@ -51,9 +53,9 @@ _OPS = ("select", "ef_update", "fused_reduce")
 
 # Tile-geometry fallback chain: an op with no cache entry of its own borrows
 # the tuned tile of the op it most resembles before giving up to the kernel
-# default. fused_reduce streams the same (block_chunks, chunk) data tiles as
-# ef_update (just with the worker axis resident), so an ef_update sweep is a
-# far better prior than the untuned default.
+# default. fused_reduce streams the same chunks per grid step as ef_update
+# (just with the worker axis resident), so an ef_update sweep is a far
+# better prior than the untuned default.
 _TILE_FALLBACK = {"fused_reduce": "ef_update"}
 
 _cache: Optional[Dict[str, int]] = None  # in-process mirror of the file
@@ -125,8 +127,12 @@ def clear_cache() -> None:
     _cache = None
 
 
-def best_block_chunks(op: str, n_chunks: int, chunk: int, dtype) -> int:
-    """Cached tile height for ``op``, or the kernel default on a miss.
+def best_block_chunks(
+    op: str, n_chunks: int, chunk: int, dtype, default: Optional[int] = None
+) -> int:
+    """Cached chunks a grid step of ``op`` covers, or ``default`` on a miss
+    (the kernels' ``BLOCK_CHUNKS`` unless given: the launch's geometry may
+    want its own, see ``PallasBackend._block``).
 
     Cheap enough for the per-launch dispatch path: one dict lookup after the
     first call (two on a fallback-chain hop — see ``_TILE_FALLBACK``; e.g.
@@ -139,15 +145,17 @@ def best_block_chunks(op: str, n_chunks: int, chunk: int, dtype) -> int:
 
     if op not in _OPS:
         raise ValueError(f"unknown autotune op {op!r}; known ops: {_OPS}")
+    if default is None:
+        default = BLOCK_CHUNKS
     cache = _load()
     got = cache.get(_key(op, chunk, dtype, n_chunks))
     if got is None and op in _TILE_FALLBACK:
         got = cache.get(_key(_TILE_FALLBACK[op], chunk, dtype, n_chunks))
     if got is None:
-        return BLOCK_CHUNKS
+        return default
     # Guard against stale caches written with a candidate set we no longer
     # ship — fall back to the default rather than an untested geometry.
-    return got if got in CANDIDATE_BLOCKS else BLOCK_CHUNKS
+    return got if got in CANDIDATE_BLOCKS else default
 
 
 def _time_once(fn, *args, iters: int = 3) -> float:

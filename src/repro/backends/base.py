@@ -53,6 +53,9 @@ Derived ops that backends override for fusion:
                                              exact).
 
 so a minimal backend is exactly {select_indices, gather, scatter}.
+``lane_dense(shape, chunk, dtype)`` reports whether a kernel backend tiles
+such an array lane-dense (the telemetry's ``lane_dense`` tap); the base
+answers False.
 
 Whether the reduce *calls* fused_reduce is a separate, orthogonal resolution:
 ``resolve_fused(spec)`` with spec True/False/"auto" ("auto" = the
@@ -144,6 +147,13 @@ class KernelBackend:
         chunk are dropped by the final slice to ``size``.
         """
         raise NotImplementedError
+
+    def lane_dense(self, shape, chunk: int, dtype) -> bool:
+        """Whether the 3-launch ops stream a chunked (..., n) array of
+        ``shape`` as lane-dense tiles (full 128-lane rows, several chunks a
+        row). A static fact of the shapes; only a kernel backend has tiles,
+        so the default answers False."""
+        return False
 
     # -- derived (override for fusion) ------------------------------------
 

@@ -16,7 +16,10 @@ in *both* layouts: every op goes through the trailing-axis wrappers in
 kernels.rowwise (kernels.chunk_topk row launchers underneath), so a flat
 1-D buffer and a layout-preserving (n_workers, *param_shape) tensor take
 the identical code path — the backend pads the trailing axis to a chunk
-multiple here and slices dense outputs back.
+multiple here and slices dense outputs back (both no-ops when the axis is
+a chunk multiple). The tile geometry follows the shapes
+(``chunk_topk.lane_dense``, reported by ``lane_dense``): full-width flat
+buffers take lane-dense tiles, which the kernels read and write in place.
 
 Execution mode is a call-time probe: native Mosaic lowering when
 jax.default_backend() == "tpu", interpret mode elsewhere (the same math at
@@ -33,6 +36,7 @@ via resolve_backend("pallas") raises a clear error on jax builds without it.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,16 +66,26 @@ class PallasBackend(KernelBackend):
             return self._interpret
         return jax.default_backend() != "tpu"
 
-    @staticmethod
-    def _block(op: str, x: Array, chunk: int) -> int:
-        # Key by the TOTAL tile rows of the launch (worker/leading axes
-        # included): a (G, size) launch covers G x n_chunks rows, i.e. the
+    def _block(self, op: str, shape, chunk: int, dtype) -> int:
+        # Key by the TOTAL chunks of the launch (worker/leading axes
+        # included): a (G, size) launch covers G x n_chunks chunks, i.e. the
         # same geometry problem autotune() times on a 1-D input of equal
         # total size (the size key is bucketed to powers of two anyway).
-        n_chunks = -(-x.shape[-1] // chunk)
-        for d in x.shape[:-1]:
-            n_chunks *= d
-        return autotune.best_block_chunks(op, n_chunks, chunk, x.dtype)
+        # An untuned lane-dense launch takes the lane-dense default.
+        from repro.kernels.chunk_topk import BLOCK_CHUNKS, DENSE_BLOCK_CHUNKS
+
+        n_chunks = -(-shape[-1] // chunk) * math.prod(shape[:-1])
+        dense = op != "fused_reduce" and self.lane_dense(shape, chunk, dtype)
+        return autotune.best_block_chunks(
+            op, n_chunks, chunk, dtype,
+            default=DENSE_BLOCK_CHUNKS if dense else BLOCK_CHUNKS,
+        )
+
+    def lane_dense(self, shape, chunk: int, dtype) -> bool:
+        from repro.kernels.chunk_topk import lane_dense
+
+        cp = -(-shape[-1] // chunk) * chunk
+        return lane_dense(chunk, cp, math.prod(shape[:-1]) * cp, dtype)
 
     def select_indices(self, x: Array, chunk: int, topm: int = 1) -> Array:
         return self.select(x, chunk, topm)[0]
@@ -81,7 +95,7 @@ class PallasBackend(KernelBackend):
 
         return rowwise.select_trailing(
             _padded(x, chunk), chunk, topm, interpret=self._interp(),
-            block_chunks=self._block("select", x, chunk),
+            block_chunks=self._block("select", x.shape, chunk, x.dtype),
         )
 
     def gather(self, x: Array, idx: Array, chunk: int, topm: int = 1) -> Array:
@@ -89,7 +103,7 @@ class PallasBackend(KernelBackend):
 
         return rowwise.gather_trailing(
             _padded(x, chunk), idx, chunk, topm, interpret=self._interp(),
-            block_chunks=self._block("select", x, chunk),
+            block_chunks=self._block("select", x.shape, chunk, x.dtype),
         )
 
     def scatter(
@@ -98,17 +112,15 @@ class PallasBackend(KernelBackend):
         from repro.kernels import rowwise
 
         n_chunks = -(-size // chunk)
-        # autotune key: TOTAL launch rows incl. broadcast leading dims,
+        # autotune key: TOTAL launch chunks incl. broadcast leading dims,
         # matching _block's convention for the other ops
         tail = 1 if topm == 1 else 2
-        rows = n_chunks
-        for d in jnp.broadcast_shapes(idx.shape[:-tail], vals.shape[:-tail]):
-            rows *= d
+        lead = jnp.broadcast_shapes(idx.shape[:-tail], vals.shape[:-tail])
         out = rowwise.scatter_trailing(
             vals, idx, chunk, n_chunks * chunk, topm=topm,
             interpret=self._interp(),
-            block_chunks=autotune.best_block_chunks(
-                "select", rows, chunk, vals.dtype
+            block_chunks=self._block(
+                "select", tuple(lead) + (n_chunks * chunk,), chunk, vals.dtype
             ),
         )
         return out[..., :size]
@@ -123,7 +135,7 @@ class PallasBackend(KernelBackend):
         m_new, vals = rowwise.ef_update_trailing(
             _padded(m, chunk), _padded(g, chunk), idx, beta, chunk, topm,
             interpret=self._interp(),
-            block_chunks=self._block("ef_update", m, chunk),
+            block_chunks=self._block("ef_update", m.shape, chunk, m.dtype),
         )
         return m_new[..., :n], vals
 
@@ -143,7 +155,7 @@ class PallasBackend(KernelBackend):
             _padded(m, chunk), _padded(g, chunk), leader, float(beta),
             chunk, topm, mode,
             interpret=self._interp(),
-            block_chunks=self._block("fused_reduce", m, chunk),
+            block_chunks=self._block("fused_reduce", m.shape, chunk, m.dtype),
         )
         return idx, vals, m_new[..., :n], ghat[..., :n]
 

@@ -479,6 +479,19 @@ def _execute(
             path=plan.path,
             compressor=comp.name,
         )
+        # Whether the 3-launch kernels streamed this tensor as lane-dense
+        # tiles (chunk_topk.lane_dense): a static fact of its shapes.
+        dense_tiles = (
+            not comp.exact
+            and not use_fused
+            and backend.lane_dense(work.shape, comp.chunk, work.dtype)
+        )
+        taps.tap(
+            "lane_dense",
+            jnp.asarray(1.0 if dense_tiles else 0.0, jnp.float32),
+            path=plan.path,
+            size=plan.size,
+        )
         taps.tap(
             "fused_launches",
             jnp.asarray(

@@ -10,9 +10,12 @@ Unfused HLO runs 3+ passes over the gradient (add, gather, scatter, axpy) —
 each HBM-bandwidth bound. This kernel does one read of (m, g, idx) and one
 write of (m', vals) per tile: ~2.3x less HBM traffic for the residue update,
 which matters because the residue array is n_workers x P — the largest state
-in the system (measured sweep: benchmarks/bench_kernels.py). Tiles are
-(block_chunks, chunk) in VMEM like chunk_topk; ``block_chunks`` is autotuned
-by repro.backends.autotune.
+in the system (measured sweep: benchmarks/bench_kernels.py). Tiles follow
+chunk_topk's two geometries — (block_chunks, chunk) rows, or lane-dense
+(block_chunks * chunk / 128, 128) tiles where ``chunk_topk.lane_dense``
+holds, with the shared per-chunk offsets spread over their chunk's lanes by
+in-vreg lane gathers; ``block_chunks`` is autotuned by
+repro.backends.autotune.
 
 ``beta`` is a *static* kernel parameter, closed over with functools.partial
 and folded into the tile arithmetic at compile time. (It used to be passed as
@@ -36,7 +39,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.chunk_topk import BLOCK_CHUNKS, _flat_view, _pad_rows
+from repro.kernels.chunk_topk import (
+    BLOCK_CHUNKS,
+    _bits,
+    _flat_view,
+    _iota,
+    _load_compact,
+    _pad_rows,
+    _pick,
+    _rows_of,
+    _spread,
+    _store_compact,
+    compact_len,
+    dense_block,
+    dense_specs,
+    join_picks,
+    split_picks,
+)
 
 __all__ = ["ef_update_pallas"]
 
@@ -61,12 +80,54 @@ def _ef_update_kernel(m_ref, g_ref, idx_ref, m_out_ref, val_ref, *, beta: float)
     m_out_ref[...] = m + beta * (g - own)
 
 
-def row_ef_update(m2d, g2d, idx, beta, *, interpret, block_chunks):
-    """(rows, chunk) m/g + per-row idx -> (m', vals); grid/padding here.
+def _dense_ef_update_kernel(m_ref, g_ref, *refs, beta: float, chunk: int, topm: int):
+    """Lane-dense tiles of m, g + topm idx blocks -> m' tile, topm value blocks."""
+    idx_refs, m_out_ref = refs[:topm], refs[topm]
+    val_refs, scratch = refs[topm + 1 : 2 * topm + 1], refs[-1]
+    m = m_ref[...]
+    g = g_ref[...]
+    ef = m + g
+    efb = _bits(ef)
+    lane = _iota(m.shape, 1) % chunk
+    hit = None
+    for j in range(topm):  # top-m: selected offsets are distinct
+        at = _rows_of(_load_compact(idx_refs[j]) & (chunk - 1), chunk)
+        _store_compact(val_refs[j], _pick(efb, at, chunk), scratch)
+        own = _spread(at, chunk) == lane
+        hit = own if hit is None else hit | own
+    # ghat_own = vals scattered at idx; m' = m + beta*(g - ghat_own)
+    m_out_ref[...] = m + beta * (g - jnp.where(hit, ef, jnp.zeros((), ef.dtype)))
+
+
+def _dense_ef_update(m2d, g2d, idx, beta, chunk, interpret, block_chunks):
+    topm = 1 if idx.ndim == 1 else idx.shape[1]
+    n = idx.shape[0]
+    block_chunks = dense_block(n, block_chunks)
+    tile, per_chunk, scratch = dense_specs(chunk, block_chunks)
+    outs = pl.pallas_call(
+        functools.partial(
+            _dense_ef_update_kernel, beta=float(beta), chunk=chunk, topm=topm
+        ),
+        grid=(pl.cdiv(n, block_chunks),),
+        in_specs=[tile, tile] + [per_chunk] * topm,
+        out_specs=[tile] + [per_chunk] * topm,
+        out_shape=[jax.ShapeDtypeStruct(m2d.shape, m2d.dtype)]
+        + [jax.ShapeDtypeStruct((compact_len(n),), m2d.dtype)] * topm,
+        scratch_shapes=[scratch],
+        interpret=interpret,
+    )(m2d, g2d, *split_picks(idx, topm))
+    return outs[0], join_picks(outs[1:], n)
+
+
+def row_ef_update(m2d, g2d, idx, beta, chunk, *, interpret, block_chunks):
+    """Tile views of m/g + per-chunk idx -> (m' in the same view, vals);
+    grid/padding here.
 
     Shared by the flat wrapper below and kernels.rowwise.ef_update_trailing.
     """
-    n_rows, chunk = m2d.shape
+    if m2d.shape[1] != chunk:
+        return _dense_ef_update(m2d, g2d, idx, beta, chunk, interpret, block_chunks)
+    n_rows = m2d.shape[0]
     mp = _pad_rows(m2d, block_chunks)
     gp = _pad_rows(g2d, block_chunks)
     idxp = _pad_rows(idx, block_chunks)
@@ -118,9 +179,8 @@ def ef_update_pallas(
     beta is static (baked into the kernel). Returns (m_new (size,), vals).
     """
     n = m.shape[-1]
-    mp, n_chunks = _flat_view(m, chunk)
-    gp, _ = _flat_view(g, chunk)
     m_new, vals = row_ef_update(
-        mp, gp, idx, beta, interpret=interpret, block_chunks=block_chunks
+        _flat_view(m, chunk), _flat_view(g, chunk), idx, beta, chunk,
+        interpret=interpret, block_chunks=block_chunks,
     )
     return m_new.reshape(-1)[:n], vals

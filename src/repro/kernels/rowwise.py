@@ -5,13 +5,18 @@ arbitrarily-batched array ((..., Cp) with Cp % chunk == 0): a flat 1-D
 buffer, a worker-stacked (n_workers, size) tensor, and a layout-preserving
 (n_workers, *param_shape) tensor are all the *same launch* — flat is the
 degenerate single-row case. An input of shape (..., Cp) is locally a
-contiguous stack of (Cp/chunk) chunks per row, so the
-(leading-dims, Cp) -> (total_chunks, chunk) reshape done here is a pure
-row-major relayout — free on-device, and *per-shard* legal under GSPMD: the
-kernels always execute on the local shard, whose trailing dim is a chunk
-multiple by the sharding contract, unlike a global 1-D flatten of a
-model-sharded tensor (which forces resharding and motivated the
+contiguous stack of (Cp/chunk) chunks per row, so the 2-D tile view taken
+here (``chunk_topk.tile_view``) is a row-major reshape, *per-shard* legal
+under GSPMD: the kernels always execute on the local shard, whose trailing
+dim is a chunk multiple by the sharding contract, unlike a global 1-D
+flatten of a model-sharded tensor (which forces resharding and motivated the
 layout-preserving rowwise layout in the first place — see core/chunked.py).
+Whether the reshape is free depends on the view: the lane-dense
+(size/128, 128) view of a flat fp32 buffer with whole (8, 128) tiles is a
+bitcast, while a (total_chunks, chunk) view with chunk < 128 pads every row
+to 128 lanes in TPU memory — a relayout copy of the whole buffer — and m'
+and ĝ pay a second one on the way back. ``chunk_topk.lane_dense`` picks the
+lane-dense view wherever the shapes allow it.
 
 All wrappers accept arbitrary leading batch dims (worker axis included), so
 callers never vmap a pallas_call: one launch covers every worker's tiles.
@@ -21,13 +26,15 @@ static, so a shared (n_chunks, topm) index set is never confused with a
 worker-stacked (n_workers, n_chunks) one.
 
 Tile geometry and grid handling are shared with the flat 1-D kernels
-(kernels.chunk_topk row launchers); ``block_chunks`` is swept by
-repro.backends.autotune and benchmarked in benchmarks/bench_kernels.py.
+(kernels.chunk_topk row launchers); ``block_chunks``, the chunks a grid step
+covers in either geometry, is swept by repro.backends.autotune and
+benchmarked in benchmarks/bench_kernels.py.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +44,8 @@ from repro.kernels.chunk_topk import (
     row_gather,
     row_scatter,
     row_select,
+    scatter_width,
+    tile_view,
 )
 from repro.kernels.ef_update import row_ef_update
 
@@ -56,11 +65,6 @@ def _check_padded(cp: int, chunk: int) -> int:
             f"first"
         )
     return cp // chunk
-
-
-def _as_rows(x: jnp.ndarray, chunk: int) -> jnp.ndarray:
-    """(..., Cp) -> (total_chunks, chunk) local relayout."""
-    return x.reshape(-1, chunk)
 
 
 def _idx_rows(idx: jnp.ndarray, lead, ncr: int, topm_tail) -> jnp.ndarray:
@@ -88,7 +92,8 @@ def select_trailing(
     """
     ncr = _check_padded(x.shape[-1], chunk)
     idx, val = row_select(
-        _as_rows(x, chunk), topm=topm, interpret=interpret, block_chunks=block_chunks
+        tile_view(x, chunk), chunk, topm=topm, interpret=interpret,
+        block_chunks=block_chunks,
     )
     out_shape = x.shape[:-1] + (ncr,) + _tail(topm)
     return idx.reshape(out_shape), val.reshape(out_shape)
@@ -106,7 +111,8 @@ def gather_trailing(
     ncr = _check_padded(x.shape[-1], chunk)
     idx2 = _idx_rows(idx, x.shape[:-1], ncr, _tail(topm))
     val = row_gather(
-        _as_rows(x, chunk), idx2, interpret=interpret, block_chunks=block_chunks
+        tile_view(x, chunk), idx2, chunk, interpret=interpret,
+        block_chunks=block_chunks,
     )
     return val.reshape(x.shape[:-1] + (ncr,) + _tail(topm))
 
@@ -131,7 +137,8 @@ def scatter_trailing(
     idx2 = _idx_rows(idx, lead, ncr, tail)
     val2 = _idx_rows(vals, lead, ncr, tail)
     out = row_scatter(
-        val2, idx2, chunk, interpret=interpret, block_chunks=block_chunks
+        val2, idx2, chunk, scatter_width(chunk, cp, math.prod(lead) * cp, vals.dtype),
+        interpret=interpret, block_chunks=block_chunks,
     )
     return out.reshape(tuple(lead) + (cp,))
 
@@ -160,7 +167,7 @@ def ef_update_trailing(
     tail = _tail(topm)
     idx2 = _idx_rows(idx, m.shape[:-1], ncr, tail)
     m_new, vals = row_ef_update(
-        _as_rows(m, chunk), _as_rows(g, chunk), idx2, beta,
+        tile_view(m, chunk), tile_view(g, chunk), idx2, beta, chunk,
         interpret=interpret, block_chunks=block_chunks,
     )
     return (

@@ -49,6 +49,26 @@ def _tap_series(steps: List[dict], name: str) -> Dict[int, List[float]]:
     return out
 
 
+def _lane_dense_share(steps: List[dict]) -> Optional[float]:
+    """Share of the reduced elements whose kernels took lane-dense tiles.
+
+    Reads the last step's ``obs/lane_dense{path,size}`` taps (1.0 or 0.0 a
+    tensor, weighted by its ``size`` label); None without such taps.
+    """
+    for ev in reversed(steps):
+        pairs = [
+            (float(labels.get("size", 0)), v)
+            for key, v in ev.get("metrics", {}).items()
+            if key.startswith("obs/")
+            for name, labels in [parse_key(key[4:])]
+            if name == "lane_dense"
+        ]
+        total = sum(size for size, _ in pairs)
+        if total:
+            return sum(size * v for size, v in pairs) / total
+    return None
+
+
 def summarize(path: str) -> Dict[str, Any]:
     events = read_events(path)
     steps = [e for e in events if e.get("type") == "step"]
@@ -116,10 +136,11 @@ def summarize(path: str) -> Dict[str, Any]:
         v for vals in _tap_series(steps, "contraction_gamma").values() for v in vals
     ]
 
-    # --- fused-path taps: which inner-loop path each tensor took and the
-    # per-tensor launch count a kernel backend pays (obs/fused{...} /
-    # obs/fused_launches{...} — static plan facts, so any step is
-    # representative; we read the last one).
+    # --- fused-path taps: which inner-loop path each tensor took, the
+    # per-tensor launch count a kernel backend pays and whether its tiles
+    # were lane-dense (obs/fused{...} / obs/fused_launches{...} /
+    # obs/lane_dense{...} — static plan facts, so any step is
+    # representative; the share reads the last one).
     fused_flags = [
         v for vals in _tap_series(steps, "fused").values() for v in vals
     ]
@@ -133,6 +154,7 @@ def summarize(path: str) -> Dict[str, Any]:
             "tensors": len(fused_flags) // per_step,
             "tensors_fused": int(sum(fused_flags) / per_step),
             "launches_per_step": sum(launches) / per_step,
+            "lane_dense_share": _lane_dense_share(steps),
         }
         if fused_flags
         else None
@@ -191,6 +213,12 @@ def format_text(s: Dict[str, Any]) -> str:
             f"  fused path: {fp['tensors_fused']}/{fp['tensors']} compressed "
             f"tensor(s) on the single-launch fused reduce, "
             f"{fp['launches_per_step']:.0f} inner-loop kernel launches/step"
+            + (
+                f", {100 * fp['lane_dense_share']:.1f}% of reduced elements "
+                f"on lane-dense tiles"
+                if fp.get("lane_dense_share") is not None
+                else ""
+            )
         )
     sim = {k: v for k, v in s["similarity"].items() if v}
     if sim:

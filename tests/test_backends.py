@@ -198,7 +198,7 @@ DTYPES = [jnp.float32, jnp.bfloat16]
 
 
 @pytest.mark.parametrize("size", SIZES)
-@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("chunk", [8, 16, 32, 64, 128])
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("topm", [1, 3])
 def test_flat_select_parity(size, chunk, dtype, topm):
@@ -211,7 +211,7 @@ def test_flat_select_parity(size, chunk, dtype, topm):
     )
 
 
-@pytest.mark.parametrize("size", [1000])
+@pytest.mark.parametrize("size", [1000, 40960])  # rows; lane-dense, ragged
 @pytest.mark.parametrize("topm", [1, 2])
 def test_flat_gather_scatter_parity(size, topm):
     chunk = 16
@@ -226,7 +226,7 @@ def test_flat_gather_scatter_parity(size, topm):
     np.testing.assert_allclose(np.asarray(d1), np.asarray(d2), rtol=1e-6)
 
 
-@pytest.mark.parametrize("size", [1000, 512])
+@pytest.mark.parametrize("size", [1000, 512, 40960])
 @pytest.mark.parametrize("beta", [0.1, 1.0])
 @pytest.mark.parametrize("topm", [1, 2])
 def test_flat_ef_update_parity(size, beta, topm):
@@ -280,7 +280,9 @@ def test_stacked_shared_index_gather_ef_parity(topm):
 
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("topm", [1, 2])
-@pytest.mark.parametrize("C", [48, 45])  # chunk multiple + tail-chunk padding
+# chunk multiple + tail-chunk padding; 1024: lane-dense in fp32; 384: a
+# 128-multiple trailing dim whose 5760 elements make no whole (8, 128) tiles
+@pytest.mark.parametrize("C", [48, 45, 1024, 384])
 def test_batched_trailing_axis_parity(dtype, topm, C):
     chunk = 16
     x = _rand((3, 5, C), 41, dtype)
@@ -311,6 +313,65 @@ def test_batched_ef_update_parity_shared_idx(topm):
     m2, v2 = PAL.ef_update(m, g, idx, 0.25, chunk, topm)
     np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-5, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# lane-dense tiles: the whole 3-launch op set, bitwise against the oracles
+# ---------------------------------------------------------------------------
+
+
+def _tricky(shape, seed):
+    """Small integers (magnitude ties in nearly every chunk), NaN lanes and
+    all-zero chunks."""
+    x = jax.random.randint(jax.random.PRNGKey(seed), shape, -3, 4).astype(jnp.float32)
+    flat = x.reshape(-1).at[jnp.array([3, 130, 131, 5000])].set(jnp.nan)
+    return flat.at[256:512].set(0.0).reshape(shape)
+
+
+@pytest.mark.parametrize("topm", [1, 2])
+@pytest.mark.parametrize("chunk", [8, 16, 32, 64, 128])
+@pytest.mark.parametrize(
+    "shape",
+    [(2, 5, 4096), (1, 3 * 1024 * 64 + 1024 * 8), (2, 6, 384)],
+    ids=["stacked", "ragged_blocks", "rows_side"],
+)
+def test_lane_dense_ops_match_the_oracles_bitwise(shape, chunk, topm):
+    """select, gather, ef_update and scatter on shapes either side of the
+    geometry rule: the (2, 5, 4096) stack and the flat buffer of 3.125
+    blocks at chunk 64 (a ragged last block in every lane-dense chunk) take
+    lane-dense tiles for chunks 16-64; (2, 6, 384) has no whole (8, 128)
+    tiles and keeps the rows, as chunks 8 and 128 do everywhere. Indices and
+    picked values equal the oracle's bit for bit; m' equals the rows
+    geometry's kernel bit for bit (the oracle's own arithmetic may round the
+    last bit differently)."""
+    from repro.kernels import chunk_topk, ef_update as efk
+
+    size = int(np.prod(shape))
+    assert chunk_topk.lane_dense(chunk, shape[-1], size, jnp.float32) is (
+        16 <= chunk <= 64 and shape[-1] != 384
+    )
+    x, g = _tricky(shape, 61), _rand(shape, 62)
+    i1, v1 = JNP.select(x, chunk, topm)
+    i2, v2 = PAL.select(x, chunk, topm)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    idx = i1[0] if shape[0] > 1 else i1  # a shared set, as clt_k broadcasts
+    w1 = JNP.gather(x, idx, chunk, topm)
+    w2 = PAL.gather(x, idx, chunk, topm)
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
+    m1, e1 = JNP.ef_update(x, g, idx, 0.25, chunk, topm)
+    m2, e2 = PAL.ef_update(x, g, idx, 0.25, chunk, topm)
+    np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
+    np.testing.assert_allclose(np.asarray(m1), np.asarray(m2), rtol=1e-6, atol=1e-6)
+    idx_rows = jnp.broadcast_to(idx, e1.shape).reshape((-1,) + e1.shape[len(shape):])
+    m_rows, _ = efk.row_ef_update(
+        x.reshape(-1, chunk), g.reshape(-1, chunk), idx_rows, 0.25, chunk,
+        interpret=True, block_chunks=1024,
+    )
+    np.testing.assert_array_equal(np.asarray(m2).reshape(-1), np.asarray(m_rows).reshape(-1))
+    d1 = JNP.scatter(e1, idx, chunk, shape[-1], topm)
+    d2 = PAL.scatter(e1, idx, chunk, shape[-1], topm)
+    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
 
 
 # ---------------------------------------------------------------------------

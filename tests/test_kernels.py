@@ -12,9 +12,32 @@ from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
 
-SIZES = [1024, 4096, 5000, 65536 + 17]
-CHUNKS = [16, 64, 128]
+# 102400: lane-dense for fp32 chunks 16-64, over several blocks with a
+# ragged last one; 5000 and 65553 keep the (n_chunks, chunk) rows
+SIZES = [1024, 4096, 5000, 65536 + 17, 102400]
+CHUNKS = [8, 16, 32, 64, 128]
 DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+@pytest.mark.parametrize(
+    "chunk,width,size,dtype,dense",
+    [
+        (64, 512, 37000 * 512, jnp.float32, True),
+        (16, 128, 1024, jnp.float32, True),
+        (32, 2048, 6 * 512 * 2048, jnp.float32, True),
+        (128, 512, 4096, jnp.float32, False),  # fills its rows already
+        (8, 512, 4096, jnp.float32, False),  # under MIN_DENSE_CHUNK
+        (96, 2112, 2112 * 512, jnp.float32, False),  # does not divide 128
+        (64, 192, 192 * 64, jnp.float32, False),  # width not a multiple of 128
+        (64, 384, 384 * 5, jnp.float32, False),  # no whole (8, 128) tiles
+        (64, 512, 4096, jnp.bfloat16, False),
+    ],
+)
+def test_lane_dense_rule(chunk, width, size, dtype, dense):
+    """The tile geometry follows from static shapes alone."""
+    from repro.kernels.chunk_topk import lane_dense
+
+    assert lane_dense(chunk, width, size, dtype) is dense
 
 
 @pytest.mark.parametrize("size", SIZES)
@@ -43,7 +66,7 @@ def test_chunk_gather_matches_ref(size, chunk):
 
 
 @pytest.mark.parametrize("size", SIZES)
-@pytest.mark.parametrize("chunk", [64])
+@pytest.mark.parametrize("chunk", [16, 32, 64])
 @pytest.mark.parametrize("beta", [0.1, 1.0])
 def test_ef_update_matches_ref(size, chunk, beta):
     k1, k2 = jax.random.split(jax.random.PRNGKey(size))
@@ -70,16 +93,30 @@ def test_kernel_property_sweep(size, chunk, seed):
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-6)
 
 
-@pytest.mark.parametrize("topm", [1, 2])
-def test_select_breaks_ties_to_the_lowest_lane(topm):
+_TIE_CASES = [(1, 64), (2, 64)] + [
+    (topm, chunk) for chunk in (8, 16, 32, 128) for topm in (1, 2)
+]
+
+
+@pytest.mark.parametrize(
+    "topm,chunk",
+    _TIE_CASES,
+    ids=[str(t) if c == 64 else f"{t}-c{c}" for t, c in _TIE_CASES],
+)
+def test_select_breaks_ties_to_the_lowest_lane(topm, chunk):
     """Exact magnitude ties pick the lowest lane, as jnp.argmax and
     lax.top_k do (full-width random gradients hit a few per step). Small
-    integers make ties in nearly every chunk, signs included."""
+    integers make ties in nearly every chunk, signs included; NaN lanes rank
+    first, the first of them winning; all-zero chunks pick lane 0. The
+    (3, 40960) stack is lane-dense for chunks 16-64 over several blocks with
+    a ragged last one, and keeps the (n_chunks, chunk) rows at 8 and 128."""
     from repro.backends import resolve_backend
 
-    x = jax.random.randint(jax.random.PRNGKey(3), (4, 4096), -3, 4).astype(jnp.float32)
-    i1, v1 = resolve_backend("pallas").select(x, 64, topm)
-    i2, v2 = resolve_backend("jnp").select(x, 64, topm)
+    x = jax.random.randint(jax.random.PRNGKey(3), (3, 40960), -3, 4).astype(jnp.float32)
+    x = x.at[0, jnp.array([5, 9, 700, 40959])].set(jnp.nan)
+    x = x.at[1, 64 * 3 : 64 * 6].set(0.0).at[2, -chunk:].set(-0.0)
+    i1, v1 = resolve_backend("pallas").select(x, chunk, topm)
+    i2, v2 = resolve_backend("jnp").select(x, chunk, topm)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
 

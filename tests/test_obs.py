@@ -250,6 +250,46 @@ def test_buildup_tap_counts_union_for_local_topk():
     assert float(union["obs/buildup_nnz{path=['a']}"]) > k
 
 
+@pytest.mark.parametrize(
+    "backend,fused,want_a",
+    [("pallas", False, 1.0), ("pallas", True, 0.0), ("jnp", False, 0.0)],
+)
+def test_lane_dense_tap_reads_the_tile_geometry(backend, fused, want_a):
+    """obs/lane_dense{path,size}: 1.0 where the 3-launch pallas kernels take
+    lane-dense tiles (a 4096-element tensor at chunk 64), 0.0 where a
+    tensor keeps the (n_chunks, chunk) rows (960 elements: no multiple of
+    128), runs the fused kernel, or meets a backend without tiles."""
+    cfg = _cfg(
+        compressor=CompressorConfig("clt_k", chunk=64), min_size=1,
+        telemetry=True, backend=backend, fused=fused,
+    )
+    sizes = {"a": 4096, "b": 960}
+    params = {k: jnp.zeros((n,)) for k, n in sizes.items()}
+    state = init_state(params, 2, min_size=1)
+    g = {k: jax.random.normal(jax.random.PRNGKey(n), (2, n)) for k, n in sizes.items()}
+    _, _, stats = scalecom_reduce(g, state, cfg)
+    assert float(stats["obs/lane_dense{path=['a'],size=4096}"]) == want_a
+    assert float(stats["obs/lane_dense{path=['b'],size=960}"]) == 0.0
+
+
+def test_report_lane_dense_share_weights_by_size(tmp_path):
+    path = str(tmp_path / "events.jsonl")
+    metrics = {
+        "obs/fused{compressor=clt_k,path=['a']}": 0.0,
+        "obs/fused{compressor=clt_k,path=['b']}": 0.0,
+        "obs/fused_launches{path=['a']}": 3.0,
+        "obs/fused_launches{path=['b']}": 3.0,
+        "obs/lane_dense{path=['a'],size=3000}": 1.0,
+        "obs/lane_dense{path=['b'],size=1000}": 0.0,
+    }
+    with EventLog(path) as log:
+        for step in range(2):
+            log.emit("step", step=step, metrics=metrics)
+    s = report.summarize(path)
+    assert s["fused_path"]["lane_dense_share"] == 0.75
+    assert "75.0% of reduced elements on lane-dense tiles" in report.format_text(s)
+
+
 def test_bucket_taps_present_only_when_bucketed():
     cfg = _cfg(telemetry=True)
     _, _, stats_u = _trajectory(cfg, buckets=False, steps=1)
