@@ -61,32 +61,32 @@ def main(argv=None) -> int:
     control = run.build(res, compute_dtype="bfloat16") if args.control else None
     found = {}
 
-    def reading(kind, loop, make_state, seed, refs):
-        state, traffic = run.start(res, make_state, seed)
+    def reading(kind, prog, seed, refs):
+        state, traffic = run.start(res, prog, seed)
         t0 = time.perf_counter()
-        state, prog, _ = run.program_readings(res, loop, state, traffic, seed)
+        state, readings, _ = run.program_readings(res, prog, state, traffic, seed)
         del state
         t1 = time.perf_counter()
         if seed not in refs:
             refs[seed] = ref.readings(seed, traffic.batch, res["mix"]["checked_steps"])
         t2 = time.perf_counter()
-        gaps = run.compare(prog, refs[seed])
+        gaps = run.compare(readings, refs[seed])
         found.setdefault(kind, []).append(gaps)
-        print(json.dumps({"kind": kind, "seed": seed, **gaps, "program_loss": prog["loss"],
+        print(json.dumps({"kind": kind, "seed": seed, **gaps, "program_loss": readings["loss"],
                           "reference_loss": refs[seed]["loss"],
                           "program_s": t1 - t0, "reference_s": t2 - t1}), flush=True)
 
     for s in seeds(args.seeds):
         seed, refs = s + args.offset, {}
-        reading("program", *program[:2], seed, refs)
+        reading("program", program, seed, refs)
         if s in seeds(args.control):
-            reading("control_bf16_program", *control[:2], seed, refs)
+            reading("control_bf16_program", control, seed, refs)
         if s in seeds(args.faults):
-            loop = program[0]
-            for fault in ("half_batch", "dropped_leaf"):
+            loop = program.loop
+            for fault in faults.planted(res["mix"]["workers"]):
                 broken = faults.wrap(type(loop).step, fault)
                 proxy = types.SimpleNamespace(step=lambda st, b, i: broken(loop, st, b, i))
-                reading(fault, proxy, program[1], seed, refs)
+                reading(fault, program._replace(loop=proxy), seed, refs)
     summary = {}
     for name in run.CHECKS:
         summary[name] = {"program_max": max(g[name] for g in found["program"])}

@@ -1,0 +1,11 @@
+"""Device milliseconds per step in the ScaleCom reduce, its kernels and the
+XLA glue around them: the self time of the ops whose ``op_name`` scope is
+``reduce`` (``chipbench.scopes``), from the trace, averaged over the chips.
+No such op, no reading."""
+
+
+def read(rec):
+    spent = rec.get("scopes", {}).get("scope_s", {}).get("reduce")
+    if not spent or not rec.get("traced_steps"):
+        return None
+    return spent / rec["traced_steps"] * 1e3

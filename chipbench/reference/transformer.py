@@ -226,6 +226,28 @@ def bf16_share(tree) -> jnp.ndarray:
     return exact / jnp.maximum(nonzero, 1)
 
 
+def grad_bf16_share(residues, beta: float) -> jnp.ndarray:
+    """Mean over tensors of the share of each learner's first gradient values
+    that bfloat16 holds, read from the residues after one step from zero.
+
+    After that step each residue is ``beta (g - own)`` (Eq. 5 with m = 0), so
+    ``g`` is the residue over ``beta`` wherever it is not zero. The product
+    and the quotient each round in float32, so a value within 2 float32 units
+    in the last place of a bfloat16 value counts as one. A float32 gradient
+    holds about 5 such values in 2**16; a tensor whose gradient is computed
+    in bfloat16 holds them throughout. Unlike ``bf16_share`` of the reduced
+    gradient, it sees each learner before the mean across learners, and each
+    tensor with the same weight however few values it has."""
+    shares = []
+    for x in jax.tree.leaves(residues):
+        g = x / beta
+        low = jax.lax.bitcast_convert_type(g, jnp.uint32) & 0xFFFF
+        nonzero = g != 0
+        near = nonzero & ((low <= 2) | (low >= 0xFFFE))
+        shares.append(jnp.sum(near) / jnp.maximum(jnp.sum(nonzero), 1))
+    return jnp.mean(jnp.stack(shares))
+
+
 class Reference:
     """The reference's training steps for one configuration and traffic mix."""
 
@@ -265,6 +287,7 @@ class Reference:
         self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=(0,))
         self._norms = jax.jit(leaf_norms)
         self._bf16_share = jax.jit(bf16_share)
+        self._grad_bf16_share = jax.jit(lambda r: grad_bf16_share(r, mix["beta"]))
         self._delta_norms = jax.jit(lambda a, b: leaf_norms(jax.tree.map(jnp.subtract, a, b)))
 
     def init(self, seed: int):
@@ -294,7 +317,8 @@ class Reference:
     def readings(self, seed: int, batch_of: Callable[[int], dict], steps: int) -> Dict:
         """Losses of ``steps`` steps, leaf norms of the first step's dense
         gradient and reduced gradient, the reduced gradient's share of
-        values that bfloat16 holds exactly, and leaf norms of the
+        values that bfloat16 holds exactly and the learners' gradients'
+        (``grad_bf16_share``), and leaf norms of the
         parameters' change after the last step. Leaves are in
         ``jax.tree.leaves`` order."""
         params = self.init(seed)
@@ -315,6 +339,7 @@ class Reference:
             if t == 0:
                 out["ghat_norms"] = np.asarray(self._norms(g_hat))
                 out["ghat_bf16_share"] = float(self._bf16_share(g_hat))
+                out["grad_bf16_share"] = float(self._grad_bf16_share(residues))
             del grads, g_hat
         del mom, residues
         out["delta_norms"] = np.asarray(self._delta_norms(params, self.init(seed)))

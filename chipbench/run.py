@@ -20,17 +20,22 @@ no result where JAX finds no TPU, fewer chips than the cell asks for, or a
 ``device_kind`` missing from ``chipbench/peaks.py``. It builds the program
 through the calls ``repro.launch.train.build`` makes (``build_model``, the
 train state, ``TrainLoop``) with the kernel backend, layout and fusion left
-to the program's own defaults, and makes the weights from ``--seed`` on the
-device in one jitted call. Set-up drives the compressed step through its
-first ``checked_steps`` steps, reading what the correctness comparison needs,
-then ``warmup_steps`` more; the window then drives ``TrainLoop.step`` for
+to the program's own defaults unless the mix names them, and makes the
+weights from ``--seed`` on the device in one jitted call. Every cell runs
+one learner per chip: its mix sets ``workers`` to the cell's ``chips``, and
+a cell on more than one chip places them as ``chipbench/workers.py`` says.
+Set-up drives the compressed step through its first ``checked_steps``
+steps, reading what the correctness comparison needs, then
+``warmup_steps`` more; the window then drives ``TrainLoop.step`` for
 ``--seconds``, the host building batch i+1 while ``in_flight`` steps are
 queued on the device (after dispatching step i it waits for step
 i - in_flight), as a training loop that reads its loss now and then does.
 With ``--trace 1`` the same loop runs for ``trace_steps`` steps under
-``jax.profiler``. After the window the program's
-state is freed and the reference trains the same weights on the same batches
-for ``checked_steps`` steps on the chip; the comparison decides ``correct``.
+``jax.profiler``, and the record the per-layer readers get holds the
+trace's reduction (busy, kernel and collective time) and its split by the
+step's named phases. After the window the program's state is freed and the
+reference trains the same weights on the same batches for ``checked_steps``
+steps on the chip; the comparison decides ``correct``.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced), and last
@@ -50,10 +55,14 @@ import shutil
 import sys
 import tempfile
 import time
+from typing import Any, Callable, NamedTuple
 
 T_START = time.perf_counter()
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHECKS = ("loss_gap", "ghat_norm_gap", "delta_norm_gap", "ghat_bf16_share_gap")
+CHECKS = ("loss_gap", "ghat_norm_gap", "delta_norm_gap", "ghat_bf16_share_gap",
+          "grad_bf16_share_gap")
+# the harness's own spans on the profiler's host plane
+HOST_SPANS = ("window", "batch", "dispatch", "wait")
 # leaves whose reference gradient is under this share of the median leaf's
 # are round-off in both implementations, and are left out of the comparison
 ROUNDOFF_LEAF = 1e-3
@@ -86,6 +95,12 @@ def resolve(workload: str, root: str = ROOT) -> dict:
     cell = cells[workload]
     configs = {c["name"]: c for c in spec["configs"]}
     entry = configs[cell["config"]]
+    mix = load_json(os.path.join(root, "chipbench", "traffic", f"{cell['traffic']}.json"))
+    if mix["workers"] != cell["chips"]:
+        raise ValueError(
+            f"{workload}: one learner per chip, but the mix has {mix['workers']} "
+            f"workers for {cell['chips']} chips"
+        )
     limits_path = os.path.join(root, "chipbench", "limits", f"{workload}.json")
 
     def applies(metric):
@@ -94,7 +109,7 @@ def resolve(workload: str, root: str = ROOT) -> dict:
     return {
         "cell": cell,
         "config": load_json(os.path.join(root, entry["file"])),
-        "mix": load_json(os.path.join(root, "chipbench", "traffic", f"{cell['traffic']}.json")),
+        "mix": mix,
         "limits": load_json(limits_path) if os.path.exists(limits_path) else {},
         "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
         "per_layer": [m for m in spec["per_layer"] if applies(m)],
@@ -147,10 +162,23 @@ def enable_compile_cache() -> None:
 # ---------------------------------------------------------------------------
 
 
-def build(res: dict, compute_dtype: str = None):
-    """The program's loop, a jitted ``make_state(key)`` that builds its
-    initial state with the configuration's seeded weights, and the state's
-    shapes. ``compute_dtype`` overrides the configuration's (the control)."""
+class Program(NamedTuple):
+    """The program under test as a run drives it: its ``TrainLoop``, a jitted
+    ``make_state(key)`` that builds its initial state with the
+    configuration's seeded weights, the state's shapes, and ``put(batch)``,
+    which places a host batch where the step takes it."""
+
+    loop: Any
+    make_state: Callable
+    shapes: Any
+    put: Callable
+
+
+def build(res: dict, compute_dtype: str = None) -> Program:
+    """The program of a cell. ``compute_dtype`` overrides the
+    configuration's (the control). On one chip, a ``TrainLoop`` as
+    ``repro.launch.train.build`` makes it; on more, one learner per chip
+    (``chipbench/workers.py``)."""
     import dataclasses
 
     import jax
@@ -163,7 +191,7 @@ def build(res: dict, compute_dtype: str = None):
     from repro.optim import make_optimizer, schedule
     from repro.training import TrainLoop, TrainState, init_train_state
 
-    config, mix = res["config"], res["mix"]
+    config, mix, chips = res["config"], res["mix"], res["cell"]["chips"]
     fields = {f.name for f in dataclasses.fields(ArchConfig)}
     arch = ArchConfig(
         name=config["name"], **{k: v for k, v in config["model"].items() if k in fields}
@@ -179,14 +207,26 @@ def build(res: dict, compute_dtype: str = None):
         **({"backend": mix["backend"]} if "backend" in mix else {}),
     )
     opt = make_optimizer(mix["optimizer"], momentum=mix["momentum"])
-    loop = TrainLoop(
-        model=model, optimizer=opt, schedule=schedule.constant(mix["lr"]),
-        sc_cfg=sc_cfg, n_workers=mix["workers"],
-    )
     shapes = jax.eval_shape(
         lambda: init_train_state(model, opt, sc_cfg, jax.random.PRNGKey(0),
                                  n_workers=mix["workers"])[0]
     )
+    job = dict(model=model, optimizer=opt, schedule=schedule.constant(mix["lr"]),
+               sc_cfg=sc_cfg, n_workers=mix["workers"])
+    if chips == 1:
+        loop, state_sharding, put = TrainLoop(**job), None, jax.device_put
+    else:
+        from chipbench import workers
+
+        state_sharding, batch_sharding = workers.placement(jax.devices()[:chips], shapes)
+        loop = workers.WorkerShardedLoop(
+            **job, worker_axis=workers.AXIS, state_sharding=state_sharding,
+            batch_sharding=batch_sharding,
+        )
+
+        def put(batch):
+            return jax.device_put(batch, batch_sharding)
+
     ref = reference_module(config)
     own = jax.eval_shape(lambda k: ref.init_params(config["model"], k), jax.random.PRNGKey(0))
     if jax.tree.structure(own) != jax.tree.structure(shapes.params) or any(
@@ -198,8 +238,7 @@ def build(res: dict, compute_dtype: str = None):
     def zeros(s):
         return jnp.zeros(s.shape, s.dtype)
 
-    @jax.jit
-    def make_state(key):
+    def initial(key):
         return TrainState(
             params=ref.init_params(config["model"], key),
             opt_state=jax.tree.map(zeros, shapes.opt_state),
@@ -207,23 +246,28 @@ def build(res: dict, compute_dtype: str = None):
             step=zeros(shapes.step),
         )
 
-    return loop, make_state, shapes
+    make_state = (
+        jax.jit(initial) if state_sharding is None
+        else jax.jit(initial, out_shardings=state_sharding)
+    )
+    return Program(loop, make_state, shapes, put)
 
 
-def start(res: dict, make_state, seed: int):
+def start(res: dict, prog: Program, seed: int):
     """The initial state and the traffic of one seed."""
     from chipbench.traffic.synthetic import Traffic
 
-    state = make_state(reference_module(res["config"]).seed_key(seed))
+    state = prog.make_state(reference_module(res["config"]).seed_key(seed))
     return state, Traffic(res["mix"], res["config"]["model"]["vocab"], seed)
 
 
-def program_readings(res, loop, state, traffic, seed):
+def program_readings(res, prog: Program, state, traffic, seed):
     """Drive the first ``checked_steps`` steps and read what the comparison
     needs: each step's loss, the leaf norms of the reduced gradient the
     optimizer got in step 1 (its momentum after one step, which starts at
-    zero) and that gradient's share of values bfloat16 holds exactly, and
-    the leaf norms of the parameters' change after the last checked step.
+    zero) and that gradient's share of values bfloat16 holds exactly, the
+    learners' own first gradients' share of them (read from the residues),
+    and the leaf norms of the parameters' change after the last checked step.
     Returns (state, readings, seconds spent on the readings alone)."""
     import jax
     import jax.numpy as jnp
@@ -232,6 +276,7 @@ def program_readings(res, loop, state, traffic, seed):
     ref = reference_module(res["config"])
     norms = jax.jit(ref.leaf_norms)
     bf16_share = jax.jit(ref.bf16_share)
+    grad_share = jax.jit(lambda r: ref.grad_bf16_share(r, res["mix"]["beta"]))
     # the initial weights are made again inside the program that reads the
     # change, so that they never sit beside the state as buffers of their own
     delta = jax.jit(lambda p, key: ref.leaf_norms(
@@ -240,12 +285,13 @@ def program_readings(res, loop, state, traffic, seed):
     out = {"loss": []}
     extra = 0.0
     for t in range(res["mix"]["checked_steps"]):
-        state, metrics = loop.step(state, jax.device_put(traffic.batch(t)), t)
+        state, metrics = prog.loop.step(state, prog.put(traffic.batch(t)), t)
         out["loss"].append(float(metrics["loss"]))
         if t == 0:
             t0 = time.perf_counter()
             out["ghat_norms"] = np.asarray(norms(state.opt_state["m"]))
             out["ghat_bf16_share"] = float(bf16_share(state.opt_state["m"]))
+            out["grad_bf16_share"] = float(grad_share(state.sc_state.residues))
             extra += time.perf_counter() - t0
     t0 = time.perf_counter()
     out["delta_norms"] = np.asarray(delta(state.params, ref.seed_key(seed)))
@@ -253,8 +299,8 @@ def program_readings(res, loop, state, traffic, seed):
     return state, out, extra
 
 
-def drive(loop, state, traffic, first: int, *, in_flight: int, seconds=None, steps=None,
-          annotate=None):
+def drive(prog: Program, state, traffic, first: int, *, in_flight: int, seconds=None,
+          steps=None, annotate=None):
     """Run the step until ``seconds`` have passed or ``steps`` have completed,
     with ``in_flight`` steps queued on the device while the host builds the
     next batch: after dispatching step i it waits for step i - in_flight.
@@ -271,7 +317,7 @@ def drive(loop, state, traffic, first: int, *, in_flight: int, seconds=None, ste
 
     def feed(i):
         with span("batch"):
-            return jax.device_put(traffic.batch(i))
+            return prog.put(traffic.batch(i))
 
     i = first
     nxt = feed(i)
@@ -289,7 +335,7 @@ def drive(loop, state, traffic, first: int, *, in_flight: int, seconds=None, ste
         while True:
             with span("dispatch"):
                 d0 = time.perf_counter()
-                state, metrics = loop.step(state, nxt, i)
+                state, metrics = prog.loop.step(state, nxt, i)
                 dispatch.append(time.perf_counter() - d0)
             pending.append(metrics["loss"])
             i += 1
@@ -303,6 +349,96 @@ def drive(loop, state, traffic, first: int, *, in_flight: int, seconds=None, ste
         while pending:
             wait_one()
     return state, t0, done, losses, dispatch
+
+
+def traced_window(prog: Program, state, traffic, first: int, mix: dict, keep: str = None):
+    """``trace_steps`` steps of the window's loop under ``jax.profiler``, and
+    the text of the compiled step they run, read before the capture opens.
+    Returns (state, completion times, losses, dispatch seconds, record), the
+    record as ``traced_record`` makes it. ``keep`` names a directory to copy
+    the trace (``trace.xplane.pb``) and the text (``step.hlo.txt``) into."""
+    import jax
+
+    from chipbench import trace as tr
+
+    t0 = time.perf_counter()
+    hlo = prog.loop.compiled(state, prog.put(traffic.batch(first)), first).as_text()
+    log(f"compiled step's text read in {time.perf_counter() - t0:.2f} s")
+    tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
+    try:
+        jax.profiler.start_trace(tmp)
+        state, _, done, losses, dispatch = drive(
+            prog, state, traffic, first, in_flight=mix["in_flight"],
+            steps=mix["trace_steps"], annotate=jax.profiler.TraceAnnotation,
+        )
+        jax.profiler.stop_trace()
+        path = tr.find_xplane(tmp)
+        events = tr.load(path, HOST_SPANS)
+        if keep:
+            os.makedirs(keep, exist_ok=True)
+            shutil.copy(path, os.path.join(keep, "trace.xplane.pb"))
+            with open(os.path.join(keep, "step.hlo.txt"), "w") as f:
+                f.write(hlo)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    return state, done, losses, dispatch, traced_record(events, hlo)
+
+
+def traced_record(events: dict, hlo: str) -> dict:
+    """What the per-layer readers take from a traced window (``events`` as
+    ``chipbench.trace.load`` gives them, with the harness's last ``window``
+    span) and the compiled step's text: ``trace``, the reduction of
+    ``chipbench.trace.reduce`` with the module's collectives, and
+    ``scopes``, the split of ``chipbench.scopes.scope_times`` by the step's
+    named phases (``scope_s``, ``stage_s``, ``unscoped_s``)."""
+    from chipbench import scopes
+    from chipbench import trace as tr
+
+    windows = [e for e in events["host"] if e[0] == "window"]
+    lo, hi = windows[-1][1], windows[-1][1] + windows[-1][2]
+    return {
+        "trace": tr.reduce(events, (lo, hi), collectives=scopes.collective_map(hlo)),
+        "scopes": scopes.scope_times(events, (lo, hi), scopes.scope_map(hlo)),
+    }
+
+
+def work(res: dict, params, traffic) -> dict:
+    """What one step does by the benchmark's own counts, for a program whose
+    parameters have the shapes ``params``: its tokens, its model FLOPs (by
+    the configuration's reference module's ``train_flops_per_token`` where
+    it defines one, else by ``chipbench.counts``'), and the least HBM bytes
+    of its reduce; and the configuration's ``model``, from which a reader
+    can count the operations and bytes of a kernel of its own."""
+    import jax
+
+    from chipbench import counts
+
+    model, mix = res["config"]["model"], res["mix"]
+    flops = getattr(reference_module(res["config"]), "train_flops_per_token",
+                    counts.train_flops_per_token)
+    return {
+        "model": model,
+        "tokens_per_step": traffic.tokens_per_step,
+        "flops_per_step": traffic.tokens_per_step * flops(model, mix["seq"]),
+        "reduce_bytes_per_step": counts.reduce_min_bytes(
+            [
+                (math.prod(p.shape), mix["workers"], p.dtype.itemsize, 4)
+                for p in jax.tree.leaves(params)
+            ],
+            mix["min_size"],
+        ),
+    }
+
+
+def read_metrics(metrics: list, rec: dict) -> dict:
+    """{name: {value, unit}} of each metric whose reader finds something in
+    the run record ``rec``."""
+    out = {}
+    for m in metrics:
+        value = reader(m["name"])(rec)
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -326,6 +462,11 @@ def compare(prog: dict, ref: dict) -> dict:
     bfloat16 most. The norms cannot see that precision: at XLA's default
     matmul precision every matmul already rounds its operands to bfloat16,
     and two float32 implementations part by as much as bfloat16 activations.
+    grad_bf16_share_gap: the same gap for the learners' own first gradients,
+    read from the residues, with each tensor weighed alike: on a TPU the
+    compiler keeps most of a bfloat16 step's gradient tensors in float32,
+    so the few that stay bfloat16 are lost among the others' values in the
+    reduced gradient, and the mean across learners rounds them away.
     """
     import numpy as np
 
@@ -346,16 +487,21 @@ def compare(prog: dict, ref: dict) -> dict:
         "ghat_norm_gap": worst(prog["ghat_norms"], ref["ghat_norms"]),
         "delta_norm_gap": worst(prog["delta_norms"], ref["delta_norms"]),
         "ghat_bf16_share_gap": abs(prog["ghat_bf16_share"] - ref["ghat_bf16_share"]),
+        "grad_bf16_share_gap": abs(prog["grad_bf16_share"] - ref["grad_bf16_share"]),
     }
 
 
 def judge(gaps: dict, limits: dict):
-    """(correct, checks): every number at or under its limit."""
+    """(correct, checks): every number at or under its limit. A cell's
+    limits name every number of CHECKS; a number whose limit is null is not
+    compared in that cell, as one that cannot tell the control from sound
+    runs there (PERF.md, section 4); a number with no limit at all is a
+    fault of the cell, and the run is not correct."""
     checks = {
         name: {"value": gaps[name], "limit": limits.get(name)} for name in CHECKS
     }
-    correct = all(
-        c["limit"] is not None and c["value"] <= c["limit"] for c in checks.values()
+    correct = set(CHECKS) <= set(limits) and all(
+        c["limit"] is None or c["value"] <= c["limit"] for c in checks.values()
     )
     return correct, checks
 
@@ -392,9 +538,6 @@ def run_cell(res: dict, seed: int, seconds: float, trace: bool, *, chip: bool = 
     look for a TPU (the tests drive the rest of a run on the CPU)."""
     import jax
 
-    from chipbench import counts
-    from chipbench import trace as tr
-
     t_import = time.perf_counter()
     devices = jax.devices()
     t_devices = time.perf_counter()
@@ -407,13 +550,13 @@ def run_cell(res: dict, seed: int, seconds: float, trace: bool, *, chip: bool = 
     mix = res["mix"]
 
     t_build = time.perf_counter()
-    loop, make_state, shapes = build(res)
-    state, traffic = start(res, make_state, seed)
+    prog = build(res)
+    state, traffic = start(res, prog, seed)
     jax.block_until_ready(state)
     t_steps = time.perf_counter()
-    state, prog, check_s = program_readings(res, loop, state, traffic, seed)
+    state, readings, check_s = program_readings(res, prog, state, traffic, seed)
     first = mix["checked_steps"]
-    state, *_ = drive(loop, state, traffic, first, in_flight=mix["in_flight"],
+    state, *_ = drive(prog, state, traffic, first, in_flight=mix["in_flight"],
                       steps=mix["warmup_steps"])
     first += mix["warmup_steps"]
     jax.block_until_ready(state)
@@ -424,23 +567,12 @@ def run_cell(res: dict, seed: int, seconds: float, trace: bool, *, chip: bool = 
         f"program and weights {t_steps - t_build:.2f} s, first "
         f"{first} steps {t_end - t_steps - check_s:.2f} s (readings {check_s:.2f} s apart)")
 
-    rec = {
-        "mix": mix, "peaks": peaks, "chips": chips, "setup_s": setup_s,
-        "tokens_per_step": traffic.tokens_per_step,
-        "flops_per_step": traffic.tokens_per_step
-        * counts.train_flops_per_token(res["config"]["model"], mix["seq"]),
-        "reduce_bytes_per_step": counts.reduce_min_bytes(
-            [
-                (math.prod(p.shape), mix["workers"], p.dtype.itemsize, 4)
-                for p in jax.tree.leaves(shapes.params)
-            ],
-            mix["min_size"],
-        ),
-    }
+    rec = {"mix": mix, "peaks": peaks, "chips": chips, "setup_s": setup_s,
+           **work(res, prog.shapes.params, traffic)}
     breakdown = None
     if not trace:
         state, t0, done, losses, _ = drive(
-            loop, state, traffic, first, in_flight=mix["in_flight"], seconds=seconds
+            prog, state, traffic, first, in_flight=mix["in_flight"], seconds=seconds
         )
         rec.update(window_s=done[-1] - t0, steps=len(done))
         gaps = sorted((b - a, i) for i, (a, b) in enumerate(zip([t0] + done, done)))
@@ -448,37 +580,19 @@ def run_cell(res: dict, seed: int, seconds: float, trace: bool, *, chip: bool = 
             f"{gaps[len(gaps) // 2][0] * 1e3:.3f} ms between completions, longest "
             + ", ".join(f"#{i} {d * 1e3:.1f} ms" for d, i in gaps[-3:]))
     else:
-        tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
-        try:
-            jax.profiler.start_trace(tmp)
-            state, t0, done, losses, dispatch = drive(
-                loop, state, traffic, first, in_flight=mix["in_flight"],
-                steps=mix["trace_steps"], annotate=jax.profiler.TraceAnnotation,
-            )
-            jax.profiler.stop_trace()
-            events = tr.load(tr.find_xplane(tmp), ("window", "batch", "dispatch", "wait"))
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
-        windows = [e for e in events["host"] if e[0] == "window"]
-        lo, dur = windows[-1][1], windows[-1][2]
-        reduced = tr.reduce(events, (lo, lo + dur))
-        rec.update(trace=reduced, traced_steps=len(done), dispatch_s=dispatch)
-        breakdown = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+        state, done, losses, dispatch, traced = traced_window(prog, state, traffic, first, mix)
+        rec.update(traced, traced_steps=len(done), dispatch_s=dispatch)
+        breakdown = {k: rec["trace"][k] for k in ("device_ops", "idle_gaps")}
     attempted, failed = len(losses), sum(not math.isfinite(x) for x in losses)
 
     peak = memory_peak_bytes(devices)
     rec["memory_peak_bytes"] = peak
-    del state, loop
+    del state, prog
     gc.collect()
 
-    metrics = {}
-    for m in res["per_layer"] if trace else res["end_to_end"]:
-        value = reader(m["name"])(rec)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-
+    metrics = read_metrics(res["per_layer"] if trace else res["end_to_end"], rec)
     ref = reference_readings(res, traffic, seed)
-    correct, checks = judge(compare(prog, ref), res["limits"])
+    correct, checks = judge(compare(readings, ref), res["limits"])
     device = {
         "platform": devices[0].platform,
         "kind": devices[0].device_kind,

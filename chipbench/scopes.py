@@ -16,12 +16,13 @@ named by the same module's instructions, so ``scope_map`` of the module's
 text and ``scope_times`` of a trace (``chipbench.trace.load``) give each
 phase's and stage's self time, with nothing but JAX.
 
-Run as a script, it makes one traced window of a cell as ``chipbench/run.py
---trace 1`` does (same program, warm-up and queued steps, ``trace_steps``
-steps), reads the compiled step's text before the capture opens, and prints
-one JSON line: per-phase device ms a step, the largest stages, the op kinds
-no phase names, and the seconds ``TrainLoop.compiled`` took. ``--keep``
-copies the trace and the module's text into a directory.
+``chipbench/run.py --trace 1`` puts this split into the record its
+per-layer readers get. Run as a script, this module makes one traced window
+of a cell through the same code (``run.traced_window``: same program,
+warm-up and queued steps, ``trace_steps`` steps), without the correctness
+readings, and prints one JSON line: per-phase and collective device ms a
+step, the largest stages and the op kinds no phase names. ``--keep`` copies
+the trace and the module's text into a directory.
 """
 
 from __future__ import annotations
@@ -30,28 +31,27 @@ import argparse
 import json
 import os
 import re
-import shutil
 import sys
-import tempfile
-import time
 from typing import Dict, Tuple
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PHASES = ("fwd_bwd", "reduce", "optimizer")
+# the model's blocks (models/transformer.py; ``moe`` and ``mla`` for the
+# sparse-expert and latent-attention blocks a configuration may bring) run
+# only inside fwd_bwd; JAX hoists a block's loop-invariant work (the rotary
+# tables of attn) out of the scan that forward and backward run, and the
+# hoisted ops keep the block's name alone
+BLOCKS = ("embed", "attn", "mlp", "loss", "moe", "mla")
 # the reduce's stages (core/scalecom.py) and the model's blocks
-# (models/transformer.py)
 STAGES = (
     "fold", "decode", "ef_sum", "select", "ef_update", "scatter", "worker_mean",
     "fused", "encode", "dense", "stats",
-    "embed", "attn", "mlp", "loss",
-)
-# the model's blocks run only inside fwd_bwd; JAX hoists a block's
-# loop-invariant work (the rotary tables of attn) out of the scan that
-# forward and backward run, and the hoisted ops keep the block's name alone
-BLOCKS = ("embed", "attn", "mlp", "loss")
+) + BLOCKS
 UNSCOPED = "unscoped"
 OTHER = "other"
+# the exchange between learners, by opcode
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
 
 _JIT = ("jit(", "pjit(")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
@@ -158,10 +158,67 @@ def scope_map(hlo_text: str) -> Dict[str, Tuple[str, str]]:
     return {name: scope.get(name, (UNSCOPED, OTHER)) for name in own}
 
 
-def instruction(event_name: str) -> str:
-    """``%select_trailing.33 = (...) custom-call(...)`` -> ``select_trailing.33``."""
-    head = event_name.split(" = ", 1)[0].strip()
-    return head.removeprefix("ROOT ").lstrip("%")
+def opcode(line: str) -> str:
+    """The opcode of one instruction line of a module's text:
+    ``%x = (f32[8]{0}, u32[]) all-reduce-start(%y), ...`` -> ``all-reduce-start``."""
+    rest = line.split(" = ", 1)[1]
+    if rest.startswith("("):  # a tuple shape: skip to its closing parenthesis
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                rest = rest[i + 1:]
+                break
+    else:
+        rest = rest.split(" ", 1)[1]
+    return rest.strip().split("(", 1)[0]
+
+
+def collective_map(hlo_text: str) -> Dict[str, Tuple[str, str]]:
+    """{instruction: (part, pair)} for every collective of a compiled module
+    (``COLLECTIVES``), known by its opcode and not by its name. ``part`` is
+    ``sync`` for a collective that runs as one op, ``start`` or ``done``
+    for the two halves of an asynchronous one (``all-reduce-start`` and
+    ``all-reduce-done``, or an ``async-start`` and ``async-done`` around a
+    computation that holds a collective), whose ``pair`` is the name of its
+    start; a fusion or call of a computation that holds a collective is
+    ``sync`` too."""
+    ops: Dict[str, tuple] = {}
+    held: Dict[str, set] = {}  # computation -> the opcodes in it
+    computation = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        op = opcode(line)
+        called = _CALLS.search(line)
+        ops[m.group(1)] = (op, _OPERAND.findall(line[m.end():]),
+                           called.group(1) if called else None)
+        held.setdefault(computation, set()).add(op)
+
+    def calls_collective(called):
+        return called is not None and bool(held.get(called, set()) & set(COLLECTIVES))
+
+    out: Dict[str, Tuple[str, str]] = {}
+    for name, (op, operands, called) in ops.items():
+        if op in COLLECTIVES or (op in ("fusion", "call") and calls_collective(called)):
+            out[name] = ("sync", name)
+        elif op.removesuffix("-start") in COLLECTIVES or (
+            op == "async-start" and calls_collective(called)
+        ):
+            out[name] = ("start", name)
+    for name, (op, operands, _) in ops.items():
+        if op.removesuffix("-done") in COLLECTIVES or op == "async-done":
+            pair = operands[0] if operands else None
+            while pair in ops and ops[pair][0] == "async-update":
+                pair = ops[pair][1][0]
+            if out.get(pair, ("",))[0] == "start":
+                out[name] = ("done", pair)
+    return out
 
 
 def scope_times(trace: dict, window: Tuple[float, float], scopes: dict, top: int = 10) -> dict:
@@ -182,7 +239,7 @@ def scope_times(trace: dict, window: Tuple[float, float], scopes: dict, top: int
         for name, start, dur, own in tr.self_times(trace["devices"][chip]["ops"]):
             if start < lo or start + dur > hi:
                 continue
-            phase, stage = scopes.get(instruction(name), (UNSCOPED, OTHER))
+            phase, stage = scopes.get(tr.instruction(name), (UNSCOPED, OTHER))
             if phase == UNSCOPED:
                 kind = tr.op_kind(name)
                 unscoped_ns[kind] = unscoped_ns.get(kind, 0.0) + own
@@ -208,54 +265,30 @@ def scoped_run(res: dict, seed: int, keep: str = None) -> dict:
     import jax
 
     from chipbench import run
-    from chipbench import trace as tr
 
     devices = jax.devices()
     run.check_devices(devices, res["cell"]["chips"])
     run.enable_compile_cache()
     mix = res["mix"]
-    loop, make_state, _ = run.build(res)
-    state, traffic = run.start(res, make_state, seed)
+    prog = run.build(res)
+    state, traffic = run.start(res, prog, seed)
     first = mix["checked_steps"] + mix["warmup_steps"]
-    state, *_ = run.drive(loop, state, traffic, 0, in_flight=mix["in_flight"], steps=first)
+    state, *_ = run.drive(prog, state, traffic, 0, in_flight=mix["in_flight"], steps=first)
     jax.block_until_ready(state)
-    t0 = time.perf_counter()
-    hlo = loop.compiled(state, jax.device_put(traffic.batch(first)), first).as_text()
-    compiled_s = time.perf_counter() - t0
-    scopes = scope_map(hlo)
-    tmp = tempfile.mkdtemp(prefix="chipbench-scopes-")
-    try:
-        jax.profiler.start_trace(tmp)
-        state, _, done, _, dispatch = run.drive(
-            loop, state, traffic, first, in_flight=mix["in_flight"],
-            steps=mix["trace_steps"], annotate=jax.profiler.TraceAnnotation,
-        )
-        jax.profiler.stop_trace()
-        path = tr.find_xplane(tmp)
-        events = tr.load(path, ("window", "batch", "dispatch", "wait"))
-        if keep:
-            os.makedirs(keep, exist_ok=True)
-            shutil.copy(path, os.path.join(keep, "scoped.xplane.pb"))
-            with open(os.path.join(keep, "scoped.hlo.txt"), "w") as f:
-                f.write(hlo)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    window = [e for e in events["host"] if e[0] == "window"][-1]
-    lo, hi = window[1], window[1] + window[2]
-    reduced = tr.reduce(events, (lo, hi))
-    split = scope_times(events, (lo, hi), scopes)
-    steps = len(done)
+    state, done, _, dispatch, rec = run.traced_window(prog, state, traffic, first, mix, keep)
+    split, steps = rec["scopes"], len(done)
 
     def ms(s):
         return s / steps * 1e3
 
     return {
-        "device": {"kind": devices[0].device_kind, "count": len(devices)},
+        "device": {"kind": devices[0].device_kind, "count": res["cell"]["chips"]},
         "traced_steps": steps,
-        "compiled_s": compiled_s,
         "host_dispatch_ms": sum(dispatch) / len(dispatch) * 1e3,
-        "busy_ms": ms(reduced["busy_s"]),
-        "reduce_kernel_ms": ms(reduced["kernel_s"]),
+        "busy_ms": ms(rec["trace"]["busy_s"]),
+        "reduce_kernel_ms": ms(rec["trace"]["kernel_s"]),
+        "collective_ms": ms(rec["trace"]["collective_s"]),
+        "collective_exposed_ms": ms(rec["trace"]["collective_exposed_s"]),
         "phase_ms": {p: ms(split["scope_s"].get(p, 0.0)) for p in PHASES},
         "unscoped_ms": ms(split["unscoped_s"]),
         "scopes": [[k, s / steps] for k, s in list(split["stage_s"].items())[:10]],

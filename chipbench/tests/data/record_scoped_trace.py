@@ -30,8 +30,9 @@ def main() -> int:
     keep = tempfile.mkdtemp(prefix="chipbench-record-")
     try:
         result = scopes.scoped_run(res, 5, keep)
-        for name in ("scoped.xplane.pb", "scoped.hlo.txt"):
-            shutil.copy(os.path.join(keep, name), os.path.join(HERE, f"small_{name}"))
+        for kept, name in (("trace.xplane.pb", "small_scoped.xplane.pb"),
+                           ("step.hlo.txt", "small_scoped.hlo.txt")):
+            shutil.copy(os.path.join(keep, kept), os.path.join(HERE, name))
     finally:
         shutil.rmtree(keep, ignore_errors=True)
     print(json.dumps(result))

@@ -42,7 +42,7 @@ def test_control_in_bfloat16_is_not_correct():
     assert not result["correct"], result["checks"]
 
 
-@pytest.mark.parametrize("fault", faults.FAULTS)
+@pytest.mark.parametrize("fault", faults.applicable(1))
 def test_fault_is_not_correct(monkeypatch, fault):
     from repro.training import TrainLoop
 
@@ -53,7 +53,7 @@ def test_fault_is_not_correct(monkeypatch, fault):
 
 def test_compare_leaves_out_roundoff_leaves():
     ref = {"loss": [2.0], "grad_norms": [1.0, 1.0, 1e-9], "ghat_norms": [1.0, 1.0, 1e-9],
-           "delta_norms": [1.0, 1.0, 1e-9], "ghat_bf16_share": 0.0}
+           "delta_norms": [1.0, 1.0, 1e-9], "ghat_bf16_share": 0.0, "grad_bf16_share": 0.0}
     prog = dict(ref, delta_norms=[1.0, 1.0, 5e-9])
     assert run.compare(prog, ref)["delta_norm_gap"] == 0.0
     prog = dict(ref, delta_norms=[1.0, 0.5, 1e-9])
@@ -72,3 +72,32 @@ def test_bf16_share_tells_float32_from_bfloat16_values():
     rounded = jax.tree.map(lambda v: v.astype(jnp.bfloat16).astype(jnp.float32), tree)
     assert float(share(rounded)) == 1.0
     assert float(share({"a": jnp.zeros(4)})) == 0.0
+
+
+def test_judge_leaves_out_a_null_limit_and_refuses_a_missing_one():
+    gaps = dict.fromkeys(run.CHECKS, 0.5)
+    limits = dict.fromkeys(run.CHECKS, 1.0)
+    assert run.judge(gaps, limits)[0] is True
+    assert run.judge(gaps, dict(limits, loss_gap=0.1))[0] is False
+    assert run.judge(gaps, dict(limits, loss_gap=None))[0] is True  # not compared
+    del limits["loss_gap"]
+    assert run.judge(gaps, limits)[0] is False
+
+
+def test_grad_bf16_share_reads_learners_gradients_from_residues():
+    import jax
+    import jax.numpy as jnp
+
+    ref = run.reference_module({"reference": "transformer"})
+    g = jax.random.normal(jax.random.PRNGKey(1), (2, 4096), jnp.float32)
+    selected = jnp.arange(4096) % 64 == 0  # each learner's own value: no residue
+
+    def residue(grad):
+        return jnp.where(selected, 0.0, 0.1 * grad)
+
+    assert float(ref.grad_bf16_share({"a": residue(g)}, 0.1)) < 0.01
+    rounded = g.astype(jnp.bfloat16).astype(jnp.float32)
+    assert float(ref.grad_bf16_share({"a": residue(rounded)}, 0.1)) == 1.0
+    # tensors weigh alike: one bfloat16 tensor of two reads a half
+    both = {"a": residue(g), "b": residue(rounded)[:, :128]}
+    assert float(ref.grad_bf16_share(both, 0.1)) == pytest.approx(0.5, abs=0.01)

@@ -81,6 +81,10 @@ def test_component_unwraps_transforms(part, name):
      "rematted_computation/mlp/dot_general", ("fwd_bwd", "mlp")),
     ("jit(train_step)/fwd_bwd/vmap(transpose(jvp(loss)))/mul", ("fwd_bwd", "loss")),
     ("jit(train_step)/optimizer/mul", ("optimizer", "other")),
+    # the blocks a sparse-expert or latent-attention configuration names
+    ("jit(train_step)/fwd_bwd/jvp(vmap())/while/body/closed_call/moe/dot_general",
+     ("fwd_bwd", "moe")),
+    ("jit(train_step)/fwd_bwd/vmap(transpose(jvp(mla)))/mul", ("fwd_bwd", "mla")),
     # a block's loop-invariant work hoisted out of the step's scopes
     ("jit(train_step)/attn/cos", ("fwd_bwd", "attn")),
     ("jit(train_step)/add", ("unscoped", "other")),
@@ -112,6 +116,66 @@ def test_scope_map_on_hand_made_module():
     assert m["Arg_0.1"] == m["copy.8"] == m["q"] == ("unscoped", "other")
     assert m["step.1"] == ("unscoped", "other")
     assert m["add.2"] == ("unscoped", "other")
+
+
+COLLECTIVE_HLO = """\
+HloModule jit_train_step, is_scheduled=true
+
+%async_computation (p: f32[8]) -> f32[2] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %reduce-scatter.1 = f32[2]{0} reduce-scatter(%p), dimensions={0}, to_apply=%add
+}
+
+%gather_fusion (q: f32[2]) -> f32[8] {
+  %q = f32[2]{0} parameter(0)
+  ROOT %all-gather.2 = f32[8]{0} all-gather(%q), dimensions={0}
+}
+
+%fused_add (r: f32[8]) -> f32[8] {
+  %r = f32[8]{0} parameter(0)
+  ROOT %add.3 = f32[8]{0} add(%r, %r)
+}
+
+ENTRY %main.9 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %all-reduce.1 = f32[8]{0} all-reduce(%x), channel_id=1, to_apply=%add
+  %all-reduce-start.2 = f32[8]{0} all-reduce-start(%all-reduce.1), to_apply=%add
+  %mul.3 = f32[8]{0} multiply(%x, %x)
+  %all-reduce-done.2 = f32[8]{0} all-reduce-done(%all-reduce-start.2)
+  %async-start.4 = ((f32[8]{0}), f32[2]{0}, u32[]) async-start(%mul.3), calls=%async_computation
+  %async-update.4 = ((f32[8]{0}), f32[2]{0}, u32[]) async-update(%async-start.4), calls=%async_computation
+  %async-done.4 = f32[2]{0} async-done(%async-update.4), calls=%async_computation
+  %fusion.5 = f32[8]{0} fusion(%async-done.4), kind=kCustom, calls=%gather_fusion
+  %all-reduce-fusion.6 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_add
+  %collective-permute.7 = f32[8]{0} collective-permute(%x), source_target_pairs={{0,1}}
+  ROOT %tuple.8 = (f32[8]{0}, f32[8]{0}) tuple(%fusion.5, %collective-permute.7)
+}
+"""
+
+
+def test_collective_map_reads_opcodes_not_names():
+    m = scopes.collective_map(COLLECTIVE_HLO)
+    assert m["all-reduce.1"] == ("sync", "all-reduce.1")
+    assert m["all-reduce-start.2"] == ("start", "all-reduce-start.2")
+    assert m["all-reduce-done.2"] == ("done", "all-reduce-start.2")
+    # a generic async pair around a collective, through its update
+    assert m["async-start.4"] == ("start", "async-start.4")
+    assert m["async-done.4"] == ("done", "async-start.4")
+    assert "async-update.4" not in m
+    # a fusion of a collective is one; a fusion named like one is not
+    assert m["fusion.5"] == ("sync", "fusion.5")
+    assert "all-reduce-fusion.6" not in m
+    assert m["collective-permute.7"] == ("sync", "collective-permute.7")
+    assert not {"x", "mul.3", "tuple.8", "add.3"} & set(m)
+
+
+@pytest.mark.parametrize("line, op", [
+    ("  %a = f32[8]{0:T(1024)} all-reduce(%x), to_apply=%r", "all-reduce"),
+    ("  %b = (f32[8]{0}, u32[]) all-reduce-start(%x)", "all-reduce-start"),
+    ("  ROOT %c = f32[8,128]{1,0:T(8,128)} fusion(%x), kind=kLoop", "fusion"),
+])
+def test_opcode_of_an_instruction_line(line, op):
+    assert scopes.opcode(line) == op
 
 
 def _event(instruction, start, dur):
@@ -160,21 +224,6 @@ def test_scope_times_refuses_a_trace_without_a_device():
 # ---------------------------------------------------------------------------
 
 
-def _opcode(line):
-    """The opcode of one instruction line of a module's text."""
-    rest = line.split(" = ", 1)[1]
-    if rest.startswith("("):  # a tuple shape: skip to its closing parenthesis
-        depth = 0
-        for i, ch in enumerate(rest):
-            depth += {"(": 1, ")": -1}.get(ch, 0)
-            if depth == 0:
-                rest = rest[i + 1:]
-                break
-    else:
-        rest = rest.split(" ", 1)[1]
-    return rest.strip().split("(", 1)[0]
-
-
 def _tiny_loop(**sc):
     from repro.configs import registry
     from repro.core.compressors import CompressorConfig
@@ -211,7 +260,7 @@ def test_compiled_step_is_covered_by_phases(variant, stages):
     work = [
         line for line in text.splitlines()
         if scopes._INSTRUCTION.match(line)
-        and _opcode(line) in ("fusion", "dot", "custom-call", "reduce", "scatter", "while")
+        and scopes.opcode(line) in ("fusion", "dot", "custom-call", "reduce", "scatter", "while")
     ]
     assert work
     for line in work:
@@ -286,6 +335,6 @@ def test_recorded_scoped_trace_reduce_kernels_are_the_kernel_time(recorded):
     in_reduce = [
         own for name, start, dur, own in trace.self_times(events["devices"][0]["ops"])
         if trace.is_kernel(name) and lo <= start and start + dur <= hi
-        and smap[scopes.instruction(name)][0] == "reduce"
+        and smap[trace.instruction(name)][0] == "reduce"
     ]
     assert sum(in_reduce) * 1e-9 == pytest.approx(kernel_s, rel=1e-9)

@@ -61,3 +61,21 @@ def test_names_and_references():
         assert m["moves"] in e2e and set(m.get("workloads", CELLS)) <= set(CELLS)
     for m in SPEC["end_to_end"]:
         assert 0 < m["bound"] <= 0.25 and m["source"] in ("host_clock", "device_trace")
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_one_learner_per_chip(workload):
+    res = run.resolve(workload)
+    assert res["mix"]["workers"] == res["cell"]["chips"]
+
+
+def test_resolve_refuses_workers_other_than_chips(tmp_path):
+    spec = dict(SPEC, workloads=[dict(SPEC["workloads"][0], name="stacked", chips=4)])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    traffic = tmp_path / "chipbench" / "traffic"
+    traffic.mkdir(parents=True)
+    cell = SPEC["workloads"][0]
+    (traffic / f"{cell['traffic']}.json").write_text(
+        open(os.path.join(ROOT, "chipbench", "traffic", f"{cell['traffic']}.json")).read())
+    with pytest.raises(ValueError, match="1 workers for 4 chips"):
+        run.resolve("stacked", root=str(tmp_path))

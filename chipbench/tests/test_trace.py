@@ -93,3 +93,72 @@ def test_recorded_tpu_trace():
     dev = events["devices"][0]["ops"]
     inside = trace.clip(dev, lo, lo + dur)
     assert len(inside) >= len(dev) // 2
+
+
+def _op(name, start, dur):
+    return (f"%{name} = f32[8]{{0}} op(%x)", start, dur)
+
+
+# as chipbench.scopes.collective_map gives them
+COLLECTIVES = {
+    "all-reduce.1": ("sync", "all-reduce.1"),
+    "all-reduce-start.2": ("start", "all-reduce-start.2"),
+    "all-reduce-done.2": ("done", "all-reduce-start.2"),
+    "collective-permute.7": ("sync", "collective-permute.7"),
+}
+
+
+def test_collective_time_and_the_part_no_other_op_hides():
+    # chip 0, window [0, 100): a sync all-reduce [0, 10) alone; an async
+    # all-reduce from its start at 20 to its done's end at 55, with a multiply
+    # [22, 42) under way; a collective-permute [60, 70) inside a while op
+    # [60, 90) that also holds a multiply [75, 85): the loop hides nothing.
+    # chip 1: an async all-reduce started before the window, done at 8.
+    tr = {
+        "devices": {
+            0: {"ops": [_op("all-reduce.1", 0, 10), _op("all-reduce-start.2", 20, 2),
+                        _op("mul.3", 22, 20), _op("all-reduce-done.2", 50, 5),
+                        _op("while.9", 60, 30), _op("collective-permute.7", 60, 10),
+                        _op("mul.3", 75, 10)], "kernels": []},
+            1: {"ops": [_op("all-reduce-start.2", -10, 2), _op("all-reduce-done.2", 5, 3)],
+                "kernels": []},
+        },
+        "host": [],
+    }
+    r = trace.reduce(tr, (0, 100), collectives=COLLECTIVES)
+    assert r["collective_s"] == pytest.approx((10 + 35 + 10 + 8) / 2 * 1e-9)
+    assert r["collective_exposed_s"] == pytest.approx((10 + 15 + 10 + 8) / 2 * 1e-9)
+    # busy time counts ops alone: not the in-flight gaps [42, 50) and [0, 5)
+    assert r["busy_s"] == pytest.approx((10 + 22 + 5 + 30 + 3) / 2 * 1e-9)
+    # without the module's collectives there is nothing to read
+    r = trace.reduce(tr, (0, 100))
+    assert r["collective_s"] == r["collective_exposed_s"] == 0
+
+
+def test_overlap_of_interval_lists():
+    assert trace.overlap([(0, 10), (20, 30)], [(5, 25)]) == 10
+    assert trace.overlap([(0, 10)], []) == 0
+    assert trace.overlap([(0, 10)], [(0, 2), (4, 6), (9, 12)]) == 5
+
+
+def test_recorded_four_chip_trace():
+    # four learners, one a chip, at a tiny size (``data/record_four_chip_trace.py``)
+    from chipbench import run, scopes
+
+    events = trace.load(os.path.join(os.path.dirname(SMALL), "four_chip.xplane.pb"),
+                        run.HOST_SPANS)
+    with open(os.path.join(os.path.dirname(SMALL), "four_chip.hlo.txt")) as f:
+        hlo = f.read()
+    collectives = scopes.collective_map(hlo)
+    # as read when the trace was recorded: the leader's indices and the
+    # worker mean, two synchronous all-reduces a step, on every chip
+    assert sorted(collectives) == ["all-reduce.26", "all-reduce.27"]
+    assert list(events["devices"]) == [0, 1, 2, 3]
+    for dev in events["devices"].values():
+        assert sum(trace.instruction(n) in collectives for n, _, _ in dev["ops"]) == 6
+    r = run.traced_record(events, hlo)["trace"]
+    assert r["chips"] == 4
+    assert 0 < r["collective_exposed_s"] <= r["collective_s"] < r["busy_s"] <= r["window_s"]
+    assert r["collective_s"] == pytest.approx(112.084e-6)
+    assert r["collective_exposed_s"] == pytest.approx(112.084e-6)
+    assert r["busy_s"] == pytest.approx(415.83475e-6)

@@ -11,7 +11,10 @@ one plane per chip, ``/device:TPU:<n>``, whose line ``XLA Ops`` has one
 event per executed HLO operation, named by the instruction's whole HLO text
 (``%select_trailing.33 = (...) custom-call(...), custom_call_target=
 "tpu_custom_call", ...``). Events nest: a ``while`` op spans the ops of its
-body. A Pallas kernel compiled by Mosaic is a ``tpu_custom_call``. The host
+body. A Pallas kernel compiled by Mosaic is a ``tpu_custom_call``. A
+collective is known by its instruction's opcode in the compiled module
+(``chipbench.scopes.collective_map``); an asynchronous one is two events,
+its ``-start`` and its ``-done``, with other ops between them. The host
 plane ``/host:CPU`` has a line per thread; ``jax.profiler.TraceAnnotation``
 spans are events on the thread that opened them (``python3``). Device and
 host events share one clock.
@@ -87,21 +90,76 @@ def op_kind(name: str) -> str:
     return base if dot and suffix.isdigit() else head
 
 
-def self_times(events: Sequence[Event]) -> List[Tuple[str, float, float, float]]:
-    """(name, start, duration, self time) of each event of one line, where
-    events nest (a ``while`` op spans the ops of its body): self time is the
-    duration less that of the direct children."""
+def instruction(event_name: str) -> str:
+    """``%select_trailing.33 = (...) custom-call(...)`` -> ``select_trailing.33``."""
+    head = event_name.split(" = ", 1)[0].strip()
+    return head.removeprefix("ROOT ").lstrip("%")
+
+
+def parents(events: Sequence[Event]) -> List[int]:
+    """The index of each event's direct parent on one line, where events
+    nest (a ``while`` op spans the ops of its body), or -1."""
     order = sorted(range(len(events)), key=lambda i: (events[i][1], -events[i][2]))
-    child = [0.0] * len(events)
+    parent = [-1] * len(events)
     stack: List[int] = []
     for i in order:
         _, start, dur = events[i]
         while stack and events[stack[-1]][1] + events[stack[-1]][2] <= start:
             stack.pop()
         if stack:
-            child[stack[-1]] += dur
+            parent[i] = stack[-1]
         stack.append(i)
+    return parent
+
+
+def self_times(events: Sequence[Event]) -> List[Tuple[str, float, float, float]]:
+    """(name, start, duration, self time) of each event of one line: self
+    time is the duration less that of the direct children."""
+    child = [0.0] * len(events)
+    for (_, _, dur), p in zip(events, parents(events)):
+        if p >= 0:
+            child[p] += dur
     return [(n, s, d, d - c) for (n, s, d), c in zip(events, child)]
+
+
+def collective_spans(events: Sequence[Event], collectives: Dict[str, Tuple[str, str]]):
+    """(collective events, other ops' events) of one chip's line.
+
+    ``collectives`` maps an instruction to (part, pair) as
+    ``chipbench.scopes.collective_map`` gives it: a ``sync`` collective is
+    its own event; an async one spans from its ``start`` event to the end of
+    the next ``done`` event of the same pair. The other ops are the events
+    that are no collective and hold no other event (a loop spans ops that
+    may be collectives)."""
+    spans: List[Event] = []
+    opened: Dict[str, float] = {}
+    for name, start, dur in sorted(events, key=lambda e: e[1]):
+        part, pair = collectives.get(instruction(name), (None, None))
+        if part == "start":
+            opened[pair] = start
+        elif part == "done" and pair in opened:
+            s = opened.pop(pair)
+            spans.append((pair, s, start + dur - s))
+        elif part == "sync":
+            spans.append((name, start, dur))
+    holders = set(parents(events))
+    others = [
+        e for i, e in enumerate(events)
+        if i not in holders and instruction(e[0]) not in collectives
+    ]
+    return spans, others
+
+
+def overlap(a: Sequence[Tuple[float, float]], b: Sequence[Tuple[float, float]]) -> float:
+    """Length of the intersection of two sorted lists of disjoint intervals."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
 
 
 def clip(events: Iterable[Event], lo: float, hi: float) -> List[Tuple[float, float]]:
@@ -147,16 +205,19 @@ def _host_label(gap: Tuple[float, float], host: Sequence[Event]) -> str:
     return label
 
 
-def reduce(trace: dict, window: Tuple[float, float], top: int = 10) -> dict:
+def reduce(trace: dict, window: Tuple[float, float], top: int = 10,
+           collectives: Dict[str, Tuple[str, str]] = None) -> dict:
     """Per-chip busy and kernel time inside ``window`` (ns), averaged over the
     chips; the operation kinds with the most self time among the operations
-    that lie wholly inside the window; and the longest idle gaps, each named
-    by the host span that overlaps it most."""
+    that lie wholly inside the window; the longest idle gaps, each named
+    by the host span that overlaps it most; and, of the ``collectives``
+    (``collective_spans``), the union of their intervals and the part of it
+    in which no other op runs on the chip, also averaged over the chips."""
     lo, hi = window
     chips = sorted(trace["devices"])
     if not chips:
         raise ValueError("the trace has no TPU device plane")
-    busy_ns = kernel_ns = 0.0
+    busy_ns = kernel_ns = collective_ns = exposed_ns = 0.0
     op_ns: Dict[str, float] = {}
     all_gaps: List[Tuple[float, str]] = []
     host = [e for e in trace["host"] if e[0] != "window"]
@@ -165,6 +226,10 @@ def reduce(trace: dict, window: Tuple[float, float], top: int = 10) -> dict:
         merged = union(clip(dev["ops"], lo, hi))
         busy_ns += sum(e - s for s, e in merged)
         kernel_ns += sum(e - s for s, e in union(clip(dev["kernels"], lo, hi)))
+        spans, others = collective_spans(dev["ops"], collectives or {})
+        held = union(clip(spans, lo, hi))
+        collective_ns += sum(e - s for s, e in held)
+        exposed_ns += sum(e - s for s, e in held) - overlap(held, union(clip(others, lo, hi)))
         for name, start, dur, own in self_times(dev["ops"]):
             if start >= lo and start + dur <= hi:
                 kind = op_kind(name)
@@ -179,6 +244,8 @@ def reduce(trace: dict, window: Tuple[float, float], top: int = 10) -> dict:
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_ns / n * 1e-9,
         "kernel_s": kernel_ns / n * 1e-9,
+        "collective_s": collective_ns / n * 1e-9,
+        "collective_exposed_s": exposed_ns / n * 1e-9,
         "device_ops": [[name, ns / n * 1e-9] for name, ns in top_ops],
         "idle_gaps": [[label, ns * 1e-9] for ns, label in all_gaps[:top]],
     }
