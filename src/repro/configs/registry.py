@@ -18,6 +18,7 @@ _MODULES: Dict[str, str] = {
     "qwen2.5-14b": "repro.configs.qwen2_5_14b",
     "command-r-plus-104b": "repro.configs.command_r_plus_104b",
     "whisper-medium": "repro.configs.whisper_medium",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "paper-transformer-base": "repro.configs.paper_transformer",
 }
 

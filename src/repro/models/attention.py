@@ -22,7 +22,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import common
+from repro.models import common, mla
 from repro.distributed.sharding import constrain
 
 Array = jnp.ndarray
@@ -36,6 +36,9 @@ NEG_INF = -1e30
 
 
 def init_attention(cfg, store: common.ParamStore, stacked: int = 0, prefix: str = "attn"):
+    if cfg.kv_lora_rank:
+        mla.init_mla(cfg, store, stacked=stacked, prefix=prefix)
+        return
     D, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     store.dense(f"{prefix}_wq", (D, H * hd), ("embed", "heads"), stacked=stacked)
     store.dense(f"{prefix}_wk", (D, KV * hd), ("embed", "kv"), stacked=stacked)
@@ -48,6 +51,8 @@ def init_attention(cfg, store: common.ParamStore, stacked: int = 0, prefix: str 
 
 
 def _project_qkv(cfg, p, x, kv_x, positions, kv_positions, dtype, rope, prefix):
+    if cfg.kv_lora_rank:
+        return mla.project_qkv(cfg, p, x, positions, dtype, rope, prefix)
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q = x @ p[f"{prefix}_wq"].astype(dtype)
@@ -82,13 +87,14 @@ def attention_core(
     window: Optional[int],
     q_chunk: int = 512,
 ) -> Array:
-    """q: (B, S, H, hd); k/v: (B, T, KV, hd); *_pos absolute positions (S,) / (T,).
+    """q, k: (B, S, H, hd), (B, T, KV, hd); v: (B, T, KV, vd); *_pos absolute
+    positions (S,) / (T,).
 
-    Returns (B, S, H, hd). Scans q chunks with a checkpointed body so backward
+    Returns (B, S, H, vd). Scans q chunks with a checkpointed body so backward
     recomputes scores instead of storing (B, H, S, T).
     """
     B, S, H, hd = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, vd = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     scale = hd**-0.5
     q_chunk = min(q_chunk, S)
@@ -116,7 +122,7 @@ def attention_core(
 
     body = jax.checkpoint(body)
     _, out = jax.lax.scan(body, None, (qg, qpos_c))
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, n_chunks * q_chunk, H, hd)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, n_chunks * q_chunk, H, vd)
     return out[:, :S]
 
 
@@ -148,15 +154,22 @@ def attention_train(
     out = attention_core(q, k, v, positions, kv_pos,
                          causal=causal and not cross, window=window)
     B, S = x.shape[:2]
-    out = out.reshape(B, S, cfg.n_heads * cfg.hd)
+    out = out.reshape(B, S, -1)
     return out @ p[f"{prefix}_wo"].astype(dtype)
 
 
+def _cache_heads(cfg) -> Tuple[int, int, int]:
+    """(key/value heads, key head dim, value head dim) a cache holds."""
+    if cfg.kv_lora_rank:  # the expanded heads, not the latent
+        return cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    return cfg.n_kv_heads, cfg.hd, cfg.hd
+
+
 def init_cache(cfg, batch: int, capacity: int, dtype) -> Dict[str, Array]:
-    KV, hd = cfg.n_kv_heads, cfg.hd
+    KV, hd, vd = _cache_heads(cfg)
     return {
         "k": jnp.zeros((batch, capacity, KV, hd), dtype),
-        "v": jnp.zeros((batch, capacity, KV, hd), dtype),
+        "v": jnp.zeros((batch, capacity, KV, vd), dtype),
         "slot_pos": jnp.full((capacity,), -1, jnp.int32),
     }
 
@@ -182,7 +195,7 @@ def attention_prefill(
             "v": cache["v"].at[:, slots].set(vs),
             "slot_pos": cache["slot_pos"].at[slots].set(pos_tail.astype(jnp.int32)),
         }
-    out = out.reshape(B, S, cfg.n_heads * cfg.hd)
+    out = out.reshape(B, S, -1)
     return out @ p[f"{prefix}_wo"].astype(dtype), new_cache
 
 
@@ -206,7 +219,8 @@ def attention_decode(
     causal=False attends to every populated slot (encoder memory).
     """
     B = x.shape[0]
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    H = cfg.n_heads
+    KV, hd, _ = _cache_heads(cfg)
     G = H // KV
     pos_arr = jnp.reshape(pos, (1,)).astype(jnp.int32)
     if update_cache:
@@ -239,5 +253,5 @@ def attention_decode(
         valid &= (pos - spos) < window
     s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1).astype(dtype)
-    o = jnp.einsum("bkgqt,btkd->bqkgd", w, v).reshape(B, 1, H * hd)
+    o = jnp.einsum("bkgqt,btkd->bqkgd", w, v).reshape(B, 1, -1)
     return o @ p[f"{prefix}_wo"].astype(dtype), cache

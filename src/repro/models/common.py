@@ -108,8 +108,8 @@ def layernorm(x: Array, scale: Array, bias: Array, eps: float = 1e-5) -> Array:
 
 def apply_norm(cfg, x: Array, p: Dict[str, Array], prefix: str) -> Array:
     if cfg.norm == "layernorm":
-        return layernorm(x, p[f"{prefix}_scale"], p[f"{prefix}_bias"])
-    return rmsnorm(x, p[f"{prefix}_scale"])
+        return layernorm(x, p[f"{prefix}_scale"], p[f"{prefix}_bias"], cfg.norm_eps or 1e-5)
+    return rmsnorm(x, p[f"{prefix}_scale"], cfg.norm_eps or 1e-6)
 
 
 def init_norm(cfg, store: ParamStore, prefix: str, d: int, stacked: int = 0):

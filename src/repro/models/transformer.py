@@ -2,7 +2,9 @@
 
 Uniform stacks (dense / moe / ssm / enc-dec) scan over layers with stacked
 (L, ...) parameters and a rematerialized block body (compile time and HBM stay
-flat in depth — essential for the 61-layer / 64-layer archs). The 1:2 hybrid
+flat in depth — essential for the 61-layer / 64-layer archs). An MoE model may
+lead with ``first_dense_layers`` dense layers, a stack of their own
+(``dense_blocks``) scanned before the MoE stack (``blocks``). The 1:2 hybrid
 (RecurrentGemma) uses a python loop over its heterogeneous 26 layers.
 
 A ``Model`` exposes:
@@ -88,7 +90,8 @@ def _block_train(
         x, _ = rglru.rglru_block(cfg, p, x, state, dtype=dtype)
         return _apply_mlp(cfg, p, x, dtype), aux
     causal = kind != "enc"
-    with jax.named_scope("attn"):
+    # latent attention has a block name of its own, so that a trace splits it out
+    with jax.named_scope("mla" if cfg.kv_lora_rank else "attn"):
         xn = common.apply_norm(cfg, x, p, "ln_attn")
         x = x + attn.attention_train(
             cfg, p, xn, positions, dtype=dtype, causal=causal, window=window,
@@ -101,9 +104,10 @@ def _block_train(
             kv_positions=enc_pos, prefix="cross",
         )
     if kind == "moe":
-        xn = common.apply_norm(cfg, x, p, "ln_mlp")
-        h, aux = moe.moe_ffn(cfg, p, xn, dtype=dtype)
-        return x + h, aux
+        with jax.named_scope("moe"):
+            xn = common.apply_norm(cfg, x, p, "ln_mlp")
+            h, aux = moe.moe_ffn(cfg, p, xn, dtype=dtype)
+            return x + h, aux
     return _apply_mlp(cfg, p, x, dtype), aux
 
 
@@ -148,8 +152,11 @@ class Model:
                 sub = tail.subtree(f"layer_{i}_{kind}")
                 _init_block(cfg, sub, kind, stacked=0)
         else:
+            n_dense = cfg.first_dense_layers
+            if n_dense:
+                _init_block(cfg, store.subtree("dense_blocks"), kinds[0], stacked=n_dense)
             blocks = store.subtree("blocks")
-            _init_block(cfg, blocks, kinds[0], stacked=cfg.n_layers)
+            _init_block(cfg, blocks, kinds[-1], stacked=cfg.n_layers - n_dense)
         return store.params, store.axes
 
     # ---------------- shared helpers ----------------
@@ -238,17 +245,21 @@ class Model:
                     x, _ = _block_train(cfg, pl, x, positions, kind, dtype=dt,
                                         window=self._window(kind))
             else:
-                kind = cfg._layer_kinds()[0]
-                window = self._window(kind)
+                kinds = cfg._layer_kinds()
 
-                def body(x, pl):
-                    x, aux = _block_train(cfg, pl, x, positions, kind, dtype=dt,
-                                          window=window)
-                    return x, aux
+                def stack(x, layers, kind):
+                    window = self._window(kind)
 
-                x, aux_stack = jax.lax.scan(
-                    jax.checkpoint(body), x, params["blocks"]
-                )
+                    def body(x, pl):
+                        x, aux = _block_train(cfg, pl, x, positions, kind, dtype=dt,
+                                              window=window)
+                        return x, aux
+
+                    return jax.lax.scan(jax.checkpoint(body), x, layers)
+
+                if cfg.first_dense_layers:
+                    x, _ = stack(x, params["dense_blocks"], kinds[0])
+                x, aux_stack = stack(x, params["blocks"], kinds[-1])
                 aux_total = {k: jnp.mean(v) for k, v in aux_stack.items()}
 
         with jax.named_scope("loss"):
@@ -300,8 +311,12 @@ class Model:
             }
             tail = [one(kind) for kind in tail_kinds]
             return {"units": units, "tail": tail}
-        cap = self._cache_capacity(seq_len, kinds[0])
-        return {"kv": _stack_caches(cfg, cfg.n_layers, batch, cap, dt)}
+        cap = self._cache_capacity(seq_len, kinds[-1])
+        n_dense = cfg.first_dense_layers
+        state = {"kv": _stack_caches(cfg, cfg.n_layers - n_dense, batch, cap, dt)}
+        if n_dense:
+            state["dense_kv"] = _stack_caches(cfg, n_dense, batch, cap, dt)
+        return state
 
     def prefill(self, params, batch, seq_len: int) -> Tuple[Array, Pytree]:
         """Encode a full prompt, returning last-position logits + decode state."""
@@ -381,27 +396,20 @@ class Model:
                 new_tail.append(st)
             state = {"units": new_units, "tail": new_tail}
         else:
-            kind = cfg._layer_kinds()[0]
-            window = self.decode_window or self._window(kind)
 
-            def body(x, inp):
-                pl, cache = inp
-                xn = common.apply_norm(cfg, x, pl, "ln_attn")
-                h, cache = attn.attention_prefill(cfg, pl, xn, positions, cache,
-                                                  dtype=dt, window=window)
-                x = x + h
-                if kind == "moe":
-                    xn = common.apply_norm(cfg, x, pl, "ln_mlp")
-                    h, _ = moe.moe_ffn(cfg, pl, xn, dtype=dt)
-                    x = x + h
-                else:
-                    x = _apply_mlp(cfg, pl, x, dt)
-                return x, cache
+            def stack(x, layers, caches, kind):
+                window = self.decode_window or self._window(kind)
 
-            x, new_kv = jax.lax.scan(
-                jax.checkpoint(body), x, (params["blocks"], state["kv"])
-            )
-            state = {"kv": new_kv}
+                def body(x, inp):
+                    pl, cache = inp
+                    xn = common.apply_norm(cfg, x, pl, "ln_attn")
+                    h, cache = attn.attention_prefill(cfg, pl, xn, positions, cache,
+                                                      dtype=dt, window=window)
+                    return _apply_ffn(cfg, pl, x + h, kind, dt), cache
+
+                return jax.lax.scan(jax.checkpoint(body), x, (layers, caches))
+
+            x, state = _dense_then_blocks(cfg, params, state, x, stack)
 
         x = common.apply_norm(cfg, x, params, "ln_final")
         logits = common.lm_logits(params, x[:, -1:, :], dt)
@@ -477,26 +485,21 @@ class Model:
                 new_tail.append(st)
             state = {"units": new_units, "tail": new_tail}
         else:
-            kind = cfg._layer_kinds()[0]
-            window = self.decode_window or self._window(kind)
-            rope = True  # RoPE for all non-enc-dec archs (enc-dec = sinusoidal)
 
-            def body(x, inp):
-                pl, cache = inp
-                xn = common.apply_norm(cfg, x, pl, "ln_attn")
-                h, cache = attn.attention_decode(cfg, pl, xn, pos, cache, dtype=dt,
-                                                 window=window, rope=rope)
-                x = x + h
-                if kind == "moe":
-                    xn = common.apply_norm(cfg, x, pl, "ln_mlp")
-                    h, _ = moe.moe_ffn(cfg, pl, xn, dtype=dt)
-                    x = x + h
-                else:
-                    x = _apply_mlp(cfg, pl, x, dt)
-                return x, cache
+            def stack(x, layers, caches, kind):
+                window = self.decode_window or self._window(kind)
 
-            x, new_kv = jax.lax.scan(body, x, (params["blocks"], state["kv"]))
-            state = {"kv": new_kv}
+                def body(x, inp):
+                    pl, cache = inp
+                    xn = common.apply_norm(cfg, x, pl, "ln_attn")
+                    # RoPE for all non-enc-dec archs (enc-dec = sinusoidal)
+                    h, cache = attn.attention_decode(cfg, pl, xn, pos, cache, dtype=dt,
+                                                     window=window, rope=True)
+                    return _apply_ffn(cfg, pl, x + h, kind, dt), cache
+
+                return jax.lax.scan(body, x, (layers, caches))
+
+            x, state = _dense_then_blocks(cfg, params, state, x, stack)
 
         x = common.apply_norm(cfg, x, params, "ln_final")
         logits = common.lm_logits(params, x, dt)
@@ -506,6 +509,25 @@ class Model:
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
+
+
+def _apply_ffn(cfg, p, x, kind, dtype):
+    """The MLP half of a block outside training (prefill, decode)."""
+    if kind == "moe":
+        xn = common.apply_norm(cfg, x, p, "ln_mlp")
+        return x + moe.moe_ffn(cfg, p, xn, dtype=dtype)[0]
+    return _apply_mlp(cfg, p, x, dtype)
+
+
+def _dense_then_blocks(cfg, params, state, x, stack):
+    """Run ``stack(x, layers, caches, kind)`` over the leading dense layers,
+    if any, then over the uniform block stack. Returns (x, new state)."""
+    kinds = cfg._layer_kinds()
+    new = {}
+    if cfg.first_dense_layers:
+        x, new["dense_kv"] = stack(x, params["dense_blocks"], state["dense_kv"], kinds[0])
+    x, new["kv"] = stack(x, params["blocks"], state["kv"], kinds[-1])
+    return x, new
 
 
 def _hybrid_units(cfg) -> Tuple[int, Tuple[str, ...]]:

@@ -17,7 +17,8 @@ B, S = 2, 32
 def _batch(cfg, key, with_labels=True):
     b = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab)}
     if with_labels:
-        b["labels"] = jax.random.randint(key, (B, S), 0, cfg.vocab)
+        # labels apart from tokens: a tied head scores a token's own id highest
+        b["labels"] = jax.random.randint(jax.random.fold_in(key, 1), (B, S), 0, cfg.vocab)
         b["mask"] = jnp.ones((B, S))
     if cfg.arch_type == "vlm":
         b["vision"] = jax.random.normal(key, (B, cfg.vision_tokens, cfg.d_model))
@@ -45,7 +46,12 @@ def test_forward_loss_finite(models, name):
     assert np.isfinite(float(loss))
     assert float(loss) > 0
     if cfg.n_experts:
-        assert "moe_lb_loss" in aux and np.isfinite(float(aux["moe_lb_loss"]))
+        # every routed pair lands on a held expert when all are held
+        assert float(aux["moe_routed_here"]) == 1.0
+        if cfg.router == "softmax":
+            assert "moe_lb_loss" in aux and np.isfinite(float(aux["moe_lb_loss"]))
+        else:  # noaux_tc: the bias balances the load, no auxiliary loss
+            assert "moe_lb_loss" not in aux
 
 
 @pytest.mark.parametrize("name", ARCHS)
@@ -66,18 +72,9 @@ def test_train_step_updates_and_finite(models, name):
 def test_prefill_decode_consistency(models, name):
     """decode_step(token T) after prefill(tokens[:T]) must reproduce the
     prefill logits of the T+1-length prompt — exercises every cache layout.
-
-    MoE archs are rebuilt with a no-drop capacity factor: capacity-based token
-    dropping legitimately depends on the co-batched token count, so exact
-    prefix consistency only holds when nothing overflows.
-    """
+    MoE dispatch is dropless, so a token's output does not depend on the
+    tokens batched with it."""
     cfg, m, params, _ = models[name]
-    if cfg.n_experts:
-        import dataclasses
-
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
-        m = build_model(cfg, compute_dtype="float32", loss_chunk=16)
-        params, _ = m.init(jax.random.PRNGKey(0))
     key = jax.random.PRNGKey(3)
     batch = _batch(cfg, key, with_labels=False)
     toks = batch["tokens"]
@@ -139,10 +136,18 @@ def test_recurrent_state_is_context_length_independent(models, name):
 
 def test_param_counts_match_analytic():
     """ArchConfig.param_count() tracks actual init within 10% (smoke scale)."""
-    for name in ["phi3-medium-14b", "starcoder2-3b", "qwen2.5-14b"]:
+    for name in ["phi3-medium-14b", "starcoder2-3b", "qwen2.5-14b", "moonlight-16b-a3b"]:
         cfg = registry.smoke(name)
         m = build_model(cfg, compute_dtype="float32")
         params, _ = m.init(jax.random.PRNGKey(0))
         actual = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
         est = cfg.param_count()
         assert abs(actual - est) / actual < 0.10, (name, actual, est)
+
+
+def test_moonlight_counts_are_the_published_sizes():
+    """16B-A3B: every expert of the 26 MoE layers held, 6 of 64 a token."""
+    cfg = registry.arch("moonlight-16b-a3b")
+    assert cfg.param_count() == 15_960_110_208
+    assert cfg.active_param_count() == 2_914_776_192
+    assert cfg._layer_kinds() == ("attn",) + ("moe",) * 26
