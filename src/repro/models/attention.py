@@ -1,11 +1,23 @@
-"""Grouped-query attention: training (q-chunked, memory-efficient), prefill
-(returns KV cache), and single-token decode (full or ring-buffer window cache).
+"""Grouped-query attention: training, prefill (returns KV cache), and
+single-token decode (full or ring-buffer window cache).
 
-Memory strategy: attention rows are independent given full K/V, so the training
-path scans over query chunks with a rematerialized body (Rabe-Staats style) — the
-(B, H, S, S) score tensor never materializes; peak extra memory is
-(B, H, q_chunk, S). This is the pure-JAX/XLA-TPU analogue of flash attention and
-what lets prefill_32k lower with sane memory.
+Training takes one of two paths, chosen from what the trace can observe
+(``attention_train``):
+
+  * the blockwise causal kernel (``kernels.flash_attention``), for causal
+    self-attention over positions 0..S-1 (``in_order``: the caller built
+    them so, and the order is known statically) with no window, on a TPU,
+    in a program on one device (GSPMD cannot partition a Mosaic kernel),
+    when S is whole kernel blocks and more than one (``flash_applies``).
+    Score tiles stay in VMEM, key blocks above the diagonal are skipped,
+    and the backward runs from the saved log-sum-exp;
+  * ``attention_core`` everywhere else (short sequences, a window,
+    cross-attention, arbitrary positions, prefill, the CPU, a mesh): query
+    chunks scanned under a rematerialized body (Rabe-Staats), masking by
+    absolute position, so the (B, H, S, S) score tensor never
+    materializes; peak extra memory is (B, H, q_chunk, S).
+
+Both compute in float32 with the matmul operands at the default precision.
 
 Cache layout: {"k": (B, C, KV, hd), "v": (B, C, KV, hd), "slot_pos": (C,) int32}
 where slot_pos[j] is the absolute position held in slot j (-1 = empty). Full
@@ -22,6 +34,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention
+from repro.kernels import ops as kernel_ops
 from repro.models import common, mla
 from repro.distributed.sharding import constrain
 
@@ -131,6 +145,17 @@ def attention_core(
 # ---------------------------------------------------------------------------
 
 
+def flash_applies(seq: int, *, causal: bool, window: Optional[int], cross: bool) -> bool:
+    """Whether training attention over positions 0..seq-1 takes the blockwise
+    kernel: causal self-attention with no window, over whole kernel blocks
+    and more than one, on a TPU, in a program on one device (GSPMD cannot
+    partition a Mosaic kernel over a mesh)."""
+    return (
+        causal and window is None and not cross and flash_attention.fits(seq)
+        and kernel_ops.on_tpu() and jax.device_count() == 1
+    )
+
+
 def attention_train(
     cfg,
     p,
@@ -144,16 +169,29 @@ def attention_train(
     kv_positions: Optional[Array] = None,
     rope: bool = True,
     prefix: str = "attn",
+    in_order: bool = False,
 ) -> Array:
-    """Full-sequence attention (training / encoding). positions: (S,)."""
+    """Full-sequence attention (training / encoding). positions: (S,).
+    ``in_order``: the caller built ``positions`` as 0..S-1, which the
+    blockwise kernel needs to know statically; an arbitrary positions array
+    keeps the masked scan."""
     cross = kv_x is not None
-    kv_src = kv_x if cross else x
-    kv_pos = kv_positions if cross else positions
-    q, k, v = _project_qkv(cfg, p, x, kv_src, positions, kv_pos, dtype,
-                           rope and not cross, prefix)
-    out = attention_core(q, k, v, positions, kv_pos,
-                         causal=causal and not cross, window=window)
     B, S = x.shape[:2]
+    if in_order and flash_applies(S, causal=causal, window=window, cross=cross):
+        def project(p, x, positions):
+            return _project_qkv(cfg, p, x, x, positions, positions, dtype, rope, prefix)
+
+        # the attention's own weights alone: the backward makes q, k and v
+        # again from them
+        own = {name: w for name, w in p.items() if name.startswith(f"{prefix}_")}
+        out = flash_attention.projected_attention(project, (own, x, positions)).astype(dtype)
+    else:
+        kv_src = kv_x if cross else x
+        kv_pos = kv_positions if cross else positions
+        q, k, v = _project_qkv(cfg, p, x, kv_src, positions, kv_pos, dtype,
+                               rope and not cross, prefix)
+        out = attention_core(q, k, v, positions, kv_pos,
+                             causal=causal and not cross, window=window)
     out = out.reshape(B, S, -1)
     return out @ p[f"{prefix}_wo"].astype(dtype)
 

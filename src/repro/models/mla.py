@@ -5,8 +5,10 @@ Keys and values come from one latent of ``kv_lora_rank`` values a token
 (RMS-normalised) and one rotary key of ``qk_rope_head_dim`` shared by every
 head; queries and keys are ``qk_nope_head_dim`` content dims followed by
 ``qk_rope_head_dim`` rotary dims, values ``v_head_dim``. The attention itself
-is ``attention.attention_core`` over the expanded heads, so a query and key
-head (nope + rope) may be wider than a value head. Rotary positions rotate the
+runs over the expanded heads, a query and key head (nope + rope) wider than
+a value head, by ``attention.attention_train``'s rule: the blockwise causal
+kernel (``kernels.flash_attention``) for a long sequence in training on a
+TPU, ``attention.attention_core`` otherwise. Rotary positions rotate the
 two halves of the rotary dims (``common.apply_rope``); the published code
 de-interleaves them first, which on seeded weights is a fixed permutation of
 the rotary columns.

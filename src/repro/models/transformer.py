@@ -75,9 +75,11 @@ def _apply_mlp(cfg, p, x, dtype):
 
 
 def _block_train(
-    cfg, p, x, positions, kind, *, dtype, window, enc_out=None, enc_pos=None
+    cfg, p, x, positions, kind, *, dtype, window, enc_out=None, enc_pos=None,
+    in_order=False,
 ):
-    """One block forward (training). Returns (x, aux)."""
+    """One block forward (training). Returns (x, aux). ``in_order``: the
+    caller built ``positions`` as 0..S-1 (``attention.attention_train``)."""
     aux: Dict[str, Array] = {}
     if kind == "ssm":
         B = x.shape[0]
@@ -96,6 +98,7 @@ def _block_train(
         x = x + attn.attention_train(
             cfg, p, xn, positions, dtype=dtype, causal=causal, window=window,
             rope=kind not in ("enc", "encdec_dec"),  # enc-dec uses sinusoidal
+            in_order=in_order,
         )
     if kind == "encdec_dec":
         xn = common.apply_norm(cfg, x, p, "ln_cross")
@@ -222,7 +225,7 @@ class Model:
             def body(x, pl):
                 x, _ = _block_train(cfg, pl, x, positions, "encdec_dec", dtype=dt,
                                     window=cfg.sliding_window, enc_out=enc_out,
-                                    enc_pos=enc_pos)
+                                    enc_pos=enc_pos, in_order=True)
                 return x, None
 
             x, _ = jax.lax.scan(jax.checkpoint(body), x, ep_layers(dp))
@@ -236,14 +239,15 @@ class Model:
                     for pos, kind in enumerate(cfg.hybrid_pattern):
                         pl = up[f"u{pos}_{kind}"]
                         x, _ = _block_train(cfg, pl, x, positions, kind,
-                                            dtype=dt, window=self._window(kind))
+                                            dtype=dt, window=self._window(kind),
+                                            in_order=True)
                     return x, None
 
                 x, _ = jax.lax.scan(jax.checkpoint(unit_body), x, params["units"])
                 for i, kind in enumerate(tail_kinds):
                     pl = params["tail"][f"layer_{i}_{kind}"]
                     x, _ = _block_train(cfg, pl, x, positions, kind, dtype=dt,
-                                        window=self._window(kind))
+                                        window=self._window(kind), in_order=True)
             else:
                 kinds = cfg._layer_kinds()
 
@@ -252,7 +256,7 @@ class Model:
 
                     def body(x, pl):
                         x, aux = _block_train(cfg, pl, x, positions, kind, dtype=dt,
-                                              window=window)
+                                              window=window, in_order=True)
                         return x, aux
 
                     return jax.lax.scan(jax.checkpoint(body), x, layers)

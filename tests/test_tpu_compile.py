@@ -195,3 +195,36 @@ def test_reduce_compiles_without_tensor_copies_for_v5e(one_chip):
             glue.append((opcode, name, shape))
     assert hlo.count("tpu_custom_call") >= 6  # 3 launches a tensor
     assert not glue, glue
+
+
+# ---------------------------------------------------------------------------
+# the blockwise causal attention kernel at the long cells' attention shapes
+# ---------------------------------------------------------------------------
+
+# (B, S, H, KV, q/k head, v head): StarCoder2-3B's grouped-query heads at
+# 8 x 4096, Moonlight's latent attention (128 + 64 rotary, v 128) at 1 x 8192
+ATTENTION = {"starcoder2": (8, 4096, 24, 2, 128, 128), "moonlight": (1, 8192, 16, 16, 192, 128)}
+
+
+@pytest.mark.parametrize("cell", sorted(ATTENTION))
+def test_flash_attention_compiles_for_v5e(one_chip, cell):
+    """Forward and backward (flash_fwd, flash_dq, flash_dkv) under the
+    training step's vmap over one learner: Mosaic accepts the block shapes,
+    the 192-wide heads and the VMEM the tiles take."""
+    from repro.kernels import flash_attention
+
+    B, S, H, KV, hd, vd = ATTENTION[cell]
+
+    def fwd_bwd(q, k, v, do):
+        attn = jax.vmap(lambda q, k, v: flash_attention.causal_attention(q, k, v, interpret=False))
+        o, back = jax.vjp(attn, q, k, v)
+        return (o,) + back(do)
+
+    f32 = jnp.float32
+    hlo = _compile(one_chip, fwd_bwd, [((1, B, S, H, hd), f32), ((1, B, S, KV, hd), f32),
+                                       ((1, B, S, KV, vd), f32), ((1, B, S, H, vd), f32)])
+    calls = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)", line).group(1) for line in calls]
+    assert len(names) == 3, names
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert any(kernel in name for name in names), names
