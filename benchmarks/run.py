@@ -22,7 +22,6 @@ BENCHES = [
     ("fig3_hamming", "benchmarks.bench_fig3_hamming"),
     ("fig6_perfmodel", "benchmarks.bench_fig6_perfmodel"),
     ("rate_sweep", "benchmarks.bench_rate_sweep"),
-    ("kernels", "benchmarks.bench_kernels"),
     ("overlap", "benchmarks.bench_overlap"),
     ("scenarios", "benchmarks.bench_scenarios"),
     ("roofline", "benchmarks.bench_roofline"),

@@ -22,10 +22,9 @@ then load /tmp/trace/trace.json in chrome://tracing or Perfetto.
 
 On TPU-class backends the whole per-tensor inner loop (select -> EF update
 -> ghat scatter) can run as ONE VMEM-resident Pallas launch instead of
-three: set ``ScaleComConfig(fused=True)`` (or ``SCALECOM_FUSED=1`` with the
-default ``fused="auto"``) — bitwise-identical results, ~7 -> ~3 modeled HBM
-passes over the residue; see ROADMAP.md "Backend surface" and
-``benchmarks/bench_kernels.py`` for the fused-vs-3-launch numbers.
+three: set ``ScaleComConfig(fused=True)`` (it is off by default) —
+bitwise-identical results, ~7 -> ~3 modeled HBM passes over the residue; see
+ROADMAP.md for what the chip has measured of it.
 """
 
 import sys
@@ -73,8 +72,8 @@ def overlap_preview(bucket_mb: float = 25.0):
 
         PYTHONPATH=src python -m repro.launch.train --bucket-mb 25
 
-    (or `SCALECOM_BUCKET_MB=25` in the environment; `--no-overlap` keeps the
-    buckets but drops the ordering hints). Here we just print the modeled
+    (`--no-overlap` keeps the buckets but drops the ordering hints; without
+    `--bucket-mb` the reduce is unbucketed). Here we just print the modeled
     timeline for the paper's transformer: how much of the compressed
     all-reduce hides behind backward compute at this bucket size.
     """

@@ -161,7 +161,7 @@ def reduce_hbm_passes(
     and the 3-phase re-streaming (4 of the 7 passes) disappears entirely.
     ``chunk``/``topm`` only move the dropped O(topm/chunk) payload terms;
     they are accepted so callers can stamp the modeled geometry next to
-    measured numbers (benchmarks/bench_kernels.py).
+    measured numbers.
     """
     g = max(1, workers)
     ghat = 1.0 / g

@@ -10,13 +10,14 @@ location in its findings:
                     compat layer is the single import point of JAX API that
                     moves between releases, so an upgrade that moves one
                     is a one-file change.
-  env-at-import     no ``os.environ`` *reads* at module top level. Every
-                    env-driven choice in this repo (SCALECOM_LAYOUT /
-                    SCALECOM_BACKEND / SCALECOM_BUCKET_MB / autotune cache)
-                    is probed at CALL time so tests can monkeypatch and
-                    long-lived processes honour late exports. Top-level env
-                    *writes* stay legal — launch/dryrun.py must pin XLA_FLAGS
-                    before jax initialises.
+  env-at-import     no ``os.environ`` *reads* at module top level. The few
+                    settings read from the environment (the compile cache's
+                    JAX_COMPILATION_CACHE_DIR, output paths of the harness
+                    and the benches) are read at CALL time so tests can
+                    monkeypatch and long-lived processes honour late
+                    exports; no environment variable chooses a kernel path.
+                    Top-level env *writes* stay legal — launch/dryrun.py
+                    must pin XLA_FLAGS before jax initialises.
   no-rw-surface     no ``rw_*`` symbol anywhere: the dual flat/rowwise op
                     surface is gone for good (PR 3); a reappearing rw_ helper
                     means a feature is about to land twice, once per layout.

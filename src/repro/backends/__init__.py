@@ -1,24 +1,20 @@
 """Pluggable kernel backends for the ScaleCom hot path.
 
     from repro.backends import resolve_backend
-    be = resolve_backend("auto")          # env var > TPU probe > jnp
+    be = resolve_backend("auto")          # pallas on a TPU, else jnp
     idx, vals = be.select(ef, chunk)      # one launch, worker axis included
 
 See base.py for the protocol and resolution rules, jnp_backend.py /
-pallas_backend.py for the two shipped implementations, and autotune.py for
-the tile-geometry cache. ``ScaleComConfig.backend`` threads a spec through
-``scalecom_reduce``; the SCALECOM_BACKEND env var overrides "auto" (that is
-the CI leg that runs the whole tier-1 suite through pallas-interpret).
+pallas_backend.py for the two shipped implementations.
+``ScaleComConfig.backend`` threads a spec through ``scalecom_reduce``.
 """
 
 from repro.backends.base import (
-    FUSABLE_MODES,
     KernelBackend,
     available_backends,
     pallas_available,
     register_backend,
     resolve_backend,
-    resolve_fused,
 )
 
 # Importing the implementation modules registers them.
@@ -26,11 +22,9 @@ from repro.backends import jnp_backend as _jnp_backend  # noqa: F401
 from repro.backends import pallas_backend as _pallas_backend  # noqa: F401
 
 __all__ = [
-    "FUSABLE_MODES",
     "KernelBackend",
     "available_backends",
     "pallas_available",
     "register_backend",
     "resolve_backend",
-    "resolve_fused",
 ]

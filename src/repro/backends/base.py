@@ -4,8 +4,7 @@ ScaleCom's per-step compute cost is the chunk-wise selection + error-feedback
 update (paper Table 1: ~3 FLOPs/element for the compressor; the EF residue is
 the largest state in the system). ``scalecom_reduce`` routes every chunked
 operation through a ``KernelBackend`` so the same algorithm runs on the
-pure-jnp oracles (CPU, any-device correctness path) or the Pallas TPU kernels
-(fused, autotuned — see benchmarks/bench_kernels.py for the measured sweep),
+pure-jnp oracles (CPU, any-device correctness path) or the Pallas TPU kernels,
 selected per run by ``resolve_backend``.
 
 Protocol
@@ -45,23 +44,15 @@ Derived ops that backends override for fusion:
                                              overrides it with the
                                              single-launch VMEM-resident
                                              kernel (kernels.fused_reduce).
-                                             Only shared-index compressors
-                                             are fusable (mode "clt_k" /
-                                             "true_topk"); the reduce falls
-                                             back to the unfused path for
-                                             the rest (local_topk, random_k,
-                                             exact).
+                                             Only the shared-index
+                                             compressors are fusable
+                                             (core.compressors.FUSABLE_MODES).
 
 so a minimal backend is exactly {select_indices, gather, scatter}.
 ``lane_dense(shape, chunk, dtype)`` reports whether a kernel backend tiles
 such an array lane-dense (the telemetry's ``lane_dense`` tap); the base
-answers False.
-
-Whether the reduce *calls* fused_reduce is a separate, orthogonal resolution:
-``resolve_fused(spec)`` with spec True/False/"auto" ("auto" = the
-SCALECOM_FUSED env var at call time, default off until the on-TPU sweep
-lands — see ROADMAP). Explicit config wins over env, mirroring
-layout/backend resolution.
+answers False. Whether the reduce *calls* fused_reduce is the explicit
+``ScaleComConfig.fused`` boolean (see core.scalecom._execute).
 
 Resolution
 ----------
@@ -69,21 +60,19 @@ Resolution
 
   "jnp"     the pure-jnp reference backend (core.chunked ops)
   "pallas"  the Pallas kernels; native on TPU, interpret mode elsewhere
-  "auto"    call-time probes, compat-layer style (repro.compat.jax_compat):
-            the SCALECOM_BACKEND env var wins if set; otherwise pallas iff
-            the pallas package imports AND jax.default_backend() == "tpu"
-            (interpret mode is a correctness path, not a fast CPU path);
-            jnp otherwise.
-  a KernelBackend instance — returned as-is (tests, custom backends)
+  "auto"    (or None) the platform decides: pallas iff the pallas package
+            imports AND jax.default_backend() == "tpu" (interpret mode is a
+            correctness path, not a fast CPU path); jnp otherwise.
+  a registered name, or a KernelBackend instance (returned as-is)
 
-Probes run at call time, not import time, so tests can monkeypatch either
-branch and deployments that hot-swap jax stay correct. Third-party backends
-register with ``register_backend(name, factory)``.
+The platform is probed at call time, not import time, so tests can
+monkeypatch either branch. Nothing else — no environment variable, no cache
+on disk — takes part. Third-party backends register with
+``register_backend(name, factory)``.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Callable, Dict, Tuple, Union
 
 import jax
@@ -95,19 +84,11 @@ Array = jnp.ndarray
 
 __all__ = [
     "KernelBackend",
-    "FUSABLE_MODES",
     "register_backend",
     "available_backends",
     "resolve_backend",
-    "resolve_fused",
     "pallas_available",
 ]
-
-# Selection modes fused_reduce implements — the shared-index compressors.
-# Must agree with kernels.fused_reduce.FUSABLE_MODES (kept separate so this
-# module never imports the pallas package).
-FUSABLE_MODES = ("clt_k", "true_topk")
-
 
 class KernelBackend:
     """Dispatch target for the chunked hot-path ops (see module docstring)."""
@@ -205,14 +186,14 @@ class KernelBackend:
         launches on a kernel backend). PallasBackend overrides it with the
         single-launch VMEM-resident kernel.
         """
+        from repro.core.compressors import FUSABLE_MODES, leader_pick
+
         if mode not in FUSABLE_MODES:
             raise ValueError(
                 f"fused_reduce supports modes {FUSABLE_MODES}, got {mode!r}"
             )
         ef = m + g
         if mode == "clt_k":
-            from repro.core.compressors import leader_pick
-
             idx = leader_pick(self.select_indices(ef, chunk, topm), leader)
         else:
             idx = self.select_indices(jnp.mean(ef, axis=0), chunk, topm)
@@ -231,8 +212,6 @@ class KernelBackend:
 # ---------------------------------------------------------------------------
 
 _REGISTRY: Dict[str, Callable[[], KernelBackend]] = {}
-
-_ENV_VAR = "SCALECOM_BACKEND"
 
 
 def register_backend(name: str, factory: Callable[[], KernelBackend]) -> None:
@@ -260,20 +239,15 @@ def resolve_backend(
 ) -> KernelBackend:
     """Resolve a backend spec ("auto" | "jnp" | "pallas" | instance).
 
-    See the module docstring for the "auto" probe order. Raises ValueError
-    for unknown names (listing what is registered).
+    See the module docstring for what "auto" picks. Raises ValueError for
+    unknown names (listing what is registered).
     """
     if isinstance(spec, KernelBackend):
         return spec
     name = spec or "auto"
     if name == "auto":
-        env = os.environ.get(_ENV_VAR, "").strip()
-        if env:
-            name = env
-        elif pallas_available() and jax.default_backend() == "tpu":
-            name = "pallas"
-        else:
-            name = "jnp"
+        on_tpu = pallas_available() and jax.default_backend() == "tpu"
+        name = "pallas" if on_tpu else "jnp"
     try:
         factory = _REGISTRY[name]
     except KeyError:
@@ -282,40 +256,3 @@ def resolve_backend(
             f"{sorted(_REGISTRY)} (register_backend to add one)"
         ) from None
     return factory()
-
-
-_FUSED_ENV = "SCALECOM_FUSED"
-_FUSED_TRUE = ("1", "true", "on", "yes")
-_FUSED_FALSE = ("0", "false", "off", "no")
-
-
-def resolve_fused(spec: Union[bool, str, None] = "auto") -> bool:
-    """Resolve the fused-reduce decision (True | False | "auto").
-
-    Explicit booleans win unconditionally ("explicit beats env", same
-    contract as layout/backend resolution). "auto"/None reads the
-    SCALECOM_FUSED env var at CALL time (so tests and hot-swapping
-    deployments see updates): accepted truthy values {1, true, on, yes},
-    falsy {0, false, off, no} (case-insensitive); unset/empty means False —
-    the fused kernel stays opt-in until the on-TPU autotune sweep validates
-    native lowering (ROADMAP follow-up). Anything else raises naming the
-    valid set.
-    """
-    if isinstance(spec, bool):
-        return spec
-    if spec in (None, "auto"):
-        env = os.environ.get(_FUSED_ENV, "").strip().lower()
-        if not env:
-            return False
-        if env in _FUSED_TRUE:
-            return True
-        if env in _FUSED_FALSE:
-            return False
-        raise ValueError(
-            f"invalid {_FUSED_ENV}={env!r}; expected one of "
-            f"{_FUSED_TRUE + _FUSED_FALSE}"
-        )
-    raise ValueError(
-        f"fused must be True, False, or 'auto' "
-        f"(then ${_FUSED_ENV} decides); got {spec!r}"
-    )

@@ -11,8 +11,7 @@ regression would hide — and keeps this module off the pallas import
 ``jax.experimental``).
 
 Used by the launch-count tripwire in tests/test_kernels.py (fused reduce
-must be 1 launch, the composed path 3) and the launches column of
-benchmarks/bench_kernels.py.
+must be 1 launch, the composed path 3).
 """
 
 from __future__ import annotations

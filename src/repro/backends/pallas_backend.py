@@ -8,8 +8,7 @@ scatter, scatter, axpy) into
     1 launch  select          — worker-stacked per-chunk argmax (+ top-m)
     1 launch  ef_update       — fused ef=m+g / gather / scatter / axpy
                                 (~2.3x less HBM traffic on the residue, the
-                                largest state in the system — model and
-                                measured sweep in benchmarks/bench_kernels.py)
+                                largest state in the system)
     1 launch  scatter         — densify the k reduced values into ĝ
 
 in *both* layouts: every op goes through the trailing-axis wrappers in
@@ -23,11 +22,11 @@ buffers take lane-dense tiles, which the kernels read and write in place.
 
 Execution mode is a call-time probe: native Mosaic lowering when
 jax.default_backend() == "tpu", interpret mode elsewhere (the same math at
-host speed — the CPU correctness path, exercised by the
-SCALECOM_BACKEND=pallas CI leg). ``chip_smoke.py`` asserts the native mode
-on the chip. Tile geometry per (op, chunk, dtype, size)
-comes from the repro.backends.autotune on-disk cache, falling back to the
-kernel default when untuned.
+host speed — the CPU correctness path, exercised by tests/test_paths.py).
+``chip_smoke.py`` asserts the native mode on the chip. The chunks a grid
+step covers are the constants of kernels.chunk_topk: ``DENSE_BLOCK_CHUNKS``
+for a lane-dense launch, ``BLOCK_CHUNKS`` otherwise (the fused launch
+included).
 
 Constructing the backend requires the pallas package to import; resolution
 via resolve_backend("pallas") raises a clear error on jax builds without it.
@@ -41,7 +40,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.backends import autotune
 from repro.backends.base import KernelBackend, pallas_available, register_backend
 
 Array = jnp.ndarray
@@ -66,20 +64,12 @@ class PallasBackend(KernelBackend):
             return self._interpret
         return jax.default_backend() != "tpu"
 
-    def _block(self, op: str, shape, chunk: int, dtype) -> int:
-        # Key by the TOTAL chunks of the launch (worker/leading axes
-        # included): a (G, size) launch covers G x n_chunks chunks, i.e. the
-        # same geometry problem autotune() times on a 1-D input of equal
-        # total size (the size key is bucketed to powers of two anyway).
-        # An untuned lane-dense launch takes the lane-dense default.
+    def _block(self, shape, chunk: int, dtype) -> int:
+        """Chunks a grid step of a 3-launch op over ``shape`` covers."""
         from repro.kernels.chunk_topk import BLOCK_CHUNKS, DENSE_BLOCK_CHUNKS
 
-        n_chunks = -(-shape[-1] // chunk) * math.prod(shape[:-1])
-        dense = op != "fused_reduce" and self.lane_dense(shape, chunk, dtype)
-        return autotune.best_block_chunks(
-            op, n_chunks, chunk, dtype,
-            default=DENSE_BLOCK_CHUNKS if dense else BLOCK_CHUNKS,
-        )
+        dense = self.lane_dense(shape, chunk, dtype)
+        return DENSE_BLOCK_CHUNKS if dense else BLOCK_CHUNKS
 
     def lane_dense(self, shape, chunk: int, dtype) -> bool:
         from repro.kernels.chunk_topk import lane_dense
@@ -95,7 +85,7 @@ class PallasBackend(KernelBackend):
 
         return rowwise.select_trailing(
             _padded(x, chunk), chunk, topm, interpret=self._interp(),
-            block_chunks=self._block("select", x.shape, chunk, x.dtype),
+            block_chunks=self._block(x.shape, chunk, x.dtype),
         )
 
     def gather(self, x: Array, idx: Array, chunk: int, topm: int = 1) -> Array:
@@ -103,7 +93,7 @@ class PallasBackend(KernelBackend):
 
         return rowwise.gather_trailing(
             _padded(x, chunk), idx, chunk, topm, interpret=self._interp(),
-            block_chunks=self._block("select", x.shape, chunk, x.dtype),
+            block_chunks=self._block(x.shape, chunk, x.dtype),
         )
 
     def scatter(
@@ -112,15 +102,15 @@ class PallasBackend(KernelBackend):
         from repro.kernels import rowwise
 
         n_chunks = -(-size // chunk)
-        # autotune key: TOTAL launch chunks incl. broadcast leading dims,
-        # matching _block's convention for the other ops
+        # the launch's shape includes the broadcast leading dims, which
+        # decide whether its tiles are lane-dense
         tail = 1 if topm == 1 else 2
         lead = jnp.broadcast_shapes(idx.shape[:-tail], vals.shape[:-tail])
         out = rowwise.scatter_trailing(
             vals, idx, chunk, n_chunks * chunk, topm=topm,
             interpret=self._interp(),
             block_chunks=self._block(
-                "select", tuple(lead) + (n_chunks * chunk,), chunk, vals.dtype
+                tuple(lead) + (n_chunks * chunk,), chunk, vals.dtype
             ),
         )
         return out[..., :size]
@@ -135,7 +125,7 @@ class PallasBackend(KernelBackend):
         m_new, vals = rowwise.ef_update_trailing(
             _padded(m, chunk), _padded(g, chunk), idx, beta, chunk, topm,
             interpret=self._interp(),
-            block_chunks=self._block("ef_update", m.shape, chunk, m.dtype),
+            block_chunks=self._block(m.shape, chunk, m.dtype),
         )
         return m_new[..., :n], vals
 
@@ -153,9 +143,7 @@ class PallasBackend(KernelBackend):
             leader = jnp.zeros((), jnp.int32)
         idx, vals, m_new, ghat = fr.fused_reduce_trailing(
             _padded(m, chunk), _padded(g, chunk), leader, float(beta),
-            chunk, topm, mode,
-            interpret=self._interp(),
-            block_chunks=self._block("fused_reduce", m.shape, chunk, m.dtype),
+            chunk, topm, mode, interpret=self._interp(),
         )
         return idx, vals, m_new[..., :n], ghat[..., :n]
 

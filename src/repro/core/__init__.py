@@ -4,7 +4,7 @@
                "chunk-wise sort"; one op set for both layouts)
 - compressors: CLT-k + baselines (true top-k, local top-k, random-k, none)
 - filter:      low-pass filtered residue update (Eq. 5) + Theorem-1 beta band
-- state:       per-worker residue state + fp32/bf16/fp8 codecs + layout probe
+- state:       per-worker residue state + fp32/bf16/fp8 codecs + chunk layouts
 - plan:        per-tensor reduce planning (rates/layout/shapes/byte rule),
                cached per tree structure
 - scalecom:    Algorithm 1 as a worker-axis gradient reduce (GSPMD-native),

@@ -31,13 +31,12 @@ production implementation; exact dense top-k equivalents are available through
 Every chunked op dispatches through a ``repro.backends`` KernelBackend
 (pure-jnp oracles or the Pallas TPU kernels — top-1 *and* top-m, there is no
 silent jnp fallback). Callers pass a resolved backend; the default resolves
-"auto" (env var > TPU probe > jnp).
+"auto" (pallas on a TPU, else jnp).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -50,8 +49,8 @@ __all__ = [
     "compress",
     "select_indices",
     "exact_k",
-    "resolve_backend_with_deprecation",
     "COMPRESSORS",
+    "FUSABLE_MODES",
     "compression_rate",
 ]
 
@@ -65,16 +64,12 @@ class CompressorConfig:
     topm:       entries kept per chunk
     exact:      use exact dense top-k over the whole tensor instead of chunked
                 selection (analysis only; k = size * topm / chunk)
-    use_kernel: DEPRECATED — use ScaleComConfig(backend="pallas") (or pass a
-                resolved backend to ``compress``). When set, it is mapped onto
-                the pallas backend with a DeprecationWarning.
     """
 
     name: str = "clt_k"
     chunk: int = 64
     topm: int = 1
     exact: bool = False
-    use_kernel: bool = False
 
     def __post_init__(self):
         # fail fast: topm > chunk would silently duplicate indices in the
@@ -92,38 +87,6 @@ class CompressorConfig:
 
 def compression_rate(cfg: CompressorConfig) -> float:
     return cfg.rate
-
-
-# Warn-once latch for the use_kernel deprecation: the resolver runs on every
-# reduce call (once per step in eager loops), and per-call DeprecationWarnings
-# are pure log noise over a long run. Tests reset this to re-assert the warning.
-_use_kernel_warned = False
-
-
-def resolve_backend_with_deprecation(cfg: CompressorConfig, spec="auto"):
-    """Resolve a backend spec, honouring the deprecated use_kernel flag.
-
-    The single home of the use_kernel -> pallas mapping (shared with
-    scalecom._resolve_cfg_backend): when the flag is set it warns (once per
-    process) and maps an "auto"/None spec onto "pallas"; an explicit spec
-    always wins.
-    """
-    from repro.backends import resolve_backend
-
-    if cfg.use_kernel:
-        global _use_kernel_warned
-        if not _use_kernel_warned:
-            _use_kernel_warned = True
-            warnings.warn(
-                "CompressorConfig.use_kernel is deprecated; set "
-                'ScaleComConfig(backend="pallas") (or pass backend= explicitly). '
-                "Mapping use_kernel=True onto the pallas backend.",
-                DeprecationWarning,
-                stacklevel=3,
-            )
-        if spec is None or spec == "auto":
-            spec = "pallas"
-    return resolve_backend(spec)
 
 
 # ---------------------------------------------------------------------------
@@ -217,6 +180,12 @@ _SHARED_INDEX_SELECTORS = {
 
 COMPRESSORS = ("clt_k", "true_topk", "local_topk", "random_k", "none")
 
+# The shared-index compressors: every worker sends values at one index set,
+# so select, EF update and ĝ scatter run as one op (KernelBackend.fused_reduce
+# and the single-launch kernel). local_topk (per-worker index sets) and
+# random_k (counter-PRNG draws, not reproducible in-tile) keep the 3-op path.
+FUSABLE_MODES = ("clt_k", "true_topk")
+
 
 def select_indices(ef: Array, t: Array, cfg: CompressorConfig, backend) -> Array:
     """The chunked index-selection step of each compressor, backend-dispatched.
@@ -284,7 +253,7 @@ def compress(
     """Compress worker-stacked EF gradients ``ef`` (n, size) at step ``t``.
 
     backend: a resolved ``repro.backends.KernelBackend``; None resolves
-    "auto" (or "pallas" under the deprecated cfg.use_kernel flag).
+    "auto".
 
     Returns (values, indices, dense_mean):
       values:     (n, k)  per-worker entries at the shared index set
@@ -305,7 +274,9 @@ def compress(
         return _compress_exact(ef, t, cfg)
 
     if backend is None:
-        backend = resolve_backend_with_deprecation(cfg)
+        from repro.backends import resolve_backend
+
+        backend = resolve_backend()
 
     idx = select_indices(ef, t, cfg, backend)
     vals = backend.gather(ef, idx, cfg.chunk, cfg.topm)

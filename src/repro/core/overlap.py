@@ -32,9 +32,7 @@ changes (asserted over 20-step trajectories by tests/test_overlap.py). With
 ``overlap=False`` the scheduler emits the same per-bucket trace with no
 ordering hints.
 
-Resolution mirrors layout/backend: ``resolve_bucket_bytes`` probes the
-``SCALECOM_BUCKET_MB`` env var at call time (the CI leg that runs tier-1
-through the bucketed pipeline), and explicit specs always win.
+Bucketing is off unless the caller asks for it (``resolve_bucket_bytes``).
 ``analysis.perfmodel.overlap_report`` models the resulting timeline
 (per-bucket compress/comm occupancy vs backward compute) and reports the
 hidden fraction; benchmarks/bench_overlap.py sweeps it.
@@ -42,7 +40,6 @@ hidden fraction; benchmarks/bench_overlap.py sweeps it.
 
 from __future__ import annotations
 
-import os
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -54,15 +51,12 @@ from repro.obs import taps
 Array = jnp.ndarray
 
 __all__ = [
-    "BUCKET_ENV",
     "resolve_bucket_bytes",
     "resolve_buckets",
     "init_token",
     "stage_bucket",
     "fence_bucket",
 ]
-
-BUCKET_ENV = "SCALECOM_BUCKET_MB"
 
 
 def resolve_bucket_bytes(
@@ -71,32 +65,14 @@ def resolve_bucket_bytes(
     """Resolve a bucketing spec to a bucket byte target (None = unbucketed).
 
     spec:
-      None | "auto"  probe $SCALECOM_BUCKET_MB at call time (compat-layer
-                     style, like SCALECOM_LAYOUT / SCALECOM_BACKEND): unset
-                     or <= 0 disables bucketing, otherwise the value is the
-                     bucket size in MB.
-      False          force the unbucketed single-shot path.
+      None | False   the unbucketed single-shot path (the default).
       True           bucketed at ``default_bytes`` (ScaleComConfig.bucket_bytes).
       int/float > 0  explicit bucket size in BYTES.
-
-    Explicit specs always win over the env var.
     """
-    if spec is False:
+    if spec is None or spec is False:
         return None
     if spec is True:
         return int(default_bytes)
-    if spec is None or spec == "auto":
-        env = os.environ.get(BUCKET_ENV, "").strip()
-        if not env:
-            return None
-        try:
-            mb = float(env)
-        except ValueError:
-            raise ValueError(
-                f"invalid ${BUCKET_ENV}={env!r}: expected a bucket size in MB "
-                f"(a number; values <= 0 disable bucketing)"
-            ) from None
-        return int(mb * (1 << 20)) if mb > 0 else None
     if isinstance(spec, (int, float)):
         if spec <= 0:
             raise ValueError(
@@ -105,8 +81,8 @@ def resolve_bucket_bytes(
             )
         return int(spec)
     raise TypeError(
-        f"buckets spec must be None/'auto', bool, a byte count, or a tuple "
-        f"of core.plan.Bucket; got {type(spec).__name__}"
+        f"buckets spec must be None, a bool, a byte count, or a tuple of "
+        f"core.plan.Bucket; got {type(spec).__name__}"
     )
 
 

@@ -56,7 +56,7 @@ import numpy as np
 from repro.core.chunked import num_chunks
 from repro.core.compressors import CompressorConfig, exact_k
 from repro.core.rates import resolve_compressor
-from repro.core.state import CODECS, codec_signature, resolve_layout, storage_shape
+from repro.core.state import CODECS, codec_signature, storage_shape
 
 Shape = Tuple[int, ...]
 
@@ -71,7 +71,7 @@ class TensorPlan:
     shape:         parameter shape (no worker axis)
     size:          element count
     groups:        G — ScaleCom worker count after hierarchical folding
-    layout:        resolved chunk layout ("flat" | "rowwise")
+    layout:        chunk layout ("flat" | "rowwise")
     comp:          resolved CompressorConfig, or None => dense reduce
     storage:       residue storage shape (no worker axis)
     work:          execute-stage view (no worker axis): ``(size,)`` for the
@@ -147,8 +147,8 @@ def _raise_state_drift(
     if actual == codec_signature(residue_dtype, G, storage_shape(shape, other)):
         causes.append(
             f"the residue was initialized under layout={other!r} but this "
-            f"reduce resolved layout={layout!r} (e.g. $SCALECOM_LAYOUT "
-            f"changed between init_state and scalecom_reduce)"
+            f"reduce runs layout={layout!r} (init_state and ScaleComConfig "
+            f"were given different layouts)"
         )
     # worker-axis drift: every codec stores (n, *storage) in its "q" leaf
     actual_by_name = dict((name, sh) for name, sh, _ in actual)
@@ -178,9 +178,8 @@ def _raise_state_drift(
         f"encoding does not match what this reduce's plan (layout={layout!r}, "
         f"residue_dtype={residue_dtype!r}, G={G}) will decode — {detail}. "
         f"Remediation: re-init the state (core.state.init_state) with the "
-        f"current config, or pin the layout explicitly "
-        f"(ScaleComConfig(layout=...) / init_state(layout=...)) so both "
-        f"sides resolve identically; on membership change use "
+        f"current config, or give ScaleComConfig(layout=...) and "
+        f"init_state(layout=...) the same layout; on membership change use "
         f"core.state.remap_state."
     )
 
@@ -368,7 +367,7 @@ def plan_tensors(
     return _plan_cached(
         tuple(leaves),
         frozenset(residue_paths),
-        resolve_layout(cfg.layout),
+        cfg.layout,
         cfg.compressor,
         tuple(cfg.rate_rules),
         cfg.min_size,

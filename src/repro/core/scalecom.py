@@ -41,8 +41,8 @@ The reduce is ONE layout-aware pipeline:
            all-reduce is staged behind an optimization_barrier token chain,
            so XLA can hide per-bucket collectives behind remaining backward
            compute. Launch granularity only — bitwise identical to the
-           single-shot path (``scalecom_reduce(..., buckets=...)``; default
-           "auto" probes $SCALECOM_BUCKET_MB, the bucketed CI leg).
+           single-shot path (``scalecom_reduce(..., buckets=...)``; off by
+           default).
 
 Two chunk layouts (ScaleComConfig.layout):
 
@@ -55,26 +55,22 @@ Two chunk layouts (ScaleComConfig.layout):
              sharding and the *only* collective is the k-value mean. Bitwise
              identical to flat whenever the last dim is a chunk multiple
              (row-major order), and statistically identical otherwise.
-  auto     — the default: the SCALECOM_LAYOUT env var if set (the CI leg
-             that runs tier-1 through the rowwise pipeline), else flat.
 
 Kernel dispatch (ScaleComConfig.backend): every chunked op — selection,
 gather, scatter, and the fused Eq. 5 residue update — routes through the ONE
 trailing-axis op set of a ``repro.backends`` KernelBackend resolved per call
-("auto" probes the SCALECOM_BACKEND env var, pallas importability and
-jax.default_backend()). On the pallas backend the per-tensor inner loop is
-three kernel launches (worker-stacked select, fused EF update, ĝ scatter)
-instead of the 7-pass jnp chain, in both layouts; on the jnp backend it is
-the bitwise reference chain. Trajectories agree across backends to fp32
-tolerance (tests/test_backends.py).
+("auto" is pallas on a TPU, else jnp). On the pallas backend the per-tensor
+inner loop is three kernel launches (worker-stacked select, fused EF update,
+ĝ scatter) instead of the 7-pass jnp chain, in both layouts; on the jnp
+backend it is the bitwise reference chain. Trajectories agree across
+backends to fp32 tolerance (tests/test_backends.py).
 
-Fused inner loop (ScaleComConfig.fused): with ``fused=True`` (or "auto" +
-$SCALECOM_FUSED) the whole inner loop collapses into the backend's ONE
-``fused_reduce`` op — on the pallas backend a single launch keeping each
-chunk tile VMEM-resident across select → EF update → ĝ scatter
-(kernels.fused_reduce, ~3 HBM passes instead of ~7 — see
-analysis.perfmodel.reduce_hbm_passes), on the jnp backend the identical
-3-op composition. Only the shared-index compressors are fusable (clt_k,
+Fused inner loop (ScaleComConfig.fused): with ``fused=True`` the whole inner
+loop collapses into the backend's ONE ``fused_reduce`` op — on the pallas
+backend a single launch keeping each chunk tile VMEM-resident across select
+→ EF update → ĝ scatter (kernels.fused_reduce, ~3 HBM passes instead of ~7
+— see analysis.perfmodel.reduce_hbm_passes), on the jnp backend the
+identical 3-op composition. Only the shared-index compressors are fusable (clt_k,
 true_topk); local_topk / random_k / exact / dense tensors silently take the
 unfused path, so a mixed rate_rules plan works under fused=True. Bitwise
 identical indices and allclose values either way (tests/test_backends.py);
@@ -96,18 +92,24 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.backends.base import FUSABLE_MODES, resolve_fused
+from repro.backends.base import resolve_backend
 from repro.core.compressors import (
+    FUSABLE_MODES,
     CompressorConfig,
     compress,
-    resolve_backend_with_deprecation,
     select_indices,
 )
 from repro.core import overlap
 from repro.core.filter import lowpass_update
 from repro.core.metrics import residue_similarity_report
 from repro.core.plan import TensorPlan, plan_tensors
-from repro.core.state import CODECS, ScaleComState, codec_key, residue_signature
+from repro.core.state import (
+    CODECS,
+    LAYOUTS,
+    ScaleComState,
+    codec_key,
+    residue_signature,
+)
 from repro.obs import taps
 
 Array = jnp.ndarray
@@ -126,23 +128,18 @@ class ScaleComConfig:
     min_size:       tensors smaller than this are reduced densely
     residue_dtype:  fp32 | bf16 | fp8 | fp8_ec (beyond-paper; lossy codecs
                     use stochastic rounding keyed from the step counter)
-    layout:         "auto" (default: $SCALECOM_LAYOUT, else flat) | "flat"
-                    (paper-faithful) | "rowwise" (layout-preserving);
-                    resolved by core.state.resolve_layout at plan time.
+    layout:         "flat" (default; paper-faithful) | "rowwise"
+                    (layout-preserving). init_state must be given the same.
     backend:        kernel backend spec for the chunked hot-path ops:
-                    "auto" (default; SCALECOM_BACKEND env var, then pallas
-                    iff running on TPU, else jnp), "jnp", "pallas", or a
-                    KernelBackend instance. Resolved at trace time with
-                    call-time feature probes (repro.backends).
+                    "auto" (default: pallas iff running on TPU, else jnp),
+                    "jnp", "pallas", or a KernelBackend instance. Resolved
+                    at trace time (repro.backends.resolve_backend).
     fused:          run the per-tensor inner loop through the backend's
                     single ``fused_reduce`` op where the compressor is
                     fusable (clt_k / true_topk — one kernel launch on the
-                    pallas backend instead of three): True | False | "auto"
-                    (default: the $SCALECOM_FUSED env var at call time,
-                    unset = off — the fused CI leg sets it). Explicit
-                    booleans win over env, mirroring layout/backend.
-                    Non-fusable tensors (local_topk, random_k, exact,
-                    dense) silently keep the unfused path, so mixed
+                    pallas backend instead of three). A bool, False by
+                    default. Non-fusable tensors (local_topk, random_k,
+                    exact, dense) keep the unfused path, so mixed
                     rate_rules plans work under fused=True. Identical
                     numerics either way.
     groups:         ScaleCom worker granularity; None => every data rank is a
@@ -152,8 +149,8 @@ class ScaleComConfig:
     bucket_bytes:   dense-byte target per launch bucket of the overlap-aware
                     bucketed reduce (core.plan.plan_buckets; 25 MB default —
                     DDP's bucket_cap_mb heritage). Whether bucketing is ON is
-                    the ``buckets`` argument of ``scalecom_reduce`` (default
-                    "auto": the $SCALECOM_BUCKET_MB env var).
+                    the ``buckets`` argument of ``scalecom_reduce`` (off by
+                    default).
     overlap:        thread the optimization_barrier token chain through the
                     bucketed launch so XLA can interleave per-bucket
                     collectives with remaining backward compute (core.overlap);
@@ -178,9 +175,9 @@ class ScaleComConfig:
     beta: float = 1.0
     min_size: int = 2048
     residue_dtype: str = "fp32"
-    layout: str = "auto"
+    layout: str = "flat"
     backend: Any = "auto"
-    fused: Any = "auto"
+    fused: bool = False
     groups: Optional[int] = None
     warmup_steps: int = 0
     bucket_bytes: int = 25 << 20
@@ -196,8 +193,8 @@ class ScaleComConfig:
         if self.bucket_bytes <= 0:
             raise ValueError(
                 f"bucket_bytes must be positive, got {self.bucket_bytes} "
-                "(bucketing is toggled by scalecom_reduce(buckets=...) / "
-                "$SCALECOM_BUCKET_MB, not by zeroing the size)"
+                "(bucketing is toggled by scalecom_reduce(buckets=...), not "
+                "by zeroing the size)"
             )
         if self.groups is not None and self.groups < 1:
             raise ValueError(
@@ -210,19 +207,15 @@ class ScaleComConfig:
                 f"metrics_every must be >= 0 (0 disables similarity "
                 f"sampling), got {self.metrics_every}"
             )
-        if not (isinstance(self.fused, bool) or self.fused in (None, "auto")):
+        if not isinstance(self.fused, bool):
+            raise ValueError(f"fused must be True or False; got {self.fused!r}")
+        if self.layout not in LAYOUTS:
             raise ValueError(
-                f"fused must be True, False, or 'auto' (then $SCALECOM_FUSED "
-                f"decides at call time); got {self.fused!r}"
+                f"unknown chunk layout {self.layout!r}; expected one of {LAYOUTS}"
             )
 
     def n_workers(self, data_ranks: int) -> int:
         return self.groups if self.groups is not None else data_ranks
-
-
-def _resolve_cfg_backend(cfg: ScaleComConfig):
-    """cfg.backend -> KernelBackend, honouring the deprecated use_kernel flag."""
-    return resolve_backend_with_deprecation(cfg.compressor, cfg.backend)
 
 
 def _group_fold(g: Array, groups: int) -> Array:
@@ -529,10 +522,10 @@ def scalecom_reduce(
 
     grads_pw: pytree of (n_workers, *shape) arrays (unreduced).
     buckets:  launch granularity of the overlap-aware bucketed reduce
-              (core.overlap.resolve_buckets): None/"auto" probes
-              $SCALECOM_BUCKET_MB, False forces the single-shot path, True
-              buckets at cfg.bucket_bytes, an int is an explicit byte target,
-              and a tuple of core.plan.Bucket is a pre-built schedule.
+              (core.overlap.resolve_buckets): None/False is the
+              single-shot path, True buckets at cfg.bucket_bytes, an int is
+              an explicit byte target, and a tuple of core.plan.Bucket is a
+              pre-built schedule.
               Bucketing changes launch order/granularity ONLY — same
               per-tensor plans, same EF residues, bitwise-identical output
               (tests/test_overlap.py).
@@ -566,8 +559,7 @@ def _reduce(
 ) -> Tuple[Pytree, ScaleComState, Dict[str, Array]]:
     """The reduce body (scalecom_reduce minus the telemetry collector)."""
     codec = CODECS[cfg.residue_dtype]
-    backend = _resolve_cfg_backend(cfg)
-    fused = resolve_fused(cfg.fused)
+    backend = resolve_backend(cfg.backend)
     flat, treedef = jax.tree_util.tree_flatten_with_path(grads_pw)
     plans = plan_tensors(
         tuple(
@@ -602,7 +594,7 @@ def _reduce(
         ghat, new_enc, ef_mean = _execute(
             plan, gw, state.residues[plan.path], codec, cfg.beta, t,
             codec_key(plan.path, t), backend, want_ef, cfg.metrics_every,
-            fused,
+            cfg.fused,
         )
         sums = None
         if want_ef:

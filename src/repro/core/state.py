@@ -37,9 +37,8 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 import zlib
-from typing import Any, Dict, Tuple, Union
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +53,7 @@ Shape = Tuple[int, ...]
 __all__ = [
     "ResidueCodec",
     "CODECS",
+    "LAYOUTS",
     "ScaleComState",
     "codec_key",
     "codec_roundtrip_error",
@@ -62,35 +62,13 @@ __all__ = [
     "remap_state",
     "residue_bytes",
     "residue_signature",
-    "resolve_layout",
     "storage_shape",
     "stochastic_round",
 ]
 
-_LAYOUT_ENV = "SCALECOM_LAYOUT"
-_LAYOUTS = ("flat", "rowwise")
+# Chunk layouts (ScaleComConfig.layout; see the module docstring).
+LAYOUTS = ("flat", "rowwise")
 
-
-def resolve_layout(spec: Union[str, None] = "auto") -> str:
-    """Resolve a chunk-layout spec ("auto" | "flat" | "rowwise").
-
-    "auto" (and None) read the SCALECOM_LAYOUT env var at call time —
-    compat-layer style, mirroring resolve_backend's SCALECOM_BACKEND probe
-    (that is the CI leg that runs the whole tier-1 suite through the
-    layout-preserving rowwise pipeline) — and fall back to "flat", the
-    paper-faithful default. An explicit layout always wins. Must resolve
-    identically at init_state and scalecom_reduce time, which is why both
-    route through here.
-    """
-    if spec in (None, "auto"):
-        env = os.environ.get(_LAYOUT_ENV, "").strip()
-        spec = env or "flat"
-    if spec not in _LAYOUTS:
-        raise ValueError(
-            f"unknown chunk layout {spec!r}; expected one of {_LAYOUTS} "
-            f'(or "auto" to probe ${_LAYOUT_ENV})'
-        )
-    return spec
 
 _FP8_MAX = 448.0  # e4m3 finite max
 _FP8_CHUNK = 512  # flat-layout scale granularity
@@ -137,7 +115,10 @@ def storage_shape(param_shape: Shape, layout: str) -> Shape:
     (R, C) was measurably worse for expert-sharded tensors — the merged
     leading dim can't carry the expert-axis sharding (see EXPERIMENTS §Perf).
     """
-    layout = resolve_layout(layout)
+    if layout not in LAYOUTS:
+        raise ValueError(
+            f"unknown chunk layout {layout!r}; expected one of {LAYOUTS}"
+        )
     size = int(np.prod(param_shape)) if len(param_shape) else 1
     if layout == "flat":
         return (size,)
@@ -333,14 +314,13 @@ def init_state(
     n_workers: int,
     residue_dtype: str = "fp32",
     min_size: int = 2048,
-    layout: str = "auto",
+    layout: str = "flat",
 ) -> ScaleComState:
     """Zero-initialized ScaleCom state for a parameter pytree.
 
     Tensors below ``min_size`` carry no residue: they are always reduced
-    densely (norm scales, biases). Must match ScaleComConfig at train time;
-    ``layout`` resolves through ``resolve_layout`` exactly like
-    ``ScaleComConfig.layout`` does, so the "auto" defaults stay in sync.
+    densely (norm scales, biases). Must match ScaleComConfig at train time,
+    ``layout`` included (both default to "flat").
     """
     codec = CODECS[residue_dtype]
     residues = {}
@@ -495,7 +475,7 @@ def residue_bytes(
     n_workers: int,
     residue_dtype: str = "fp32",
     min_size: int = 2048,
-    layout: str = "auto",
+    layout: str = "flat",
 ) -> int:
     codec = CODECS[residue_dtype]
     total = 0

@@ -5,7 +5,7 @@ Each kernel: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd 1-D wrapper
 with CPU interpret fallback), ref.py (pure-jnp oracle), rowwise.py (the
 trailing-axis launchers every backend op routes through — one surface for
 both the flat and the layout-preserving layouts, top-1 and top-m). Production
-dispatch goes through repro.backends (resolve_backend); tile geometry is
-swept by repro.backends.autotune and benchmarked in
-benchmarks/bench_kernels.py.
+dispatch goes through repro.backends (resolve_backend); the chunks a grid
+step covers are the constants of chunk_topk (``BLOCK_CHUNKS``,
+``DENSE_BLOCK_CHUNKS``).
 """

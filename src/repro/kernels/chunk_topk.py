@@ -11,8 +11,8 @@ tile: no data-dependent control flow, MXU not needed. Reads at a
 data-dependent lane offset are a one-hot compare-and-select followed by a lane
 sum (``lane_pick``) or an in-vreg lane gather: Mosaic lowers no general
 in-kernel gather. ``block_chunks`` — the chunks one grid step covers, in either
-geometry below — is a static tuning knob swept by ``repro.backends.autotune``;
-it must be a multiple of 1024 (see ``BLOCK_CHUNKS``).
+geometry below — is static; the backend passes ``BLOCK_CHUNKS`` or
+``DENSE_BLOCK_CHUNKS``, and any value must be a multiple of 1024.
 
 Two tile geometries, chosen from static shapes (``lane_dense``):
 
@@ -74,14 +74,16 @@ __all__ = [
 # geometry, a (BLOCK_CHUNKS * chunk / 128, 128) lane-dense tile. The
 # per-chunk (index, value) arrays are 1-D, and XLA lays a long 1-D TPU array
 # out in 1024-element tiles; Mosaic refuses a 1-D block that is not a
-# multiple of that tile, so every block is a multiple of 1024 chunks
-# (autotune.CANDIDATE_BLOCKS). A 1024 x 64 fp32 rows tile is 512 KiB in VMEM
-# (64-lane rows pad to 128 lanes), its lane-dense twin 256 KiB; both sit well
-# inside the 16 MiB default scoped limit with double buffering.
+# multiple of that tile, so every block is a multiple of 1024 chunks. A
+# 1024 x 64 fp32 rows tile is 512 KiB in VMEM (64-lane rows pad to 128
+# lanes), its lane-dense twin 256 KiB; both sit well inside the 16 MiB
+# default scoped limit with double buffering.
 BLOCK_CHUNKS = 1024
 
 # Default for lane-dense launches: 4096 chunks of 64 make a 1 MiB tile, so
-# the fixed cost of a grid step is a small share of the tile's DMA.
+# the fixed cost of a grid step is a small share of the tile's DMA; chosen
+# by a sweep on a TPU v5e when the lane-dense tiles came in. These two
+# constants are the only tile sizes the backend passes.
 DENSE_BLOCK_CHUNKS = 4096
 
 LANES = 128

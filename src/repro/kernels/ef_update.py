@@ -10,12 +10,11 @@ Unfused HLO runs 3+ passes over the gradient (add, gather, scatter, axpy) —
 each HBM-bandwidth bound. This kernel does one read of (m, g, idx) and one
 write of (m', vals) per tile: ~2.3x less HBM traffic for the residue update,
 which matters because the residue array is n_workers x P — the largest state
-in the system (measured sweep: benchmarks/bench_kernels.py). Tiles follow
-chunk_topk's two geometries — (block_chunks, chunk) rows, or lane-dense
-(block_chunks * chunk / 128, 128) tiles where ``chunk_topk.lane_dense``
-holds, with the shared per-chunk offsets spread over their chunk's lanes by
-in-vreg lane gathers; ``block_chunks`` is autotuned by
-repro.backends.autotune.
+in the system. Tiles follow chunk_topk's two geometries — (block_chunks,
+chunk) rows, or lane-dense (block_chunks * chunk / 128, 128) tiles where
+``chunk_topk.lane_dense`` holds, with the shared per-chunk offsets spread
+over their chunk's lanes by in-vreg lane gathers; ``block_chunks`` is one of
+chunk_topk's constants ``BLOCK_CHUNKS`` / ``DENSE_BLOCK_CHUNKS``.
 
 ``beta`` is a *static* kernel parameter, closed over with functools.partial
 and folded into the tile arithmetic at compile time. (It used to be passed as

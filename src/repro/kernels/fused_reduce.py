@@ -22,8 +22,8 @@ so ef never exists in HBM and (m, g) are read once: ~3 passes instead of ~7.
 
 Tiles are (G, block_chunks, chunk): the FULL worker axis rides in every tile
 because both selection modes need all workers of a chunk row resident
-(leader pick / worker mean). ``block_chunks`` comes from the autotune cache
-("fused_reduce" op, falling back to the ef_update op's tuned tile).
+(leader pick / worker mean). ``block_chunks`` is ``chunk_topk.BLOCK_CHUNKS``:
+these tiles keep the rows geometry.
 
 Double-buffered DMA: the grid iterates over row blocks and every operand's
 BlockSpec maps grid step i to a disjoint HBM slab, which is exactly the shape
@@ -53,14 +53,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.compressors import FUSABLE_MODES
 from repro.kernels.chunk_topk import BLOCK_CHUNKS, _padded_rows, lane_argmax
 
-__all__ = ["FUSABLE_MODES", "fused_reduce_trailing", "row_fused_reduce"]
-
-# Selection modes the fused kernel implements. local_topk (per-worker index
-# sets) and random_k (counter-PRNG draws, not reproducible in-tile) fall back
-# to the 3-launch path — backends.base.fused_reduce documents the contract.
-FUSABLE_MODES = ("clt_k", "true_topk")
+__all__ = ["fused_reduce_trailing", "row_fused_reduce"]
 
 # Scoped VMEM the fused kernel may use. The default 16 MiB holds the
 # double-buffered (G, block_chunks, chunk) m/g/m' tiles for only a few

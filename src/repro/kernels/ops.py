@@ -7,8 +7,8 @@ against repro.kernels.ref by tests/test_kernels.py. Native compilation is
 checked by tests/test_tpu_compile.py and on the chip by ``chip_smoke.py``.
 
 These are the thin 1-D convenience entry points. Production dispatch —
-jnp-vs-pallas selection, autotuned tile geometry, batched worker axes, and the
-rowwise layout — goes through ``repro.backends`` (resolve_backend), which is
+jnp-vs-pallas selection, tile geometry, batched worker axes, and the rowwise
+layout — goes through ``repro.backends`` (resolve_backend), which is
 what ``scalecom_reduce`` uses.
 """
 

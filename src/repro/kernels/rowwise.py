@@ -27,8 +27,8 @@ worker-stacked (n_workers, n_chunks) one.
 
 Tile geometry and grid handling are shared with the flat 1-D kernels
 (kernels.chunk_topk row launchers); ``block_chunks``, the chunks a grid step
-covers in either geometry, is swept by repro.backends.autotune and
-benchmarked in benchmarks/bench_kernels.py.
+covers in either geometry, is one of chunk_topk's constants
+``BLOCK_CHUNKS`` / ``DENSE_BLOCK_CHUNKS``.
 """
 
 from __future__ import annotations

@@ -34,7 +34,7 @@ from repro.configs import SHAPES, registry
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.scalecom import ScaleComConfig
 from repro.core.compressors import CompressorConfig
-from repro.core.state import init_state, resolve_layout
+from repro.core.state import init_state
 from repro.distributed.sharding import specs_for_axes
 from repro.launch.mesh import make_production_mesh
 from repro.models import build_model
@@ -127,7 +127,6 @@ def _residue_specs(
               (matched by key path), prefixed with the worker axes — every
               compression op is then sharding-preserving.
     """
-    layout = resolve_layout(layout)  # accept "auto" like storage_shape does
     rest = tuple(a for a in mesh.axis_names if a not in worker_axes)
     wa = worker_axes[0] if len(worker_axes) == 1 else worker_axes
 
@@ -215,7 +214,7 @@ def lower_train(
         compressor=CompressorConfig("clt_k", chunk=settings["chunk"]),
         beta=0.1,
         residue_dtype=settings["residue_dtype"],
-        layout=resolve_layout(settings.get("layout") or "auto"),
+        layout=settings.get("layout") or "flat",
         groups=settings["groups"],
     )
     opt = make_optimizer("sgdm")

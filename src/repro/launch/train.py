@@ -76,17 +76,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--groups", type=int, default=None)
     ap.add_argument("--backend", default="auto", choices=["auto", "jnp", "pallas"],
                     help="kernel backend for the chunked reduce ops "
-                         "(repro.backends; auto = env var > TPU probe > jnp)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="sweep Pallas tile geometry for this model's tensor "
-                         "sizes and persist winners to the autotune cache "
-                         "before training (see repro.backends.autotune)")
+                         "(repro.backends; auto = pallas on a TPU, else jnp)")
     ap.add_argument("--bucket-mb", type=float, default=None,
                     help="overlap-aware bucketed reduce: pack tensors into "
                          "~this many MB per launch bucket (core.overlap) so "
                          "per-bucket compress+all-reduce can hide behind "
-                         "backward compute. Default: $SCALECOM_BUCKET_MB if "
-                         "set, else unbucketed; 0 forces unbucketed")
+                         "backward compute. Unset or 0: unbucketed")
     ap.add_argument("--no-overlap", action="store_true",
                     help="keep the bucketed launch but drop the "
                          "optimization_barrier ordering hints (the "
@@ -133,17 +128,12 @@ def build(args: argparse.Namespace, **sc_overrides):
     """
     cfg = (registry.arch if args.full_width else registry.smoke)(args.arch)
     model = build_model(cfg, compute_dtype="float32", loss_chunk=64)
-    # --bucket-mb: None -> "auto" ($SCALECOM_BUCKET_MB probe), 0 -> force the
-    # unbucketed single-shot reduce, > 0 -> bucketed at that size
-    if args.bucket_mb is None:
-        buckets = None
-        bucket_bytes = ScaleComConfig.bucket_bytes
-    elif args.bucket_mb <= 0:
-        buckets = False
-        bucket_bytes = ScaleComConfig.bucket_bytes
-    else:
-        buckets = True
-        bucket_bytes = int(args.bucket_mb * (1 << 20))
+    # --bucket-mb: unset or <= 0 -> the unbucketed single-shot reduce,
+    # > 0 -> bucketed at that size
+    buckets = (args.bucket_mb or 0) > 0
+    bucket_bytes = (
+        int(args.bucket_mb * (1 << 20)) if buckets else ScaleComConfig.bucket_bytes
+    )
     sc_cfg = ScaleComConfig(
         compressor=CompressorConfig(args.compressor, chunk=args.chunk),
         beta=args.beta,
@@ -208,25 +198,14 @@ def main(argv=None):
     print(f"[launch.train] {jax_compat.describe()}")
 
     _, loop, state, batches = build(args)
-    if args.autotune and args.backend != "jnp":
-        from repro.backends import autotune as _at
-
-        wins = _at.autotune_params(
-            state.params, args.chunk, min_size=loop.sc_cfg.min_size
-        )
-        for key, best in wins.items():
-            print(f"[launch.train] autotune {key}: block_chunks={best} "
-                  f"-> {_at.cache_path()}")
-    elif args.autotune:
-        print("[launch.train] --autotune skipped: backend=jnp never consults "
-              "the Pallas tile cache")
     telemetry = None
     if args.trace_dir:
         telemetry = obs.TelemetryRun(
             args.trace_dir,
             backend_name=args.backend,
             extra_provenance={"arch": args.arch, "compressor": args.compressor,
-                              "workers": args.workers},
+                              "workers": args.workers,
+                              "fused": loop.sc_cfg.fused},
         )
     # run_training's default log is the (silent-by-default) telemetry logger;
     # the CLI is the consumer that wants visible step lines

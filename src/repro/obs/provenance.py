@@ -47,9 +47,9 @@ def git_sha(cwd: Optional[str] = None) -> Optional[str]:
 def device_tags(backend_name: Optional[str] = None) -> Dict[str, Any]:
     """Per-record device tags: device kind, jax platform, and — when a kernel
     backend name is given — whether pallas would run in interpret mode here
-    (any non-TPU host: the timings measure the interpreter) plus the resolved
-    fused-reduce decision ($SCALECOM_FUSED under "auto"), so a bench record
-    says which inner-loop path produced it."""
+    (any non-TPU host: the timings measure the interpreter). Which inner-loop
+    path a run took is its config's (``ScaleComConfig.fused``), which the
+    caller stamps itself."""
     import jax
 
     tags: Dict[str, Any] = {
@@ -57,12 +57,9 @@ def device_tags(backend_name: Optional[str] = None) -> Dict[str, Any]:
         "jax_backend": jax.default_backend(),
     }
     if backend_name is not None:
-        from repro.backends.base import resolve_fused
-
         tags["interpret"] = (
             backend_name == "pallas" and jax.default_backend() != "tpu"
         )
-        tags["fused"] = resolve_fused("auto")
     return tags
 
 

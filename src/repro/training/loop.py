@@ -49,7 +49,7 @@ class TrainLoop:
     log_every: int = 10
     compute_stats: bool = False
     # overlap-aware bucketed reduce launch spec (scalecom_reduce buckets=...);
-    # None/"auto" probes $SCALECOM_BUCKET_MB at trace time
+    # None is the unbucketed single-shot launch
     buckets: Any = None
 
     def __post_init__(self):

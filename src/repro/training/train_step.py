@@ -83,12 +83,12 @@ def build_train_step(
     batch: worker-stacked {"tokens": (n, B, S), ...}.
 
     buckets selects the launch granularity of the ScaleCom reduce (see
-    scalecom_reduce): the default None/"auto" probes $SCALECOM_BUCKET_MB at
-    trace time; an explicit value (False / True / bytes / a prebuilt bucket
-    tuple) wins. With bucketing on, each bucket's compress + all-reduce is
-    staged in reverse-autodiff grad-ready order behind an
-    optimization_barrier token chain, so XLA's scheduler can overlap the
-    per-bucket collectives with the rest of backward — numerics unchanged.
+    scalecom_reduce): None/False (the default) is the single-shot launch;
+    True, a byte count or a prebuilt bucket tuple buckets it. With bucketing
+    on, each bucket's compress + all-reduce is staged in reverse-autodiff
+    grad-ready order behind an optimization_barrier token chain, so XLA's
+    scheduler can overlap the per-bucket collectives with the rest of
+    backward — numerics unchanged.
 
     worker_shardings pins the expanded params AND the per-worker gradient
     cotangents to (worker_axis, *param_sharding). Without the explicit

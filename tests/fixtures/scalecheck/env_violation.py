@@ -2,12 +2,12 @@
 
 import os
 
-LAYOUT = os.environ.get("SCALECOM_LAYOUT", "flat")  # read at import time
+PLATFORMS = os.environ.get("JAX_PLATFORMS", "cpu")  # read at import time
 
-if "SCALECOM_BACKEND" in os.environ:  # membership read at import time
-    BACKEND = os.environ["SCALECOM_BACKEND"]
+if "XLA_FLAGS" in os.environ:  # membership read at import time
+    FLAGS = os.environ["XLA_FLAGS"]
 
 
 def fine():
     # call-time probes are the sanctioned pattern
-    return os.environ.get("SCALECOM_BUCKET_MB", "")
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "")

@@ -4,13 +4,11 @@ The contract under test (src/repro/backends): the pallas backend in interpret
 mode is *bitwise-identical* on indices and allclose on values against the jnp
 oracle backend, for every op, both layouts, odd sizes, tail chunks, bf16 and
 top-m — and a 20-step scalecom_reduce trajectory is identical between
-backend="jnp" and backend="pallas" to fp32 tolerance. Resolution ("auto", the
-SCALECOM_BACKEND env var, the deprecated use_kernel flag) is pure-python and
-tested directly.
+backend="jnp" and backend="pallas" to fp32 tolerance. Resolution (a spec and
+the platform, nothing else) is pure-python and tested directly.
 """
 
-import json
-import os
+import inspect
 
 import numpy as np
 import pytest
@@ -23,13 +21,11 @@ from repro.backends import (
     KernelBackend,
     available_backends,
     resolve_backend,
-    resolve_fused,
 )
-from repro.backends import autotune
 from repro.backends.jnp_backend import JnpBackend
 from repro.backends.pallas_backend import PallasBackend
 from repro.core import chunked
-from repro.core.compressors import CompressorConfig, compress
+from repro.core.compressors import CompressorConfig
 from repro.core.scalecom import ScaleComConfig, scalecom_reduce
 from repro.core.state import CODECS, init_state
 
@@ -63,130 +59,59 @@ def test_resolve_unknown_name_raises():
         resolve_backend("cuda")
 
 
-def test_auto_env_var_wins(monkeypatch):
-    monkeypatch.setenv("SCALECOM_BACKEND", "pallas")
-    assert isinstance(resolve_backend("auto"), PallasBackend)
-    monkeypatch.setenv("SCALECOM_BACKEND", "jnp")
-    assert isinstance(resolve_backend("auto"), JnpBackend)
+# (spec, platform, pallas importable) -> backend class, or the error raised
+_RESOLUTION_CASES = [
+    ("auto", "cpu", True, JnpBackend),
+    (None, "cpu", True, JnpBackend),
+    ("auto", "tpu", True, PallasBackend),
+    (None, "tpu", True, PallasBackend),
+    ("auto", "tpu", False, JnpBackend),
+    ("jnp", "tpu", True, JnpBackend),
+    ("jnp", "cpu", False, JnpBackend),
+    ("pallas", "cpu", True, PallasBackend),
+    ("pallas", "tpu", True, PallasBackend),
+    ("pallas", "tpu", False, ImportError),
+    ("pallas", "cpu", False, ImportError),
+    ("cuda", "tpu", True, ValueError),
+]
 
 
-def test_invalid_env_value_names_registered_set(monkeypatch):
-    """A typo'd $SCALECOM_BACKEND must fail loudly, listing what exists."""
-    monkeypatch.setenv("SCALECOM_BACKEND", "cuda")
-    with pytest.raises(ValueError, match="unknown kernel backend") as err:
-        resolve_backend("auto")
-    msg = str(err.value)
-    assert "jnp" in msg and "pallas" in msg
-
-
-def test_explicit_backend_wins_over_env(monkeypatch):
-    monkeypatch.setenv("SCALECOM_BACKEND", "pallas")
-    assert isinstance(resolve_backend("jnp"), JnpBackend)
-    # even a garbage env var is ignored when the config is explicit
-    monkeypatch.setenv("SCALECOM_BACKEND", "cuda")
-    assert isinstance(resolve_backend("jnp"), JnpBackend)
-
-
-def test_auto_without_tpu_is_jnp(monkeypatch):
-    monkeypatch.delenv("SCALECOM_BACKEND", raising=False)
-    # this container is CPU-only, so the TPU probe must fall through to jnp
-    assert isinstance(resolve_backend("auto"), JnpBackend)
-
-
-def test_auto_probes_at_call_time(monkeypatch):
-    monkeypatch.delenv("SCALECOM_BACKEND", raising=False)
+@pytest.mark.parametrize(
+    "spec,platform,importable,expected", _RESOLUTION_CASES,
+    ids=[f"{c[0]}-{c[1]}-{'pl' if c[2] else 'nopl'}" for c in _RESOLUTION_CASES],
+)
+def test_backend_resolution(monkeypatch, spec, platform, importable, expected):
+    """The backend is a function of the spec and what the code can observe:
+    the platform (probed at call time) and whether pallas imports. An
+    explicit spec wins over the platform; an unknown name raises and lists
+    the registered set; pallas without the package raises ImportError."""
     import repro.backends.base as base
-
-    monkeypatch.setattr(base.jax, "default_backend", lambda: "tpu")
-    assert isinstance(resolve_backend("auto"), PallasBackend)
-
-
-def test_pallas_backend_requires_pallas(monkeypatch):
     import repro.backends.pallas_backend as pb
 
-    monkeypatch.setattr(pb, "pallas_available", lambda: False)
-    with pytest.raises(ImportError, match="pallas"):
-        PallasBackend()
-
-
-def test_use_kernel_deprecation_maps_to_pallas(monkeypatch):
-    from repro.core import compressors as comp_mod
-
-    monkeypatch.setattr(comp_mod, "_use_kernel_warned", False)
-    ef = _rand((2, 256), 0)
-    cfg = CompressorConfig("clt_k", chunk=16, use_kernel=True)
-    with pytest.warns(DeprecationWarning, match="use_kernel is deprecated"):
-        vals, idx, dense = compress(ef, jnp.zeros((), jnp.int32), cfg)
-    ref = compress(ef, jnp.zeros((), jnp.int32), CompressorConfig("clt_k", chunk=16),
-                   backend=JNP)
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref[1]))
-    np.testing.assert_allclose(np.asarray(dense), np.asarray(ref[2]), rtol=1e-6)
-
-
-def test_use_kernel_deprecation_warns_once_per_process(monkeypatch):
-    """The warning is a one-shot latch: warn-on-every-call was pure log noise
-    over a long run (the resolver fires once per reduce call)."""
-    import warnings as _warnings
-
-    from repro.core import compressors as comp_mod
-
-    monkeypatch.setattr(comp_mod, "_use_kernel_warned", False)
-    cfg = CompressorConfig("clt_k", chunk=16, use_kernel=True)
-    with _warnings.catch_warnings(record=True) as rec:
-        _warnings.simplefilter("always")
-        comp_mod.resolve_backend_with_deprecation(cfg)
-        comp_mod.resolve_backend_with_deprecation(cfg)
-        comp_mod.resolve_backend_with_deprecation(cfg)
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    # the mapping itself still applies on every call, silently
-    assert isinstance(comp_mod.resolve_backend_with_deprecation(cfg), PallasBackend)
-
-
-# ---------------------------------------------------------------------------
-# fused-reduce resolution ($SCALECOM_FUSED — mirrors the layout/backend rules)
-# ---------------------------------------------------------------------------
-
-
-def test_resolve_fused_env_probe_at_call_time(monkeypatch):
-    monkeypatch.delenv("SCALECOM_FUSED", raising=False)
-    assert resolve_fused("auto") is False  # opt-in until on-TPU validation
-    assert resolve_fused(None) is False
-    for val in ("1", "true", "ON", "yes"):
-        monkeypatch.setenv("SCALECOM_FUSED", val)
-        assert resolve_fused("auto") is True
-    for val in ("0", "false", "Off", "no", ""):
-        monkeypatch.setenv("SCALECOM_FUSED", val)
-        assert resolve_fused("auto") is False
-
-
-def test_resolve_fused_explicit_wins_over_env(monkeypatch):
-    monkeypatch.setenv("SCALECOM_FUSED", "1")
-    assert resolve_fused(False) is False
-    # even a garbage env var is never read when the config is explicit
-    monkeypatch.setenv("SCALECOM_FUSED", "banana")
-    assert resolve_fused(True) is True
-    assert resolve_fused(False) is False
-
-
-def test_resolve_fused_invalid_env_names_valid_set(monkeypatch):
-    monkeypatch.setenv("SCALECOM_FUSED", "maybe")
-    with pytest.raises(ValueError, match="SCALECOM_FUSED") as err:
-        resolve_fused("auto")
-    msg = str(err.value)
-    for token in ("1", "true", "0", "false"):
-        assert token in msg
-
-
-def test_resolve_fused_invalid_spec_raises():
-    # strings other than "auto" are config bugs, not env lookups
-    with pytest.raises(ValueError, match="fused must be"):
-        resolve_fused("yes")
+    monkeypatch.setattr(base.jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(base, "pallas_available", lambda: importable)
+    monkeypatch.setattr(pb, "pallas_available", lambda: importable)
+    pb._instance.cache_clear()
+    try:
+        if isinstance(expected, type) and issubclass(expected, Exception):
+            with pytest.raises(expected) as err:
+                resolve_backend(spec)
+            if expected is ValueError:
+                assert "jnp" in str(err.value) and "pallas" in str(err.value)
+            else:
+                assert "pallas" in str(err.value)
+        else:
+            assert isinstance(resolve_backend(spec), expected)
+    finally:
+        pb._instance.cache_clear()
 
 
 def test_config_rejects_invalid_fused_spec():
-    with pytest.raises(ValueError, match="fused must be"):
-        ScaleComConfig(fused="on")
+    """fused is a bool: no other value (the old "auto" included) defers the
+    choice to anything else."""
+    for spec in ("auto", "on", None, 1):
+        with pytest.raises(ValueError, match="fused must be"):
+            ScaleComConfig(fused=spec)
 
 
 # ---------------------------------------------------------------------------
@@ -372,6 +297,52 @@ def test_lane_dense_ops_match_the_oracles_bitwise(shape, chunk, topm):
     d1 = JNP.scatter(e1, idx, chunk, shape[-1], topm)
     d2 = PAL.scatter(e1, idx, chunk, shape[-1], topm)
     np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+
+
+@pytest.mark.parametrize(
+    "shape,chunk,dtype,dense",
+    [
+        ((2, 1024), 64, jnp.float32, True),
+        ((2, 960), 64, jnp.float32, False),
+        ((2, 1024), 64, jnp.bfloat16, False),
+        ((2, 1024), 128, jnp.float32, False),
+    ],
+    ids=["lane_dense", "ragged_width", "bf16", "chunk128"],
+)
+def test_tile_is_a_kernel_constant(monkeypatch, shape, chunk, dtype, dense):
+    """Every pallas launch takes one of kernels.chunk_topk's two tile
+    constants, picked by the launch's static shape alone: DENSE_BLOCK_CHUNKS
+    where the tiles are lane-dense, BLOCK_CHUNKS otherwise and for the fused
+    launch."""
+    from repro.kernels import chunk_topk, fused_reduce as fr, rowwise
+
+    seen = []
+
+    def spy(fn):
+        def wrapped(*args, **kw):
+            seen.append((fn.__name__, kw.get("block_chunks", "default")))
+            return fn(*args, **kw)
+        return wrapped
+
+    for name in ("select_trailing", "gather_trailing", "ef_update_trailing",
+                 "scatter_trailing"):
+        monkeypatch.setattr(rowwise, name, spy(getattr(rowwise, name)))
+    fused_kernel = fr.fused_reduce_trailing
+    monkeypatch.setattr(fr, "fused_reduce_trailing", spy(fused_kernel))
+    x, g = _rand(shape, 71, dtype), _rand(shape, 72, dtype)
+    idx, _ = PAL.select(x, chunk)
+    PAL.gather(x, idx, chunk)
+    _, vals = PAL.ef_update(x, g, idx, 0.25, chunk)
+    PAL.scatter(vals, idx, chunk, shape[-1])
+    PAL.fused_reduce(x, g, 0.25, chunk)
+    want = chunk_topk.DENSE_BLOCK_CHUNKS if dense else chunk_topk.BLOCK_CHUNKS
+    assert seen[:4] == [
+        ("select_trailing", want), ("gather_trailing", want),
+        ("ef_update_trailing", want), ("scatter_trailing", want),
+    ]
+    assert seen[4] == ("fused_reduce_trailing", "default")
+    assert inspect.signature(fused_kernel.__wrapped__).parameters[
+        "block_chunks"].default == chunk_topk.BLOCK_CHUNKS
 
 
 # ---------------------------------------------------------------------------
@@ -595,20 +566,6 @@ def test_fused_trajectory_across_backends():
             )
 
 
-def test_fused_env_var_drives_the_reduce(monkeypatch):
-    """SCALECOM_FUSED=1 + fused="auto" takes the fused path end-to-end (and
-    produces the same output as fused off)."""
-    monkeypatch.setenv("SCALECOM_FUSED", "1")
-    gh1, _ = _fused_trajectory("flat", "clt_k", 1, "pallas", "auto", False,
-                               steps=3)
-    monkeypatch.delenv("SCALECOM_FUSED")
-    gh2, _ = _fused_trajectory("flat", "clt_k", 1, "pallas", "auto", False,
-                               steps=3)
-    for a, b in zip(gh1, gh2):
-        for k in a:
-            np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
-
-
 @pytest.mark.parametrize("layout", ["flat", "rowwise"])
 def test_pallas_backend_bypasses_jnp_chunked_ops(monkeypatch, layout):
     """With backend="pallas" no jnp chunked op runs on the compressed path.
@@ -671,147 +628,3 @@ def test_backend_surface_has_no_rw_methods():
         be = resolve_backend(name)
         rw = [a for a in dir(be) if a.startswith("rw_")]
         assert not rw, (name, rw)
-
-
-# ---------------------------------------------------------------------------
-# autotune cache plumbing
-# ---------------------------------------------------------------------------
-
-
-def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
-    cache = tmp_path / "autotune.json"
-    monkeypatch.setenv("SCALECOM_AUTOTUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    try:
-        best = autotune.autotune(
-            "select", size=1024, chunk=16, candidates=(1024, 2048), iters=1
-        )
-        assert best in (1024, 2048)
-        assert cache.exists()
-        # the read path the dispatch layer uses returns the cached winner
-        assert autotune.best_block_chunks("select", 64, 16, jnp.float32) == best
-        # a miss (different op/chunk) falls back to the kernel default
-        from repro.kernels.chunk_topk import BLOCK_CHUNKS
-
-        assert autotune.best_block_chunks("ef_update", 64, 16, jnp.float32) == BLOCK_CHUNKS
-        # stale entries outside the candidate set are ignored, not trusted
-        import json
-
-        data = json.loads(cache.read_text())
-        data = {k: 7 for k in data}
-        cache.write_text(json.dumps(data))
-        autotune.clear_cache()
-        assert autotune.best_block_chunks("select", 64, 16, jnp.float32) == BLOCK_CHUNKS
-    finally:
-        autotune.clear_cache()  # drop the tmp-path mirror for later tests
-
-
-def test_autotune_rejects_unknown_op():
-    with pytest.raises(ValueError, match="op must be one of"):
-        autotune.autotune("softmax", size=64, chunk=16)
-
-
-def test_autotune_fused_tile_falls_back_to_ef_update(tmp_path, monkeypatch):
-    """fused_reduce with no cache entry borrows ef_update's tuned tile (the
-    _TILE_FALLBACK chain); its own entry wins once a fused sweep ran; and an
-    unknown op name raises instead of silently pinning the default tile."""
-    cache = tmp_path / "autotune.json"
-    monkeypatch.setenv("SCALECOM_AUTOTUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    try:
-        from repro.kernels.chunk_topk import BLOCK_CHUNKS
-
-        # empty cache: kernel default
-        assert (
-            autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32)
-            == BLOCK_CHUNKS
-        )
-        # an ef_update entry at the same geometry is borrowed
-        ef_key = autotune._key("ef_update", 16, jnp.float32, 64)
-        cache.write_text(json.dumps({ef_key: 2048}))
-        autotune.clear_cache()
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 2048
-        # ...until the fused op has its own tuned entry
-        own_key = autotune._key("fused_reduce", 16, jnp.float32, 64)
-        cache.write_text(json.dumps({ef_key: 2048, own_key: 4096}))
-        autotune.clear_cache()
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 4096
-        # the fallback never launders a stale (non-candidate) geometry
-        cache.write_text(json.dumps({ef_key: 7}))
-        autotune.clear_cache()
-        assert (
-            autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32)
-            == BLOCK_CHUNKS
-        )
-        with pytest.raises(ValueError, match="unknown autotune op"):
-            autotune.best_block_chunks("softmax", 64, 16, jnp.float32)
-    finally:
-        autotune.clear_cache()
-
-
-def test_autotune_sweeps_fused_reduce(tmp_path, monkeypatch):
-    """The explicit write path handles the fused op: one sweep populates a
-    fused_reduce entry the read path then returns (keyed by TOTAL launch
-    rows, workers included — PallasBackend._block's convention)."""
-    cache = tmp_path / "autotune.json"
-    monkeypatch.setenv("SCALECOM_AUTOTUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    try:
-        best = autotune.autotune(
-            "fused_reduce", size=256, chunk=16, candidates=(1024,), iters=1
-        )
-        assert best == 1024
-        # size=256, chunk=16 -> 16 chunk rows x 4 sweep workers = 64 rows
-        assert autotune.best_block_chunks("fused_reduce", 64, 16, jnp.float32) == 1024
-        assert any("fused_reduce" in k for k in json.loads(cache.read_text()))
-    finally:
-        autotune.clear_cache()
-
-
-@pytest.mark.parametrize(
-    "garbage", ['{"k": 128', "", "[1, 2, 3]", '"a bare string"', "\x00\x01"]
-)
-def test_autotune_tolerates_corrupt_cache(tmp_path, monkeypatch, garbage):
-    """A truncated / mistyped / binary-garbage cache file must degrade to an
-    empty cache (kernel-default reads, re-sweep on autotune), never raise."""
-    cache = tmp_path / "autotune.json"
-    cache.write_text(garbage)
-    monkeypatch.setenv("SCALECOM_AUTOTUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    try:
-        from repro.kernels.chunk_topk import BLOCK_CHUNKS
-
-        assert autotune.best_block_chunks("select", 64, 16, jnp.float32) == BLOCK_CHUNKS
-        # the explicit write path re-sweeps and republishes a valid cache
-        best = autotune.autotune(
-            "select", size=256, chunk=16, candidates=(1024,), iters=1
-        )
-        assert best == 1024
-        assert isinstance(json.loads(cache.read_text()), dict)
-    finally:
-        autotune.clear_cache()
-
-
-def test_autotune_store_is_atomic(tmp_path, monkeypatch):
-    """The publish is temp-file + os.replace: no partially-written cache is
-    ever visible at the cache path, and no temp litter survives."""
-    cache = tmp_path / "autotune.json"
-    monkeypatch.setenv("SCALECOM_AUTOTUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    try:
-        replaced = []
-        real_replace = os.replace
-
-        def spy(src, dst):
-            # at replace time the temp file already holds COMPLETE json
-            assert isinstance(json.loads(open(src).read()), dict)
-            replaced.append((src, dst))
-            real_replace(src, dst)
-
-        monkeypatch.setattr(autotune.os, "replace", spy)
-        autotune.autotune("select", size=256, chunk=16, candidates=(64,), iters=1)
-        assert replaced and replaced[-1][1] == str(cache)
-        assert json.loads(cache.read_text())  # final file is whole
-        assert os.listdir(tmp_path) == ["autotune.json"]  # no tmp litter
-    finally:
-        autotune.clear_cache()

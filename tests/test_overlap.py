@@ -158,50 +158,37 @@ def test_config_rejects_nonpositive_bucket_bytes():
 
 
 # ---------------------------------------------------------------------------
-# resolution: buckets= arg > $SCALECOM_BUCKET_MB > off
+# resolution: the buckets= arg alone (off unless asked for)
 # ---------------------------------------------------------------------------
 
 
-def test_resolve_bucket_bytes_env_probe(monkeypatch):
-    monkeypatch.delenv(overlap.BUCKET_ENV, raising=False)
+def test_resolve_bucket_bytes_invalid_values():
     assert overlap.resolve_bucket_bytes(None) is None
-    assert overlap.resolve_bucket_bytes("auto") is None
-    monkeypatch.setenv(overlap.BUCKET_ENV, "8")
-    assert overlap.resolve_bucket_bytes(None) == 8 << 20
-    monkeypatch.setenv(overlap.BUCKET_ENV, "0.5")
-    assert overlap.resolve_bucket_bytes(None) == 1 << 19
-    monkeypatch.setenv(overlap.BUCKET_ENV, "0")
-    assert overlap.resolve_bucket_bytes(None) is None
-
-
-def test_resolve_bucket_bytes_explicit_wins_over_env(monkeypatch):
-    monkeypatch.setenv(overlap.BUCKET_ENV, "8")
     assert overlap.resolve_bucket_bytes(False) is None
     assert overlap.resolve_bucket_bytes(True, default_bytes=123) == 123
     assert overlap.resolve_bucket_bytes(4096) == 4096
-
-
-def test_resolve_bucket_bytes_invalid_values(monkeypatch):
-    monkeypatch.setenv(overlap.BUCKET_ENV, "lots")
-    with pytest.raises(ValueError, match="SCALECOM_BUCKET_MB"):
-        overlap.resolve_bucket_bytes(None)
-    monkeypatch.delenv(overlap.BUCKET_ENV, raising=False)
     with pytest.raises(ValueError, match="positive"):
         overlap.resolve_bucket_bytes(-1)
     with pytest.raises(TypeError, match="buckets spec"):
         overlap.resolve_bucket_bytes("yes please")
+    with pytest.raises(TypeError, match="buckets spec"):
+        overlap.resolve_bucket_bytes("auto")
 
 
-def test_resolve_buckets_passthrough_and_env(monkeypatch):
+def test_resolve_buckets_passthrough_and_env():
+    """A pre-built schedule passes through; None and False are unbucketed;
+    an explicit size or True (cfg.bucket_bytes) plans buckets."""
     cfg = _cfg(min_size=1)
     plans = _plans(cfg, (("['w']", (256,), 4),))
     prebuilt = plan_buckets(plans, 512)
     assert overlap.resolve_buckets(prebuilt, cfg, plans) == prebuilt
-    monkeypatch.delenv(overlap.BUCKET_ENV, raising=False)
     assert overlap.resolve_buckets(None, cfg, plans) is None
-    monkeypatch.setenv(overlap.BUCKET_ENV, "1")
-    sched = overlap.resolve_buckets(None, cfg, plans)
+    assert overlap.resolve_buckets(False, cfg, plans) is None
+    sched = overlap.resolve_buckets(1 << 20, cfg, plans)
     assert sched is not None and isinstance(sched[0], Bucket)
+    assert overlap.resolve_buckets(True, cfg, plans) == plan_buckets(
+        plans, cfg.bucket_bytes
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -247,13 +234,12 @@ def test_bucketed_trajectory_bitwise_identical(layout, backend):
         )
 
 
-def test_sync_fallback_and_env_leg_bitwise_identical(monkeypatch):
-    """overlap=False (the synchronous fallback) and the $SCALECOM_BUCKET_MB
-    env leg both stay bitwise identical to the single-shot launch."""
+def test_sync_fallback_and_env_leg_bitwise_identical():
+    """overlap=False (the synchronous fallback) and buckets=True at a ~1 KB
+    cfg.bucket_bytes both stay bitwise identical to the single-shot launch."""
     ghats_u, state_u = _trajectory(_cfg(), buckets=False, steps=6)
     ghats_s, state_s = _trajectory(_cfg(overlap=False), buckets=1024, steps=6)
-    monkeypatch.setenv(overlap.BUCKET_ENV, "0.001")  # ~1 KB via the env var
-    ghats_e, state_e = _trajectory(_cfg(), buckets=None, steps=6)
+    ghats_e, state_e = _trajectory(_cfg(bucket_bytes=1048), buckets=True, steps=6)
     for gu, gs, ge in zip(ghats_u, ghats_s, ghats_e):
         for k in _TREE_SIZES:
             np.testing.assert_array_equal(np.asarray(gu[k]), np.asarray(gs[k]))

@@ -1,5 +1,5 @@
-"""The plan stage: per-tensor resolution, caching, layout probing and the
-one-rule byte accounting (core/plan.py + core.state.resolve_layout)."""
+"""The plan stage: per-tensor resolution, caching, layout validation and the
+one-rule byte accounting (core/plan.py + core.state.storage_shape)."""
 
 import jax.numpy as jnp
 import pytest
@@ -8,7 +8,7 @@ from repro.core.compressors import CompressorConfig
 from repro.core.plan import payload_bytes, plan_tensors
 from repro.core.rates import RateRule
 from repro.core.scalecom import ScaleComConfig
-from repro.core.state import resolve_layout, storage_shape
+from repro.core.state import storage_shape
 
 
 def _plans(cfg, leaves, residues=None):
@@ -97,41 +97,20 @@ def test_topm_beyond_chunk_fails_fast():
         CompressorConfig("clt_k", chunk=16, topm=0)
 
 
-def test_resolve_layout_env_probe(monkeypatch):
-    monkeypatch.delenv("SCALECOM_LAYOUT", raising=False)
-    assert resolve_layout("auto") == "flat"
-    assert resolve_layout(None) == "flat"
-    monkeypatch.setenv("SCALECOM_LAYOUT", "rowwise")
-    assert resolve_layout("auto") == "rowwise"
-    # an explicit layout always wins over the env var
-    assert resolve_layout("flat") == "flat"
-    with pytest.raises(ValueError, match="unknown chunk layout"):
-        resolve_layout("diagonal")
-
-
-def test_resolve_layout_invalid_env_value_names_valid_set(monkeypatch):
-    """A typo'd $SCALECOM_LAYOUT must fail loudly AND name the valid set —
-    not silently fall back to flat and quietly change the wire format."""
-    monkeypatch.setenv("SCALECOM_LAYOUT", "diagonal")
-    with pytest.raises(ValueError, match="unknown chunk layout") as err:
-        resolve_layout("auto")
-    msg = str(err.value)
-    assert "flat" in msg and "rowwise" in msg and "SCALECOM_LAYOUT" in msg
-
-
-def test_resolve_layout_explicit_wins_over_env(monkeypatch):
-    monkeypatch.setenv("SCALECOM_LAYOUT", "flat")
-    assert resolve_layout("rowwise") == "rowwise"
-    # even a garbage env var is ignored when the config is explicit
-    monkeypatch.setenv("SCALECOM_LAYOUT", "diagonal")
-    assert resolve_layout("rowwise") == "rowwise"
-
-
-def test_layout_env_threads_through_plan(monkeypatch):
-    monkeypatch.setenv("SCALECOM_LAYOUT", "rowwise")
-    cfg = ScaleComConfig(compressor=CompressorConfig("clt_k", chunk=16), min_size=1)
-    (p,) = _plans(cfg, (("['w']", (8, 64), 4),))
-    assert p.layout == "rowwise" and p.work == (8, 64)
+def test_resolve_layout_invalid_env_value_names_valid_set():
+    """A typo'd layout must fail loudly AND name the valid set — at config
+    construction and at init_state's storage shapes — not silently fall back
+    to flat and quietly change the wire format. "auto" is no longer a
+    layout."""
+    for bad in ("diagonal", "auto", None):
+        with pytest.raises(ValueError, match="unknown chunk layout") as err:
+            ScaleComConfig(layout=bad)
+        assert "flat" in str(err.value) and "rowwise" in str(err.value)
+        with pytest.raises(ValueError, match="unknown chunk layout") as err:
+            storage_shape((8, 64), bad)
+        assert "flat" in str(err.value) and "rowwise" in str(err.value)
+    assert storage_shape((8, 64), "flat") == (512,)
+    assert storage_shape((8, 64), "rowwise") == (8, 64)
 
 
 def test_groups_amortize_the_index_broadcast():

@@ -91,7 +91,7 @@ def test_env_at_import_fixture():
     assert len(findings) >= 3
     # the sanctioned call-time probe inside fine() is NOT flagged
     call_time_line = next(
-        i for i, line in enumerate(text, 1) if "SCALECOM_BUCKET_MB" in line
+        i for i, line in enumerate(text, 1) if "JAX_COMPILATION_CACHE_DIR" in line
     )
     assert call_time_line not in lines
 
