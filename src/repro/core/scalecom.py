@@ -8,7 +8,7 @@ dense reduced-and-sparsified gradient ĝ^t every worker applies, and the
 updated state.
 
 The function is pure GSPMD-friendly jnp: when the worker axis is sharded over
-the mesh ``data`` axis, XLA lowers
+the mesh ``data`` axis (and ``worker_sharding`` says so), XLA lowers
 
     leader-index slice    ->  O(k) broadcast from the leader's shard
     mean over worker axis ->  k-element all-reduce        (the compressed reduce)
@@ -16,7 +16,8 @@ the mesh ``data`` axis, XLA lowers
 
 which is exactly the paper's communication structure (constant in n; Table 1
 row "ScaleCom"). There is no dense gradient collective anywhere on the path —
-asserted by tests/test_distributed.py on the lowered HLO.
+asserted by tests/test_distributed.py on the CPU and by
+tests/test_tpu_compile.py on a module compiled for a described TPU.
 
 Plan / execute split
 --------------------
@@ -87,12 +88,14 @@ accounting against core.plan / analysis.perfmodel.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.backends.base import resolve_backend
+from repro.compat.jax_compat import NamedSharding, P
 from repro.core.compressors import (
     FUSABLE_MODES,
     CompressorConfig,
@@ -367,6 +370,28 @@ def _tap_execute(
             taps.tap(name, value, path=plan.path)
 
 
+def _learner_local(vals: Array, worker_sharding) -> Array:
+    """Pin the (G, ..., n_chunks[, topm]) per-chunk values to one learner a
+    chip, the layout ``worker_sharding`` gives the worker axis; the other
+    axes are left to propagation.
+
+    The values are the lane sums of a masked (G, ..., n_chunks, chunk) tile
+    (``core.chunked.chunk_gather``). Without this fence the TPU compiler
+    folds that lane sum into the worker mean and all-reduces the whole tile,
+    ``chunk`` times the k values. Nothing is pinned where the worker axis
+    spans one device (no exchange) or G does not split evenly over it
+    (hierarchical groups on a wider axis).
+    """
+    mesh, axes = worker_sharding.mesh, worker_sharding.spec[0]
+    chips = math.prod(
+        mesh.shape[a] for a in (axes if isinstance(axes, tuple) else (axes,))
+    )
+    if chips == 1 or vals.shape[0] % chips:
+        return vals
+    spec = P(axes, *(P.UNCONSTRAINED,) * (vals.ndim - 1))
+    return jax.lax.with_sharding_constraint(vals, NamedSharding(mesh, spec))
+
+
 def _execute(
     plan: TensorPlan,
     gw: Array,
@@ -379,6 +404,7 @@ def _execute(
     compute_stats: bool,
     metrics_every: int = 0,
     fused: bool = False,
+    worker_sharding=None,
 ):
     """Algorithm 1 over the plan's trailing-axis work view.
 
@@ -392,6 +418,10 @@ def _execute(
     ``fused_reduce`` launch with the chunk tile VMEM-resident across all
     three phases; on that path ``ef = m + g`` is never materialized unless
     telemetry/stats ask for it.
+
+    ``worker_sharding`` (``scalecom_reduce``'s) puts one learner on each
+    chip, or is None when the learners share a device; the worker mean then
+    takes the per-chunk values each chip holds (``_learner_local``).
 
     Returns (ghat (*plan.shape), new_enc, ef_mean) — ef_mean feeds the
     contraction_gamma diagnostic (identical in both layouts; None unless
@@ -450,6 +480,8 @@ def _execute(
                 ).reshape(plan.shape)
         else:
             with jax.named_scope("worker_mean"):
+                if worker_sharding is not None:
+                    vals = _learner_local(vals, worker_sharding)
                 vmean = jnp.mean(vals, axis=0)  # all-reduce of k values
             with jax.named_scope("scatter"):
                 ghat = backend.scatter(
@@ -517,6 +549,7 @@ def scalecom_reduce(
     *,
     compute_stats: bool = False,
     buckets: Any = None,
+    worker_sharding: Any = None,
 ) -> Tuple[Pytree, ScaleComState, Dict[str, Array]]:
     """Run Algorithm 1 on worker-stacked gradients.
 
@@ -529,6 +562,11 @@ def scalecom_reduce(
               Bucketing changes launch order/granularity ONLY — same
               per-tensor plans, same EF residues, bitwise-identical output
               (tests/test_overlap.py).
+    worker_sharding: a NamedSharding whose spec splits the leading worker
+              axis over a mesh (``P(axis)``), given when the learners live on
+              different chips; the shared-index worker mean then all-reduces
+              the k values each chip holds. None (the default): the learners
+              share a device and nothing is staged for it.
     Returns (ghat, new_state, stats) where ghat matches the *un-stacked* param
     shapes and is identical on every worker (it came out of an all-reduce).
 
@@ -540,10 +578,12 @@ def scalecom_reduce(
     dict, which is where TelemetryRun.record_step picks them up.
     """
     if not cfg.telemetry:
-        return _reduce(grads_pw, state, cfg, compute_stats, buckets)
+        return _reduce(
+            grads_pw, state, cfg, compute_stats, buckets, worker_sharding
+        )
     with taps.collect() as collected:
         ghat_tree, new_state, stats = _reduce(
-            grads_pw, state, cfg, compute_stats, buckets
+            grads_pw, state, cfg, compute_stats, buckets, worker_sharding
         )
     for key in sorted(collected):
         stats[f"obs/{key}"] = collected[key]
@@ -556,6 +596,7 @@ def _reduce(
     cfg: ScaleComConfig,
     compute_stats: bool,
     buckets: Any,
+    worker_sharding: Any,
 ) -> Tuple[Pytree, ScaleComState, Dict[str, Array]]:
     """The reduce body (scalecom_reduce minus the telemetry collector)."""
     codec = CODECS[cfg.residue_dtype]
@@ -594,7 +635,7 @@ def _reduce(
         ghat, new_enc, ef_mean = _execute(
             plan, gw, state.residues[plan.path], codec, cfg.beta, t,
             codec_key(plan.path, t), backend, want_ef, cfg.metrics_every,
-            cfg.fused,
+            cfg.fused, worker_sharding,
         )
         sums = None
         if want_ef:

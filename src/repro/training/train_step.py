@@ -7,9 +7,10 @@ Two compiled variants:
     (``spmd_axis_name`` shards the axis over the mesh). Because worker i's loss
     touches only ``pex[i]``, the Jacobian is block-diagonal and ``jax.grad``
     yields *unreduced per-worker gradients* — no shard_map, no process groups.
-    ``scalecom_reduce`` then performs Algorithm 1; the only cross-worker
-    gradient collective in the lowered HLO is the k-element value all-reduce
-    (plus the O(k) leader-index broadcast).
+    ``scalecom_reduce`` then performs Algorithm 1, told the worker axis's
+    sharding so that each device sums its learner's per-chunk values first;
+    the only cross-worker gradient collective in the compiled module is the
+    k-element value all-reduce (plus the O(k) leader-index broadcast).
 
   * **dense** — the uncompressed baseline (and the compression warm-up path):
     plain data-parallel GSPMD, loss over the folded global batch, XLA's own
@@ -128,6 +129,13 @@ def build_train_step(
 
         return jax.tree.map(pin_one, tree, worker_shardings)
 
+    # one learner a chip: the reduce's worker mean takes the per-chunk values
+    # each chip holds (core.scalecom._learner_local)
+    worker_sharding = None
+    if worker_axis is not None and worker_shardings is not None:
+        mesh = jax.tree.leaves(worker_shardings)[0].mesh
+        worker_sharding = NamedSharding(mesh, PartitionSpec(worker_axis))
+
     def per_worker_grads(params, batch):
         n = n_workers
         pex = jax.tree.map(
@@ -189,7 +197,7 @@ def build_train_step(
             with jax.named_scope("reduce"):
                 ghat, sc_state, stats = scalecom_reduce(
                     gpw, state.sc_state, sc_cfg, compute_stats=compute_stats,
-                    buckets=buckets,
+                    buckets=buckets, worker_sharding=worker_sharding,
                 )
                 ghat = _pin_reduced(ghat)
         elif mode == "dense":

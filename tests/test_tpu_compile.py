@@ -9,8 +9,9 @@ limit — so a passing case means the kernel compiles natively (a
 ``tpu_custom_call`` in the compiled HLO). Interpret mode, which every other
 kernel test uses, checks none of it. Cases cover both tile geometries: the
 lane-dense tiles (chunks 64 and 32) and the (n_chunks, chunk) rows a chunk
-of 96 keeps. The last test compiles the whole 3-launch reduce and reads the
-compiled HLO for copies of the tensor made around the kernels.
+of 96 keeps. A further test compiles the whole 3-launch reduce and reads the
+compiled HLO for copies of the tensor made around the kernels, and one the
+worker-sharded step on all four chips for what its worker mean exchanges.
 """
 
 import math
@@ -33,23 +34,29 @@ SHAPES = {"ffn": (512, 2048), "embed": (37000, 512)}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent compile cache off
-    (a compile for a described chip cannot be read back from it)."""
+def topo():
+    """A described v5e:2x2, with the persistent compile cache off (a compile
+    for a described chip cannot be read back from it)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _kernel_case(op, shape, chunk=CHUNK):
@@ -228,3 +235,73 @@ def test_flash_attention_compiles_for_v5e(one_chip, cell):
     assert len(names) == 3, names
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert any(kernel in name for name in names), names
+
+
+# ---------------------------------------------------------------------------
+# one learner a chip: what the worker mean exchanges
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("buckets", [None, True], ids=["single_shot", "bucketed"])
+def test_worker_sharded_step_all_reduces_k_values_for_v5e(topo, buckets):
+    """The compressed step of four learners, one on each chip of the 2x2
+    (paper transformer's shape at smoke widths, jnp kernels, CLT-k chunk 64),
+    in one launch and in the bucketed one: the TPU compiler all-reduces one
+    value a chunk under the worker mean, as many bytes as the leader's index
+    broadcast, and never the (n_chunks, chunk) tile the values are gathered
+    from."""
+    import dataclasses
+
+    from repro.compat.jax_compat import NamedSharding, P, make_mesh
+    from repro.configs import registry
+    from repro.core.compressors import CompressorConfig
+    from repro.core.scalecom import ScaleComConfig
+    from repro.core.state import ScaleComState
+    from repro.models import build_model
+    from repro.optim import make_optimizer, schedule
+    from repro.training import init_train_state
+    from repro.training.train_step import build_train_step
+
+    n, batch, seq = 4, 2, 32
+    cfg = registry.smoke("paper-transformer-base")
+    model = build_model(cfg, compute_dtype="float32", loss_chunk=16)
+    sc = ScaleComConfig(compressor=CompressorConfig("clt_k", chunk=CHUNK), beta=0.1,
+                        min_size=1024, backend="jnp")
+    opt = make_optimizer("sgdm")
+    shapes = jax.eval_shape(
+        lambda: init_train_state(model, opt, sc, jax.random.PRNGKey(0), n_workers=n)[0]
+    )
+    mesh = make_mesh((n,), ("data",), devices=topo.devices[:n])
+    whole, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    placed = dataclasses.replace(
+        jax.tree.map(lambda _: whole, shapes),
+        sc_state=ScaleComState(jax.tree.map(lambda _: split, shapes.sc_state.residues), whole),
+    )
+    step = build_train_step(model, opt, schedule.constant(0.05), sc, n_workers=n,
+                            worker_axis="data",
+                            worker_shardings=jax.tree.map(lambda _: split, shapes.params),
+                            buckets=buckets)
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), shapes, placed
+    )
+    tokens = jax.ShapeDtypeStruct((n, batch, seq), jnp.int32, sharding=split)
+    data = {"tokens": tokens, "labels": tokens,
+            "mask": jax.ShapeDtypeStruct((n, batch, seq), jnp.float32, sharding=split)}
+    hlo = jax.jit(step).lower(state, data).compile().as_text()
+
+    # one shape an exchanged operand: a lone all-reduce's own, or each
+    # element a combined all-reduce's tuple hands out
+    combined = set()
+    exchanged = {"worker_mean": [], "select": []}
+    for name, shape, opcode, operands, op_name in _instructions(hlo):
+        stage = re.search(r"/reduce/(worker_mean|select)/", op_name)
+        if opcode.removesuffix("-start") == "all-reduce":
+            if shape.startswith("("):
+                combined.add(name)
+            elif stage:
+                exchanged[stage.group(1)].append(_dims(shape))
+        elif stage and opcode == "get-tuple-element" and operands[0] in combined:
+            exchanged[stage.group(1)].append(_dims(shape))
+    values, indices = exchanged["worker_mean"], exchanged["select"]
+    assert values and all(len(d) == 1 for d in values), values
+    assert sorted(values) == sorted(indices)
